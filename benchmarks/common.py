@@ -13,12 +13,26 @@ claim it validates).
 
 from __future__ import annotations
 
+import os
 import time
+from pathlib import Path
 
 import jax
 import numpy as np
 
 ROWS = []
+
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[1] / ".jax_cache"
+
+
+def enable_compile_cache() -> None:
+    """Keep JAX's persistent compilation cache where
+    ``JAX_COMPILATION_CACHE_DIR`` says (JAX reads the variable itself), or,
+    where it is unset, at the fixed ``.jax_cache/`` at the repository root,
+    where later runs from the same checkout find it. Called by entry points
+    only, never by library code or tests."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
 
 
 def emit(name: str, us_per_call: float, derived: str | float):

@@ -38,6 +38,9 @@ def _resolve(spec: str):
 
 
 def main() -> None:
+    from benchmarks.common import enable_compile_cache
+
+    enable_compile_cache()
     args = sys.argv[1:]
     picks = args if args else list(SUITES)
     print("name,us_per_call,derived")

@@ -5,8 +5,8 @@ traces, no device execution. The walkers are duck-typed (``.eqns`` /
 ``.jaxpr`` attributes) rather than isinstance-checked against jax internals,
 so they survive the ``jax.core`` module reshuffles across versions.
 
-Conventions this module encodes (verified against jax 0.4.37 Pallas
-lowerings, which ``repro/kernels/_compat.py`` pins around):
+Conventions this module encodes (verified against jax 0.9 Pallas
+lowerings):
 
 * a ``pallas_call`` eqn carries the kernel body as ``params["jaxpr"]`` and a
   ``grid_mapping`` whose operand counts slice the kernel invars into
@@ -151,15 +151,14 @@ def is_literal(var) -> bool:
 
 
 def int_literals(eqn) -> list:
-    """Integer literal operands of one eqn."""
+    """Integer scalar literal operands of one eqn. A literal's ``val`` is a
+    Python int, a NumPy scalar, or a typed 0-d array (jax wraps inline
+    constants in its own array type), so it is read through ``np.asarray``."""
     out = []
     for var in eqn.invars:
         if is_literal(var):
-            val = var.val
-            if isinstance(val, (int, np.integer)):
-                out.append(int(val))
-            elif isinstance(val, np.ndarray) and val.ndim == 0 \
-                    and np.issubdtype(val.dtype, np.integer):
+            val = np.asarray(var.val)
+            if val.ndim == 0 and np.issubdtype(val.dtype, np.integer):
                 out.append(int(val))
     return out
 

@@ -44,6 +44,12 @@ registry-completeness test):
   envelope's ``bsr_caps`` block bounds; the dispatchers build/require
   block-capped envelopes for them, using ``block_size`` as the default
   block edge.
+* ``mosaic_refusal`` names why the TPU compiler refuses a backend's kernel
+  (``None``: it compiles). ``auto`` never considers such a backend, on any
+  platform, and :func:`get` refuses to hand it out on the TPU — so the
+  refusal is raised before anything is compiled. ``tests/test_tpu_compile.py``
+  compiles every backend for a described v5e and holds each flag to the
+  compiler's verdict.
 """
 
 from __future__ import annotations
@@ -141,6 +147,9 @@ class BackendSpec:
     # additionally donates the staged batch buffers (the C accumulator
     # stacks) into the cores. None = backend has no batched cores to scope.
     make_batched_cores: Callable | None = None
+    # why Mosaic refuses to compile this backend's kernel for the TPU;
+    # None = it compiles (see the module docstring)
+    mosaic_refusal: str | None = None
 
     @property
     def supports_batched(self) -> bool:
@@ -207,11 +216,18 @@ def ensure_registered() -> None:
 
 def get(name: str) -> BackendSpec:
     """Resolve a backend name; unknown names raise the dispatcher's
-    canonical error."""
+    canonical error, and so does a backend whose kernel the TPU compiler
+    refuses when the default device is a TPU."""
+    import jax
+
     ensure_registered()
     spec = _REGISTRY.get(name)
     if spec is None:
         raise ValueError(f"unknown backend {name!r}")
+    if spec.mosaic_refusal is not None and jax.default_backend() == "tpu":
+        raise ValueError(
+            f"backend {name!r} does not compile for the TPU: "
+            f"{spec.mosaic_refusal}")
     return spec
 
 
@@ -233,8 +249,14 @@ def batched_backends() -> tuple:
 
 
 def accumulator_specs() -> tuple:
-    """Specs participating in the planner's ``auto`` resolve, priority order."""
+    """Specs with a planner byte model (accumulators), priority order."""
     return tuple(s for s in specs() if s.is_accumulator)
+
+
+def auto_specs() -> tuple:
+    """Specs the planner's ``auto`` resolve chooses among: the accumulators
+    the TPU compiler accepts, priority order."""
+    return tuple(s for s in accumulator_specs() if s.mosaic_refusal is None)
 
 
 def masked_backends() -> tuple:

@@ -42,14 +42,15 @@ The registered backends, in registry (= auto tie-break) order:
   (``symbolic.bsr_plan_caps``), so the whole *envelope* is the jit key.
 
 ``backend="auto"`` is the planner-driven dispatch over the registered
-accumulator backends: ``planner.select_accumulator_backend`` argmins their
-``BackendFastModel`` peak-resident byte models — dense slabs when C
-densifies, ESC when the expand stream is small, hash when outputs are wide
-but rows stay sparse, BSR when the operands are block-structured (its
-model prices the ``bs^2``-per-entry padding waste honestly, and an
-envelope without block caps prices it at infinity, keeping block analysis
-opt-in). The ``accumulator_shootout`` and ``bsr_blocking`` bench lanes
-measure where the models cross.
+accumulator backends the TPU compiler accepts:
+``planner.select_accumulator_backend`` argmins their ``BackendFastModel``
+peak-resident byte models — dense slabs, or BSR when the operands are
+block-structured (its model prices the ``bs^2``-per-entry padding waste
+honestly, and an envelope without block caps prices it at infinity, keeping
+block analysis opt-in). ``sparse`` and ``hash`` register a
+``mosaic_refusal``: Mosaic does not compile their kernels, so ``auto`` skips
+them and asking for them on a TPU raises. The ``accumulator_shootout`` and
+``bsr_blocking`` bench lanes measure where the models cross.
 
 Because a traced scan (or Pallas grid) cannot mutate Python-side counters,
 ChunkStats for these backends is *computed from the plan*: the uniform padding
@@ -100,7 +101,9 @@ from repro.kernels.bsr_spgemm import bsr_spgemm_blocks, bsr_spgemm_symbolic
 from repro.kernels.hash_accum_spgemm import (
     hash_accum_spgemm_stream, hash_masked_accum_spgemm_stream,
 )
-from repro.kernels.ranged_spgemm import default_interpret, ranged_spgemm_stream
+from repro.kernels.ranged_spgemm import (
+    default_interpret, ranged_spgemm_stream, stage_dims,
+)
 from repro.kernels.sparse_accum_spgemm import sparse_accum_spgemm_stream
 from repro.sparse.bsr import bsr_blocks_with_sentinel, bsr_from_dense
 from repro.sparse.csr import (
@@ -377,9 +380,12 @@ def planned_stats_pallas(plan: ChunkPlan, slab_nbytes: int, a_stage_nbytes: int,
     raise ValueError(f"unknown algorithm {plan.algorithm!r}")
 
 
-def _pallas_stage_nbytes(strip_rows: int, k: int, span: int, n: int) -> tuple:
-    """(slab, a_stage, c_stage) dense staged footprints in bytes (f32)."""
-    return span * n * 4, strip_rows * (k + span) * 4, strip_rows * n * 4
+def _pallas_stage_nbytes(strip_rows: int, k: int, chunk_rows: int,
+                         n: int) -> tuple:
+    """(slab, a_stage, c_stage) dense staged footprints in bytes (f32), at
+    the kernel's lane-aligned staging (``ranged_spgemm.stage_dims``)."""
+    k_pad, span, n_pad = stage_dims(k, chunk_rows, n)
+    return span * n_pad * 4, strip_rows * k_pad * 4, strip_rows * n_pad * 4
 
 
 # ---------------------------------------------------------------------------
@@ -442,13 +448,6 @@ def _dense_stack(stacked: CSR, levels: int = 1) -> jax.Array:
     return fn(stacked.indptr, stacked.indices, stacked.data)
 
 
-def _pad_cols(a: jax.Array, span: int) -> jax.Array:
-    """Zero-pad the last (column) axis by ``span`` so the kernel's ranged
-    slice of the final chunk never reads out of bounds."""
-    pad = [(0, 0)] * (a.ndim - 1) + [(0, span)]
-    return jnp.pad(a.astype(jnp.float32), pad)
-
-
 def _make_pallas_core(key: str, order: str, *, batched: bool, strips: bool):
     """One jitted staging-and-launch core; the six variants differ only in
     the streaming order, the trace-counter key, and whether A arrives as a
@@ -464,9 +463,8 @@ def _make_pallas_core(key: str, order: str, *, batched: bool, strips: bool):
     @jax.jit
     def core(Ast: CSR, Bst: CSR, r0s) -> jax.Array:
         TRACE_COUNTS[key] += 1
-        span = Bst.n_rows
-        a = _pad_cols(_dense_stack(Ast, levels=a_levels), span)
-        slabs = _dense_stack(Bst, levels=2 if batched else 1).astype(jnp.float32)
+        a = _dense_stack(Ast, levels=a_levels)
+        slabs = _dense_stack(Bst, levels=2 if batched else 1)
         if not strips:               # knl: the whole A is the single strip
             a = a[:, None] if batched else a[None]
         if not batched:              # width-1 batch axis
@@ -1521,6 +1519,10 @@ def _register_all() -> None:
         audit_trace=_make_audit_csr_accum("sparse"),
         traffic_model=_traffic_csr_accum,
         make_batched_cores=_make_sparse_batched_cores,
+        mosaic_refusal=(
+            "the ESC merge's argsort and scatter have no Mosaic lowering, "
+            "and its (1, 1, cap) CSR blocks break the (8, 128) block-shape "
+            "rule"),
     ))
     register(Spec(
         name="hash",
@@ -1535,6 +1537,9 @@ def _register_all() -> None:
         audit_trace=_make_audit_csr_accum("hash"),
         traffic_model=_traffic_csr_accum,
         make_batched_cores=_make_hash_batched_cores,
+        mosaic_refusal=(
+            "the probe loops' dynamic_slice has no Mosaic lowering, and its "
+            "(1, 1, cap) CSR blocks break the (8, 128) block-shape rule"),
     ))
     register(Spec(
         name="bsr",

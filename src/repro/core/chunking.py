@@ -282,8 +282,8 @@ def chunked_spgemm(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int | None = None,
     BackendSpec` (``"loop"``, ``"scan"``, ``"pallas"``, ``"sparse"``,
     ``"hash"``, ``"bsr"``, ...) or ``"auto"``, which lets the planner pick
     the accumulator backend whose peak-resident byte model is smallest under
-    this instance's envelope (``planner.select_accumulator_backend``). The
-    dispatch is entirely registry-driven: the spec supplies the
+    this instance's envelope (``planner.select_accumulator_backend``), and
+    raises when no candidate fits the kernels' fast limit. The dispatch is entirely registry-driven: the spec supplies the
     per-algorithm executor, and its capability flags decide what the
     dispatcher stages — ``needs_output_caps`` backends receive the symbolic
     phase's ``StripOutputCaps`` (one expansion amortized across the default

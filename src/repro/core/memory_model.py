@@ -117,12 +117,17 @@ P100 = MemorySystem(
     spgemm_core_rate=25e9,     # paper Fig 6/7 ceiling
 )
 
-# TPU v5e chip (the §Roofline constants mandated by the task):
-#   197 TFLOP/s bf16; 819 GB/s HBM (16 GiB); VMEM ~128 MiB at ~22 TB/s, ~ns latency.
-# fast=VMEM, slow=HBM: the on-chip two-level pair the Pallas kernels chunk across.
+# TPU v5e chip. Peaks from Google Cloud's "TPU v5e" documentation: 197
+# TFLOP/s bf16, 16 GB of HBM at 819 GB/s. fast=VMEM, slow=HBM: the on-chip
+# two-level pair the Pallas kernels chunk across. The fast capacity is the
+# planning limit, not the chip's 128 MiB of VMEM: the v5e compiler accepts a
+# scoped VMEM limit up to those 128 MiB (kernels/scoped_vmem.py, rehearsed
+# by compiling for a described v5e), and the Pallas pipeline holds two
+# buffers of every staged piece a plan counts once, so a plan may count 64.
+# VMEM bandwidth and both latencies are modeled, not measured.
 TPU_V5E = MemorySystem(
     name="tpu_v5e",
-    fast=MemoryLevel("VMEM", 128 * (1 << 20), 22e12, 30e-9, granularity_bytes=512),
+    fast=MemoryLevel("VMEM", 64 * (1 << 20), 22e12, 30e-9, granularity_bytes=512),
     slow=MemoryLevel("HBM", 16 * GiB, 819e9, 600e-9, granularity_bytes=512),
     copy_bandwidth_Bps=819e9,
     flops_peak=197e12,
@@ -142,6 +147,22 @@ TPU_HBM_Bps = 819e9
 TPU_PEAK_FLOPS = 197e12
 
 MACHINES = {"knl": KNL, "p100": P100, "tpu_v5e": TPU_V5E, "tpu_v5e_host": TPU_V5E_HOST}
+
+# The memory system of each accelerator this repository runs on, keyed by
+# ``jax.Device.device_kind``.
+DEVICE_SYSTEMS = {"TPU v5 lite": TPU_V5E}
+
+
+def memory_system_for(device_kind: str) -> MemorySystem:
+    """The memory system a plan for ``device_kind`` is sized against. A
+    device without an entry is an error: a plan sized for another chip's
+    memory would be refused by its compiler or waste it."""
+    try:
+        return DEVICE_SYSTEMS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no memory system for device kind {device_kind!r}; known: "
+            f"{sorted(DEVICE_SYSTEMS)}") from None
 
 
 # ---------------------------------------------------------------------------

@@ -120,7 +120,7 @@ def _spill_to_slow(T: CSR) -> CSR:
 
 def pipeline_spgemm(A: CSR, P: CSR, R: CSR, plan: PipelinePlan | None = None,
                     *, system=None, fast_limit_bytes: float | None = None,
-                    backend: str = "sparse", caps: PipelineCaps | None = None):
+                    backend: str = "scan", caps: PipelineCaps | None = None):
     """Execute ``C = R x (A x P)`` as a fused two-hop pipeline.
 
     Returns ``(C, PipelineStats)``. ``plan`` defaults to

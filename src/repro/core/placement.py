@@ -6,10 +6,10 @@ memory cannot hold the whole problem, placing **only B fast** recovers most of t
 fast-memory performance — *iff* B fits ("This method, DP, only works when B fits
 into HBM").
 
-On real TPU hardware placement is realized with ``jax.device_put`` +
-``memory_kind`` shardings (HBM vs pinned_host); on this CPU container the placement
-is recorded and its performance evaluated through the memory cost model, while the
-functional result is (trivially) identical — asserted in tests.
+Placement is realized with ``jax.device_put`` + ``memory_kind`` shardings
+(device memory vs ``pinned_host``); its performance is evaluated through the
+memory cost model, while the functional result is identical — asserted in
+tests.
 """
 
 from __future__ import annotations
@@ -100,19 +100,14 @@ def placement_cost(system: MemorySystem, placement: Placement, A: CSR, B: CSR,
 
 
 def place(operand, space: str, system_name: str = "tpu_v5e"):
-    """Physically place an operand pytree in a memory space.
-
-    On TPU runtimes, 'slow' maps to ``pinned_host`` memory kind and 'fast' to device
-    HBM. On backends without memory kinds (this CPU container) placement is a no-op
-    transfer and the cost is tracked analytically.
-    """
+    """Physically place an operand pytree in a memory space: 'slow' maps to
+    the ``pinned_host`` memory kind and 'fast' to device memory. A device
+    without the memory kind raises; the placement is never silently
+    dropped."""
     del system_name   # parity with the cost APIs; physical placement is kind-based
     if space not in SPACES:
         raise ValueError(f"space must be one of {SPACES}")
-    try:
-        dev = jax.devices()[0]
-        kind = "device" if space == "fast" else "pinned_host"
-        sharding = jax.sharding.SingleDeviceSharding(dev, memory_kind=kind)
-        return jax.device_put(operand, sharding)
-    except (ValueError, RuntimeError, NotImplementedError):
-        return jax.device_put(operand)
+    dev = jax.devices()[0]
+    kind = "device" if space == "fast" else "pinned_host"
+    sharding = jax.sharding.SingleDeviceSharding(dev, memory_kind=kind)
+    return jax.device_put(operand, sharding)

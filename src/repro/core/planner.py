@@ -235,11 +235,14 @@ def planned_stats_dense_slab(plan: ChunkPlan, envelope) -> BackendFastModel:
     """The dense-accumulator (``backend="pallas"``) resident footprint: the
     streamed/stationary pieces are dense f32 slabs and the C accumulator is a
     dense ``[strip_rows, n_cols]`` block per resident strip."""
+    from repro.kernels.ranged_spgemm import stage_dims
+
     k, n = envelope.a_shape[1], envelope.b_shape[1]
-    span, strip_rows = envelope.chunk_rows, envelope.strip_rows
-    slab = float(span * n * 4)                       # streamed B chunk
-    a_stage = float(strip_rows * (k + span) * 4)     # column-padded A strip
-    c_block = float(strip_rows * n * 4)
+    k_pad, span, n_pad = stage_dims(k, envelope.chunk_rows, n)
+    strip_rows = envelope.strip_rows
+    slab = float(span * n_pad * 4)                   # lane-aligned B window
+    a_stage = float(strip_rows * k_pad * 4)          # column-padded A strip
+    c_block = float(strip_rows * n_pad * 4)
     if plan.algorithm == "chunk2":
         streamed, stationary = a_stage, slab
         c_accum = plan.n_ac * c_block                # all partials persist
@@ -252,6 +255,87 @@ def planned_stats_dense_slab(plan: ChunkPlan, envelope) -> BackendFastModel:
         streamed_bytes=streamed, stationary_bytes=stationary,
         c_accum_bytes=c_accum, workspace_bytes=0.0,
     )
+
+
+def dense_slab_slow_bytes(plan: ChunkPlan, a_shape: tuple, b_shape: tuple) -> float:
+    """Slow-memory (HBM) bytes the dense-slab core holds at its peak: the
+    densified A strips before and after their column padding, the
+    lane-aligned B slabs, and the padded ``C_prev`` and result blocks (f32,
+    at ``ranged_spgemm.stage_dims``)."""
+    from repro.kernels.ranged_spgemm import stage_dims
+
+    strip_rows = max(e - s for s, e in zip(plan.p_ac[:-1], plan.p_ac[1:]))
+    chunk_rows = max(e - s for s, e in zip(plan.p_b[:-1], plan.p_b[1:]))
+    k, n = a_shape[1], b_shape[1]
+    k_pad, span, n_pad = stage_dims(k, chunk_rows, n)
+    rows = plan.n_ac * strip_rows
+    return 4.0 * (rows * (k + k_pad) + plan.n_b * span * n_pad
+                  + 2 * rows * n_pad)
+
+
+def plan_dense_slab(A: CSR, B: CSR, system: MemorySystem) -> ChunkPlan:
+    """Plan for the dense-slab accumulator (``backend="pallas"``): uniform
+    strips and chunks in the Chunk1 order, the fewest grid steps whose
+    :func:`planned_stats_dense_slab` footprint fits ``system``'s fast
+    level.
+
+    :func:`plan_chunks` sizes pieces by their CSR bytes, but this backend
+    stages every piece densified, so its plans are searched against its own
+    byte model, and against the scoped VMEM its kernel asks for
+    (``ranged_spgemm.vmem_need_bytes``: both buffers of every block and the
+    body's temporaries), which the model, counting each piece once, does
+    not bound. The densified operands also live whole in slow memory
+    (:func:`dense_slab_slow_bytes`); an operator whose staging cannot fit
+    there, or whose smallest pieces overflow the fast limit, raises."""
+    from repro.kernels.ranged_spgemm import vmem_need_bytes
+    from repro.kernels.scoped_vmem import VMEM_CAPACITY_BYTES
+
+    fast = float(system.fast.capacity_bytes)
+
+    def uniform(n, step):
+        return tuple(range(0, n, step)) + (n,)
+
+    best = None
+    for strip_rows in (1024, 512, 256, 128, 64, 32, 16, 8):
+        for chunk_rows in (16384, 8192, 4096, 2048, 1024, 512, 256, 128):
+            plan = ChunkPlan("chunk1", uniform(A.n_rows, strip_rows),
+                             uniform(B.n_rows, chunk_rows), 0.0, 0.0)
+            env = _DenseSlabEnvelope(A.shape, B.shape,
+                                     min(strip_rows, A.n_rows),
+                                     min(chunk_rows, B.n_rows))
+            model = planned_stats_dense_slab(plan, env)
+            kernel_need = vmem_need_bytes("chunk1", plan.n_ac, env.strip_rows,
+                                          A.n_cols, env.chunk_rows, B.n_cols)
+            if (model.fast_bytes_needed > fast
+                    or kernel_need > VMEM_CAPACITY_BYTES):
+                continue
+            steps = plan.n_ac * plan.n_b
+            if best is None or steps < best[0]:
+                best = (steps, plan, model)
+    if best is None:
+        raise ValueError(f"no dense-slab plan for A{A.shape} x B{B.shape} "
+                         f"fits the fast limit of {fast:.0f} B")
+    _, plan, model = best
+    slow = dense_slab_slow_bytes(plan, A.shape, B.shape)
+    if slow > system.slow.capacity_bytes:
+        raise ValueError(
+            f"dense-slab staging of A{A.shape} x B{B.shape} needs {slow:.0f} "
+            f"B of {system.slow.name}, more than its "
+            f"{system.slow.capacity_bytes:.0f} B")
+    copy = (plan.n_ac * (model.stationary_bytes + 2 * model.c_accum_bytes)
+            + plan.n_ac * plan.n_b * model.streamed_bytes)
+    return dataclasses.replace(plan, copy_bytes=float(copy),
+                               fast_bytes_needed=model.fast_bytes_needed)
+
+
+@dataclasses.dataclass(frozen=True)
+class _DenseSlabEnvelope:
+    """The geometry :func:`planned_stats_dense_slab` reads from an envelope."""
+
+    a_shape: tuple
+    b_shape: tuple
+    strip_rows: int
+    chunk_rows: int
 
 
 def _csr_accum_model(plan: ChunkPlan, envelope, backend: str,
@@ -398,17 +482,46 @@ def backend_fast_models(plan: ChunkPlan, envelope) -> dict:
             for s in backend_registry.accumulator_specs()}
 
 
+def kernel_fast_limit() -> float:
+    """The fast-memory limit a Pallas kernel's plan must fit: the planning
+    limit of the default device's memory system on a TPU. Elsewhere the
+    kernels run in the interpreter under the same scoped-VMEM caps as on
+    the v5e (``kernels/scoped_vmem``), so the v5e's limit binds there too."""
+    import jax
+
+    from repro.core.memory_model import TPU_V5E, memory_system_for
+
+    system = TPU_V5E
+    if jax.default_backend() == "tpu":
+        system = memory_system_for(jax.devices()[0].device_kind)
+    return system.fast.capacity_bytes
+
+
 def select_accumulator_backend(plan: ChunkPlan, envelope) -> str:
     """The ``backend="auto"`` rule: run the accumulator whose modeled peak
-    resident fast-memory footprint is smallest under this plan + envelope —
-    dense slab (``pallas``) vs ESC CSR scratch (``sparse``) vs hash probe
-    (``hash``) vs blocked MXU tiles (``bsr``, only under block-capped
-    envelopes — uncapped ones price it at infinity). Ties break toward the
-    earlier registry entry (dense slab first: on real hardware it is the
-    MXU-shaped one). This is the per-geometry accumulator choice ROADMAP
-    asked the planner to make instead of picking one unconditionally."""
-    models = backend_fast_models(plan, envelope)
-    return min(models, key=lambda b: models[b].fast_bytes_needed)
+    resident fast-memory footprint is smallest under this plan + envelope,
+    among those the TPU compiler accepts (``backend_registry.auto_specs``)
+    and whose footprint fits :func:`kernel_fast_limit` — today dense slab
+    (``pallas``) vs blocked MXU tiles (``bsr``, only under block-capped envelopes — uncapped ones
+    price it at infinity). The choice is the same on every platform, so
+    CPU runs pick what the chip runs. Ties break toward the earlier
+    registry entry (dense slab first). A plan that no candidate fits
+    raises, naming each candidate's bytes."""
+    from repro.core import backend_registry
+
+    fast = float(kernel_fast_limit())
+    models = {s.name: s.byte_model(plan, envelope)
+              for s in backend_registry.auto_specs()}
+    fits = [b for b in models if models[b].fast_bytes_needed <= fast]
+    if not fits:
+        needs = ", ".join(f"{b} {models[b].fast_bytes_needed:.0f} B"
+                          for b in models)
+        raise ValueError(
+            f"backend='auto': no compiled backend fits this {plan.algorithm} "
+            f"plan ({plan.n_ac}x{plan.n_b}) in the fast limit of {fast:.0f} B "
+            f"({needs}); plan for the dense-slab backend with "
+            f"planner.plan_dense_slab, or run backend='scan'")
+    return min(fits, key=lambda b: models[b].fast_bytes_needed)
 
 
 def check_output_caps(strip_nnz, c_max_row_nnz: int, c_pad: int,

@@ -30,8 +30,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
+from repro.kernels import scoped_vmem
 from repro.sparse.bsr import BSR
 
 
@@ -152,6 +151,8 @@ def _kernel(a_slots_ref, b_slots_ref, a_blocks_ref, b_blocks_ref, out_ref, acc_r
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    # HIGHEST: on the TPU the default f32 matmul rounds its operands to
+    # bf16, which thins the product's structure where terms cancel
     if skip_zero:
         e = pl.program_id(0)
         valid = a_slots_ref[e, u] != a_zero_slot
@@ -159,11 +160,13 @@ def _kernel(a_slots_ref, b_slots_ref, a_blocks_ref, b_blocks_ref, out_ref, acc_r
         @pl.when(valid)
         def _mac():
             acc_ref[...] += jnp.dot(
-                a_blocks_ref[0], b_blocks_ref[0], preferred_element_type=jnp.float32
+                a_blocks_ref[0], b_blocks_ref[0], preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.HIGHEST,
             )
     else:
         acc_ref[...] += jnp.dot(
-            a_blocks_ref[0], b_blocks_ref[0], preferred_element_type=jnp.float32
+            a_blocks_ref[0], b_blocks_ref[0], preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,
         )
 
     @pl.when(u == u_max - 1)
@@ -199,7 +202,10 @@ def bsr_spgemm_blocks(a_blocks: jax.Array, b_blocks: jax.Array, a_slots: jax.Arr
             scratch_shapes=[pltpu.VMEM((bs, bs), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((nc_pad, bs, bs), out_dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=scoped_vmem.compiler_params(
+            blocked=[((1, bs, bs), a_blocks.dtype), ((1, bs, bs), b_blocks.dtype),
+                     ((1, bs, bs), out_dtype)],
+            scratch=[((bs, bs), jnp.float32)],
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -18,7 +18,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
 from repro.sparse.bsr import BSR
 
@@ -83,7 +82,7 @@ def bsr_spmm_blocks(a_blocks: jax.Array, x: jax.Array, a_slots: jax.Array,
             scratch_shapes=[pltpu.VMEM((bs, bn), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((mb * bs, nf), out_dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
 NEG_INF = -1e30
 
@@ -87,7 +86,7 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array, lengths: jax.Arra
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((bsz, hkv, g, d), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

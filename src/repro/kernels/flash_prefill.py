@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
 NEG_INF = -1e30
 
@@ -110,7 +109,7 @@ def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq * g, 1), jnp.float32),
             pltpu.VMEM((bq * g, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),

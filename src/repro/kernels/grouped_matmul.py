@@ -20,7 +20,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
 
 def _kernel(gid_ref, x_ref, w_ref, out_ref, acc_ref, *, nk: int):
@@ -63,7 +62,7 @@ def grouped_matmul_padded(x: jax.Array, w: jax.Array, tile_group: jax.Array,
             scratch_shapes=[pltpu.VMEM((bt, bn), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((t_pad, ndim), out_dtype or x.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -286,6 +286,12 @@ def hash_masked_merge_impl(A: CSR, B_chunk: CSR, r0, r1, C_prev: CSR,
     return CSR(indptr, indices, data, (m, B_chunk.n_cols), c_pad)
 
 
+def _table_bytes(Ast: CSR, C0st: CSR, table_size: int) -> int:
+    """The merge's workspace: one key/value table of ``table_size`` slots
+    per strip row (the ``planner.planned_stats_hash`` workspace term)."""
+    return Ast.shape[0] * table_size * (4 + C0st.data.dtype.itemsize)
+
+
 def hash_masked_accum_spgemm_stream(Ast: CSR, Bst: CSR, C0st: CSR,
                                     mask_st: CSR, r0s: jax.Array,
                                     r1s: jax.Array, *, order: str,
@@ -304,7 +310,9 @@ def hash_masked_accum_spgemm_stream(Ast: CSR, Bst: CSR, C0st: CSR,
     merge = functools.partial(hash_masked_merge_impl, table_size=table_size)
     return sparse_accum_spgemm_stream(Ast, Bst, C0st, r0s, r1s, order=order,
                                       interpret=interpret, merge_fn=merge,
-                                      mask_st=mask_st)
+                                      mask_st=mask_st,
+                                      workspace_bytes=_table_bytes(
+                                          Ast, C0st, table_size))
 
 
 def hash_accum_spgemm_stream(Ast: CSR, Bst: CSR, C0st: CSR,
@@ -324,4 +332,6 @@ def hash_accum_spgemm_stream(Ast: CSR, Bst: CSR, C0st: CSR,
                          ">= 1 (use planner.hash_table_slots)")
     merge = functools.partial(hash_merge_impl, table_size=table_size)
     return sparse_accum_spgemm_stream(Ast, Bst, C0st, r0s, r1s, order=order,
-                                      interpret=interpret, merge_fn=merge)
+                                      interpret=interpret, merge_fn=merge,
+                                      workspace_bytes=_table_bytes(
+                                          Ast, C0st, table_size))

@@ -1,8 +1,8 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 Each wrapper does the host-side symbolic planning (NumPy), appends the zero-sentinel
-blocks, and dispatches the pallas_call. ``interpret`` defaults to True off-TPU so the
-same code path validates on this CPU container and compiles on real hardware.
+blocks, and dispatches the pallas_call. ``interpret`` defaults to
+``default_interpret()``: the interpreter on the CPU, a Mosaic compile on the TPU.
 """
 
 from __future__ import annotations
@@ -16,10 +16,7 @@ from repro.kernels import bsr_spgemm as _spgemm
 from repro.kernels import bsr_spmm as _spmm
 from repro.kernels import grouped_matmul as _gmm
 from repro.kernels import chunked_attention as _attn
-
-
-def default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+from repro.kernels.ranged_spgemm import default_interpret
 
 
 def _with_zero_block(blocks: jax.Array) -> jax.Array:

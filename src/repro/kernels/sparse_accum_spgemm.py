@@ -25,7 +25,7 @@ final strip structure, the scratch can never overflow mid-stream.
 The streaming schedule is the same explicit two-slot DMA pattern as
 ``ranged_spgemm_stream``: the stationary operand (the A strip in the Chunk1
 order, the B chunk in Chunk2) rides a normal blocked ``BlockSpec``; the
-streamed operand's CSR triple lives in slow memory (``pltpu.ANY``) and is
+streamed operand's CSR triple lives in slow memory (``pl.ANY``) and is
 hand-DMA'd — three async copies per element, one per CSR field — through
 ``[2, ...]`` VMEM scratch buffers, starting element j+1 while element j
 multiplies. Scalar-prefetched ``r0s``/``r1s`` realize the ranged column skip.
@@ -47,8 +47,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.kkmem import spgemm_ranged_impl
-from repro.kernels import dma_schedule
-from repro.kernels._compat import ANY as _ANY
+from repro.kernels import dma_schedule, scoped_vmem
 # shared with the dense-slab streaming kernel: same interpret heuristic, same
 # linear-grid decomposition, same slot schedule (the two kernels are one DMA
 # pattern — kernels/dma_schedule — two accumulators)
@@ -173,7 +172,8 @@ def _kernel(r0s_ref, r1s_ref, *refs, order: str, batch: int,
 def sparse_accum_spgemm_stream(Ast: CSR, Bst: CSR, C0st: CSR,
                                r0s: jax.Array, r1s: jax.Array, *, order: str,
                                interpret: bool | None = None,
-                               merge_fn=None, mask_st: CSR | None = None):
+                               merge_fn=None, mask_st: CSR | None = None,
+                               workspace_bytes: float | None = None):
     """Streamed sparse-output multiply over stacked CSR strips and chunks.
 
     Args:
@@ -201,6 +201,9 @@ def sparse_accum_spgemm_stream(Ast: CSR, Bst: CSR, C0st: CSR,
         and ``merge_fn`` must then accept them appended: ``(A, B_chunk, r0,
         r1, C_prev, c_cap, mask_indptr, mask_indices) -> CSR`` (the masked
         hash merge). ``C0st``'s capacity must bound every strip's mask nnz.
+      workspace_bytes: the merge body's largest temporaries, for the
+        kernel's VMEM limit; defaults to the ESC expand buffer
+        (``a_cap * b_max_row_nnz + c_cap`` slots of row, column, value).
 
     Returns ``(indptr, indices, data)`` with leading ``[batch, n_ac]`` axes —
     the accumulated C strip CSRs at capacity ``c_cap``.
@@ -241,7 +244,7 @@ def sparse_accum_spgemm_stream(Ast: CSR, Bst: CSR, C0st: CSR,
     def blocked(trail, index_map):
         return pl.BlockSpec((1, 1) + trail, index_map)
 
-    any_spec = pl.BlockSpec(memory_space=_ANY)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
     if order == "chunk1":
         grid = (batch, n_ac, n_b)
         stat = Ast
@@ -286,6 +289,15 @@ def sparse_accum_spgemm_stream(Ast: CSR, Bst: CSR, C0st: CSR,
                 pltpu.VMEM((ns, a_cap), jnp.int32),
                 pltpu.VMEM((ns, a_cap), dtype)]
 
+    if workspace_bytes is None:
+        esc_slots = a_cap * max(Bst.max_row_nnz, 1) + c_cap
+        workspace_bytes = esc_slots * (8 + jnp.dtype(dtype).itemsize)
+    blocked = [(spec.block_shape, op.dtype) for spec, op in zip(
+        [*stat_specs, *c0_specs, *out_specs, *mask_specs],
+        [stat.indptr, stat.indices, stat.data,
+         C0st.indptr, C0st.indices, C0st.data,
+         C0st.indptr, C0st.indices, C0st.data,
+         *([mask_st.indptr, mask_st.indices] if masked else [])])]
     kernel = functools.partial(
         _kernel, order=order, batch=batch, n_ac=n_ac, n_b=n_b,
         strip_rows=strip_rows, chunk_rows=chunk_rows, k_cols=k_cols,
@@ -314,5 +326,8 @@ def sparse_accum_spgemm_stream(Ast: CSR, Bst: CSR, C0st: CSR,
                             pltpu.SemaphoreType.DMA((dma_schedule.N_SLOTS, 3))],
         ),
         out_shape=out_shape,
+        compiler_params=scoped_vmem.compiler_params(
+            blocked=blocked, scratch=[(b.shape, b.dtype) for b in bufs],
+            workspace=workspace_bytes),
         interpret=interpret,
     )(*operands)

@@ -11,13 +11,9 @@ import jax
 
 
 def mesh_kwargs(n_axes: int) -> dict:
-    """jax >= 0.5 takes axis_types=(AxisType.Auto, ...); older jax has neither
-    the enum nor the kwarg — explicit-sharding mode simply doesn't exist there,
-    so omitting it is the exact equivalent."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+    """``jax.make_mesh`` keyword arguments: every axis in automatic
+    (not explicit) sharding mode."""
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -1,25 +1,16 @@
-"""Shared test fixtures and hypothesis strategies.
-
-``hypothesis`` is optional: hermetic environments don't have it. When absent,
-test modules that do ``from conftest import given, settings, st`` degrade
-gracefully —
-
-  * ``@given(csr_pair(...))`` (all arguments seeded-example providers) becomes
-    a deterministic ``pytest.mark.parametrize`` over a handful of seeded
-    (A, B) pairs, so the core SpGEMM properties still run;
-  * ``@given(...)`` over generic strategies (``st.lists``/``st.integers``/...)
-    auto-skips with an explanatory reason.
+"""Shared test fixtures and hypothesis strategies (test modules import
+``given``, ``settings`` and ``st`` from here).
 
 NOTE: no XLA_FLAGS here on purpose — tests must see exactly 1 CPU device
 (only launch/dryrun.py requests 512 placeholder devices).
 """
 
-import inspect
-
 import jax
 import numpy as np
 import pytest
 import jax.numpy as jnp  # noqa: F401  (re-exported convenience for tests)
+# re-exported: test modules import given/settings/st from conftest
+from hypothesis import given, settings, strategies as st  # noqa: F401
 
 from repro.sparse.csr import CSR, csr_from_dense
 
@@ -37,14 +28,6 @@ def _release_compiled_executables_between_modules():
     yield
     jax.clear_caches()
 
-try:
-    # re-exported: test modules import given/settings/st from conftest
-    from hypothesis import given, settings, strategies as st  # noqa: F401
-
-    HAVE_HYPOTHESIS = True
-except ModuleNotFoundError:
-    HAVE_HYPOTHESIS = False
-
 
 def random_dense(rng, m, n, density):
     d = (rng.random((m, n)) < density) * rng.standard_normal((m, n))
@@ -58,8 +41,8 @@ def random_csr(rng, m, n, density, pad_extra=0) -> CSR:
 
 
 def csr_pair_cases(n_examples=8, max_dim=24, seed=0):
-    """Deterministic (A, B) pairs with compatible inner dims — the seeded
-    fallback behind ``csr_pair`` and directly usable with parametrize."""
+    """Deterministic (A, B) pairs with compatible inner dims, for
+    parametrized tests."""
     rng = np.random.default_rng(seed)
     out = []
     for _ in range(n_examples):
@@ -72,61 +55,18 @@ def csr_pair_cases(n_examples=8, max_dim=24, seed=0):
     return out
 
 
-if HAVE_HYPOTHESIS:
-
-    @st.composite
-    def csr_pair(draw, max_dim=24):
-        """(A, B) with compatible inner dims for C = A x B."""
-        m = draw(st.integers(1, max_dim))
-        k = draw(st.integers(1, max_dim))
-        n = draw(st.integers(1, max_dim))
-        seed = draw(st.integers(0, 2**31 - 1))
-        da = draw(st.floats(0.05, 0.6))
-        db = draw(st.floats(0.05, 0.6))
-        rng = np.random.default_rng(seed)
-        return (random_csr(rng, m, k, da, pad_extra=draw(st.integers(0, 7))),
-                random_csr(rng, k, n, db, pad_extra=draw(st.integers(0, 7))))
-
-else:
-
-    class _SeededExamples:
-        """Concrete examples standing in for a strategy (fallback mode)."""
-
-        def __init__(self, values):
-            self.values = values
-
-    def csr_pair(max_dim=24):
-        return _SeededExamples(csr_pair_cases(n_examples=6, max_dim=max_dim))
-
-    def settings(*_args, **_kwargs):
-        return lambda fn: fn
-
-    def given(*strategies, **kwargs):
-        if (strategies and not kwargs
-                and all(isinstance(s, _SeededExamples) for s in strategies)):
-            def deco(fn):
-                # hypothesis fills the RIGHTMOST parameters (fixtures precede)
-                names = list(inspect.signature(fn).parameters)[-len(strategies):]
-                cases = list(zip(*(s.values for s in strategies)))
-                if len(names) == 1:
-                    cases = [c[0] for c in cases]
-                return pytest.mark.parametrize(
-                    ",".join(names), cases,
-                    ids=[f"seeded{i}" for i in range(len(cases))],
-                )(fn)
-
-            return deco
-        return lambda fn: pytest.mark.skip(
-            reason="hypothesis not installed (property test auto-skipped)"
-        )(fn)
-
-    class _StrategyNamespace:
-        """Opaque stand-ins so module-level strategy expressions still build."""
-
-        def __getattr__(self, _name):
-            return lambda *a, **k: None
-
-    st = _StrategyNamespace()
+@st.composite
+def csr_pair(draw, max_dim=24):
+    """(A, B) with compatible inner dims for C = A x B."""
+    m = draw(st.integers(1, max_dim))
+    k = draw(st.integers(1, max_dim))
+    n = draw(st.integers(1, max_dim))
+    seed = draw(st.integers(0, 2**31 - 1))
+    da = draw(st.floats(0.05, 0.6))
+    db = draw(st.floats(0.05, 0.6))
+    rng = np.random.default_rng(seed)
+    return (random_csr(rng, m, k, da, pad_extra=draw(st.integers(0, 7))),
+            random_csr(rng, k, n, db, pad_extra=draw(st.integers(0, 7))))
 
 
 @pytest.fixture

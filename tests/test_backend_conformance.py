@@ -77,6 +77,8 @@ def test_registry_completeness():
                                                    "hash", "bsr")
     assert tuple(s.name for s in backend_registry.accumulator_specs()) == (
         "pallas", "sparse", "hash", "bsr")
+    assert tuple(s.name for s in backend_registry.auto_specs()) == (
+        "pallas", "bsr")
     with pytest.raises(ValueError, match="unknown backend"):
         backend_registry.get("nope")
 
@@ -416,10 +418,10 @@ def test_undercapped_hash_table_raises():
 
 
 def _auto_geometries(rng):
-    """Three geometries whose minimum-byte accumulator provably differs:
-    dense narrow C (dense slab wins), wide sparse C with a fat product
-    expansion (hash wins), near-diagonal tall operands (tiny ESC expand
-    stream beats the row-count-scaled hash tables)."""
+    """Three geometries that stress different byte-model terms: dense narrow
+    C, wide sparse C with a fat product expansion (hash tables beat the ESC
+    workspace), near-diagonal tall operands (tiny ESC expand stream beats
+    the row-count-scaled hash tables)."""
     dense_a = csr_from_dense(random_dense(rng, 24, 16, 0.6))
     dense_b = csr_from_dense(random_dense(rng, 16, 12, 0.6))
     dense_plan = ChunkPlan("chunk1", (0, 12, 24), (0, 8, 16), 0.0, 0.0)
@@ -443,22 +445,28 @@ def _auto_geometries(rng):
 
 
 def test_auto_selects_min_resident_bytes_backend():
-    """Acceptance: on three geometries with three different byte-model
-    winners, ``backend="auto"`` (i) resolves to the argmin of the three
-    planner models, (ii) runs exactly that backend's core (trace-counted),
-    and (iii) stays oracle-correct. Together the three cases cover every
-    accumulator being chosen at least once."""
+    """Acceptance: on three geometries, ``backend="auto"`` (i) resolves to
+    the argmin of the planner models of the accumulators the TPU compiler
+    accepts, (ii) runs exactly that backend's core (trace-counted), and
+    (iii) stays oracle-correct. The byte models alone would pick the CSR
+    accumulators (``sparse``, ``hash``) on these geometries; their
+    ``mosaic_refusal`` is what keeps ``auto`` off them."""
     from repro.core.planner import backend_fast_models
 
+    lowerable = {s.name for s in backend_registry.auto_specs()}
+    assert lowerable == {"pallas", "bsr"}
     rng = np.random.default_rng(500)
-    winners = {}
+    winners, byte_winners = {}, {}
     for name, A, B, plan in _auto_geometries(rng):
         c_pad = default_c_pad(A, B, plan)
         env = instance_envelope(A, B, plan, c_pad=c_pad)
         models = backend_fast_models(plan, env)
         pick = select_accumulator_backend(plan, env)
+        assert pick in lowerable, name
         assert models[pick].fast_bytes_needed == min(
-            m.fast_bytes_needed for m in models.values()), name
+            models[b].fast_bytes_needed for b in lowerable), name
+        byte_winners[name] = min(models,
+                                 key=lambda b: models[b].fast_bytes_needed)
         key = TRACE_KEYS[pick].format(alg=plan.algorithm)
         before = TRACE_COUNTS[key]
         C, _ = chunked_spgemm(A, B, plan, c_pad, backend="auto")
@@ -469,7 +477,9 @@ def test_auto_selects_min_resident_bytes_backend():
         assert_close(csr_to_dense(C), spgemm_dense_oracle(A, B), atol=1e-4,
                      msg=f"auto/{name}")
         winners[name] = pick
-    assert set(winners.values()) == {"pallas", "sparse", "hash"}, winners
+    # uncapped envelopes price bsr at infinity, so the dense slab runs
+    assert set(winners.values()) == {"pallas"}, winners
+    assert set(byte_winners.values()) == {"sparse", "hash"}, byte_winners
 
 
 # ---------------------------------------------------------------------------

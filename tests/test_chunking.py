@@ -288,3 +288,62 @@ def test_replan_for_latency_coarsens_streamed_partition():
     # odd chunk counts round up: 5 -> 3
     odd = ChunkPlan("knl", (0, 4), (0, 1, 2, 3, 4, 5), 0.0, 1.0)
     assert replan_for_latency(odd).p_b == (0, 2, 4, 5)
+
+
+def test_plan_dense_slab_fits_its_kernel_and_runs_under_auto():
+    """The dense-slab planner sizes strips and chunks to its backend's own
+    byte model and to the scoped VMEM its kernel asks for, and ``auto``
+    resolves such a plan to that backend."""
+    from repro.core.chunking import instance_envelope
+    from repro.core.memory_model import TPU_V5E
+    from repro.core.planner import (
+        dense_slab_slow_bytes, plan_dense_slab, select_accumulator_backend,
+    )
+    from repro.kernels.ranged_spgemm import vmem_need_bytes
+    from repro.kernels.scoped_vmem import VMEM_CAPACITY_BYTES
+
+    A, _, P = multigrid.problem("brick3d", 12)
+    plan = plan_dense_slab(A, P, TPU_V5E)
+    assert plan.fast_bytes_needed <= TPU_V5E.fast.capacity_bytes
+    strip = max(np.diff(plan.p_ac))
+    chunk = max(np.diff(plan.p_b))
+    assert vmem_need_bytes("chunk1", plan.n_ac, strip, A.n_cols, chunk,
+                           P.n_cols) <= VMEM_CAPACITY_BYTES
+    assert (dense_slab_slow_bytes(plan, A.shape, P.shape)
+            <= TPU_V5E.slow.capacity_bytes)
+    env = instance_envelope(A, P, plan)
+    assert select_accumulator_backend(plan, env) == "pallas"
+    C, _ = chunked_spgemm(A, P, plan, backend="auto")
+    assert_close(csr_to_dense(C), spgemm_dense_oracle(A, P), atol=1e-4)
+
+
+def test_plan_dense_slab_refuses_staging_beyond_slow_memory():
+    from repro.core.memory_model import TPU_V5E
+    from repro.core.planner import plan_dense_slab
+
+    A, _, P = multigrid.problem("brick3d", 36)   # dense A alone is 8.7 GB
+    with pytest.raises(ValueError, match="of HBM, more than"):
+        plan_dense_slab(A, P, TPU_V5E)
+
+
+@pytest.mark.parametrize("fast_limit,fits", [(2 ** 18, True),
+                                             (2 ** 20, False)])
+def test_auto_on_row_byte_plan_runs_or_names_the_misfit(fast_limit, fits):
+    """``plan_chunks`` sizes pieces by CSR bytes; the dense slab of such a
+    plan may or may not fit the kernels' fast limit. ``auto`` runs it when
+    it does and raises a planning error, before any staging, when no
+    compiled backend fits."""
+    from repro.core.memory_model import TPU_V5E
+    from repro.core.symbolic import spgemm_pattern_host
+
+    A, _, P = multigrid.problem("brick3d", 16)
+    T = spgemm_pattern_host(A, P)
+    plan = plan_chunks(A, P, row_bytes_csr(T), TPU_V5E,
+                       fast_limit_bytes=fast_limit)
+    assert plan.algorithm == "chunk2"
+    if fits:
+        C, _ = chunked_spgemm(A, P, plan, backend="auto")
+        assert_close(csr_to_dense(C), spgemm_dense_oracle(A, P), atol=1e-4)
+    else:
+        with pytest.raises(ValueError, match="no compiled backend fits"):
+            chunked_spgemm(A, P, plan, backend="auto")

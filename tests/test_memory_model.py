@@ -2,7 +2,9 @@
 
 import pytest
 
-from repro.core.memory_model import KNL, P100, TPU_V5E, spgemm_cost
+from repro.core.memory_model import (
+    KNL, P100, TPU_V5E, memory_system_for, spgemm_cost,
+)
 from repro.core.placement import (
     Placement, ALL_FAST, ALL_SLOW, DP, dp_recommendation, placement_cost,
 )
@@ -92,6 +94,17 @@ def test_latency_vs_bandwidth_terms():
 
 
 def test_tpu_preset_constants():
+    from repro.kernels.scoped_vmem import VMEM_CAPACITY_BYTES
+
     assert TPU_V5E.flops_peak == 197e12
     assert abs(TPU_V5E.slow.bandwidth_Bps - 819e9) < 1e6
-    assert TPU_V5E.fast.capacity_bytes == 128 * (1 << 20)
+    # plans count each staged piece once; the pipeline holds two of each
+    assert TPU_V5E.fast.capacity_bytes == 64 * (1 << 20)
+    assert 2 * TPU_V5E.fast.capacity_bytes == VMEM_CAPACITY_BYTES
+
+
+def test_memory_system_by_device_kind():
+    assert memory_system_for("TPU v5 lite") is TPU_V5E
+    for kind in ("cpu", "TPU v4", "TPU v6 lite"):
+        with pytest.raises(ValueError, match="no memory system"):
+            memory_system_for(kind)

@@ -4,7 +4,7 @@ Three contracts from the pipeline work:
 
 * **Galerkin triple product** — ``C = R x (A x P)`` through the pipeline
   executor equals the dense oracle on all four multigrid ``PROBLEMS``,
-  through both the sparse (ESC) and hash chunked backends, on the resident
+  through the scan, sparse (ESC) and hash chunked backends, on the resident
   and the forced-spill paths; and the resident/spill answers agree with
   each other bitwise in structure (the composed symbolic phase is exact, so
   chunking must never change C's pattern).
@@ -45,7 +45,7 @@ def _tight_limit(A, P, R, frac):
     return float(A.nbytes() + P.nbytes() + R.nbytes()) * frac
 
 
-@pytest.mark.parametrize("backend", ["sparse", "hash"])
+@pytest.mark.parametrize("backend", ["scan", "sparse", "hash"])
 @pytest.mark.parametrize("name", multigrid.PROBLEMS)
 def test_pipeline_matches_dense_oracle(name, backend):
     """R x (A x P) on every problem, default (ample) fast budget."""
@@ -56,7 +56,7 @@ def test_pipeline_matches_dense_oracle(name, backend):
     assert stats.spilled is (not stats.plan.t_resident)
 
 
-@pytest.mark.parametrize("backend", ["sparse", "hash"])
+@pytest.mark.parametrize("backend", ["scan", "sparse", "hash"])
 def test_pipeline_chunked_regime_matches_dense_oracle(backend):
     """A fast budget tight enough to force chunked hops (and possibly a
     spilled intermediate) must not change the answer."""

@@ -26,7 +26,6 @@ from repro.analysis import corpus
 from repro.core import backend_registry
 from repro.core.backend_registry import BackendSpec, TraceTarget
 from repro.core.chunking import instance_envelope
-from repro.kernels._compat import ANY as _ANY
 from repro.kernels.dma_schedule import SlotSchedule, TWO_SLOT
 from repro.kernels.hash_accum_spgemm import probe_step_bound
 
@@ -226,7 +225,7 @@ def _toy_missing_wait_core():
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=0,
                 grid=(1,),
-                in_specs=[pl.BlockSpec(memory_space=_ANY)],
+                in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
                 out_specs=pl.BlockSpec(x.shape, lambda i: (0, 0)),
                 scratch_shapes=[
                     pltpu.VMEM((2,) + x.shape, jnp.float32),
