@@ -106,7 +106,7 @@ def run_triangle_counting(smoke: bool = False) -> dict:
         "backend": backend,
         "interpret_mode": default_interpret(),
         "smoke": smoke,
-        # lane-level scalar so tools/bench_trajectory.py keeps it verbatim
+        # lane-level scalar, which the CI smoke step asserts on
         "chunked_vs_kkmem_speedup": round(statistics.median(
             r["chunked_vs_kkmem"] for r in rows), 3),
         "rows": rows,

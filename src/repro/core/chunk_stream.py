@@ -96,6 +96,7 @@ from repro.core.planner import (
     planned_stats_bsr, planned_stats_dense_slab, planned_stats_hash,
     planned_stats_sparse, select_accumulator_backend,
 )
+from repro.core.spans import span
 from repro.core.symbolic import masked_output_caps, strip_output_caps
 from repro.kernels.bsr_spgemm import bsr_spgemm_blocks, bsr_spgemm_symbolic
 from repro.kernels.hash_accum_spgemm import (
@@ -393,40 +394,48 @@ def _pallas_stage_nbytes(strip_rows: int, k: int, chunk_rows: int,
 # ---------------------------------------------------------------------------
 
 
-def chunk_knl_scan(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int):
+def _stage(A: CSR | None, B: CSR, plan: ChunkPlan, sp) -> tuple:
+    """On the device: the strips of ``A`` (none for ``None``) and the chunks
+    of ``B``, each as a list and as a stack, and the plan's B-row ranges."""
+    strips = None if A is None else a_strips(A, plan.p_ac)
     chunks = b_chunks(B, plan.p_b)
-    Bs = csr_stack(chunks)
-    r0s, r1s = plan.b_ranges()
-    C0 = _empty_c(A.n_rows, B.n_cols, c_pad, A.dtype)
-    C = _knl_scan(A, Bs, jnp.asarray(r0s), jnp.asarray(r1s), C0, c_pad)
+    r0s, r1s = sp.put(plan.b_ranges())
+    As = None if strips is None else csr_stack(strips)
+    return strips, chunks, As, csr_stack(chunks), r0s, r1s
+
+
+def chunk_knl_scan(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int):
+    with span("stage") as sp:
+        _, chunks, _, Bs, r0s, r1s = _stage(None, B, plan, sp)
+        C0 = _empty_c(A.n_rows, B.n_cols, c_pad, A.dtype)
+    with span("launch"):
+        C = _knl_scan(A, Bs, r0s, r1s, C0, c_pad)
     stats = planned_stats(plan, chunks[0].nbytes(), 0, 0)
     return C, stats
 
 
 def chunk_gpu1_scan(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int):
-    strips = a_strips(A, plan.p_ac)
-    chunks = b_chunks(B, plan.p_b)
-    As, Bs = csr_stack(strips), csr_stack(chunks)
-    r0s, r1s = plan.b_ranges()
-    strip_rows = strips[0].n_rows
-    C0 = _empty_c(strip_rows, B.n_cols, c_pad, A.dtype)
-    Cs = _chunk1_scan(As, Bs, jnp.asarray(r0s), jnp.asarray(r1s), C0, c_pad)
+    with span("stage") as sp:
+        strips, chunks, As, Bs, r0s, r1s = _stage(A, B, plan, sp)
+        strip_rows = strips[0].n_rows
+        C0 = _empty_c(strip_rows, B.n_cols, c_pad, A.dtype)
+    with span("launch"):
+        Cs = _chunk1_scan(As, Bs, r0s, r1s, C0, c_pad)
     stats = planned_stats(plan, chunks[0].nbytes(), strips[0].nbytes(),
                           _c_strip_nbytes(strip_rows, c_pad, A.dtype))
-    return _assemble(csr_unstack(Cs), plan.p_ac, B.n_cols), stats
+    return _assemble(Cs, plan.p_ac, B.n_cols), stats
 
 
 def chunk_gpu2_scan(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int):
-    strips = a_strips(A, plan.p_ac)
-    chunks = b_chunks(B, plan.p_b)
-    As, Bs = csr_stack(strips), csr_stack(chunks)
-    r0s, r1s = plan.b_ranges()
-    strip_rows = strips[0].n_rows
-    C0s = _empty_c_stack(plan.n_ac, strip_rows, B.n_cols, c_pad, A.dtype)
-    Cs = _chunk2_scan(As, Bs, jnp.asarray(r0s), jnp.asarray(r1s), C0s, c_pad)
+    with span("stage") as sp:
+        strips, chunks, As, Bs, r0s, r1s = _stage(A, B, plan, sp)
+        strip_rows = strips[0].n_rows
+        C0s = _empty_c_stack(plan.n_ac, strip_rows, B.n_cols, c_pad, A.dtype)
+    with span("launch"):
+        Cs = _chunk2_scan(As, Bs, r0s, r1s, C0s, c_pad)
     stats = planned_stats(plan, chunks[0].nbytes(), strips[0].nbytes(),
                           _c_strip_nbytes(strip_rows, c_pad, A.dtype))
-    return _assemble(csr_unstack(Cs), plan.p_ac, B.n_cols), stats
+    return _assemble(Cs, plan.p_ac, B.n_cols), stats
 
 
 # ---------------------------------------------------------------------------
@@ -515,37 +524,44 @@ def _make_pallas_batched_cores(donate: bool = False) -> dict:
 
 
 def _pallas_assemble(dense, p_ac: tuple, dtype) -> CSR:
-    """Crop per-strip dense results to their true rows, concatenate, and
-    sparsify (host). The Pallas backend's CSR keeps exactly the nonzeros of
-    the dense result, so comparisons against the loop oracle are allclose on
-    the densified values rather than bitwise on padding structure."""
-    dense = np.asarray(dense)
-    whole = np.concatenate([
-        dense[i][: e - s]
-        for i, (s, e) in enumerate(zip(p_ac[:-1], p_ac[1:]))
-    ])
-    return csr_from_dense(whole.astype(dtype))
+    """Crop per-strip dense results (``[n_ac, rows, n]``, or one ``[rows,
+    n]`` block) to their true rows, concatenate, and sparsify on the host.
+    The Pallas backend's CSR keeps exactly the nonzeros of the dense result,
+    so comparisons against the loop oracle are allclose on the densified
+    values rather than bitwise on padding structure."""
+    with span("wait"):
+        jax.block_until_ready(dense)
+    with span("fetch") as sp:
+        dense = sp.get(dense)
+    with span("assemble") as sp:
+        if dense.ndim == 2:
+            dense = dense[None]
+        whole = np.concatenate([
+            dense[i][: e - s]
+            for i, (s, e) in enumerate(zip(p_ac[:-1], p_ac[1:]))
+        ])
+        C = csr_from_dense(whole.astype(dtype))
+        sp.add(h2d_bytes=C.nbytes)
+        return C
 
 
 def chunk_knl_pallas(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int):
     del c_pad  # capacity is implicit in the dense accumulator
-    chunks = b_chunks(B, plan.p_b)
-    Bs = csr_stack(chunks)
-    r0s, _ = plan.b_ranges()
-    dense = _knl_pallas(A, Bs, jnp.asarray(r0s))
-    C = csr_from_dense(np.asarray(dense).astype(np.dtype(A.dtype)))
+    with span("stage") as sp:
+        _, _, _, Bs, r0s, _ = _stage(None, B, plan, sp)
+    with span("launch"):
+        dense = _knl_pallas(A, Bs, r0s)
     stats = planned_stats_pallas(
         plan, *_pallas_stage_nbytes(A.n_rows, A.n_cols, Bs.n_rows, B.n_cols))
-    return C, stats
+    return _pallas_assemble(dense, (0, A.n_rows), np.dtype(A.dtype)), stats
 
 
 def chunk_gpu1_pallas(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int):
     del c_pad
-    strips = a_strips(A, plan.p_ac)
-    chunks = b_chunks(B, plan.p_b)
-    As, Bs = csr_stack(strips), csr_stack(chunks)
-    r0s, _ = plan.b_ranges()
-    dense = _chunk1_pallas(As, Bs, jnp.asarray(r0s))
+    with span("stage") as sp:
+        _, _, As, Bs, r0s, _ = _stage(A, B, plan, sp)
+    with span("launch"):
+        dense = _chunk1_pallas(As, Bs, r0s)
     stats = planned_stats_pallas(
         plan, *_pallas_stage_nbytes(As.n_rows, A.n_cols, Bs.n_rows, B.n_cols))
     return _pallas_assemble(dense, plan.p_ac, np.dtype(A.dtype)), stats
@@ -553,11 +569,10 @@ def chunk_gpu1_pallas(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int):
 
 def chunk_gpu2_pallas(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int):
     del c_pad
-    strips = a_strips(A, plan.p_ac)
-    chunks = b_chunks(B, plan.p_b)
-    As, Bs = csr_stack(strips), csr_stack(chunks)
-    r0s, _ = plan.b_ranges()
-    dense = _chunk2_pallas(As, Bs, jnp.asarray(r0s))
+    with span("stage") as sp:
+        _, _, As, Bs, r0s, _ = _stage(A, B, plan, sp)
+    with span("launch"):
+        dense = _chunk2_pallas(As, Bs, r0s)
     stats = planned_stats_pallas(
         plan, *_pallas_stage_nbytes(As.n_rows, A.n_cols, Bs.n_rows, B.n_cols))
     return _pallas_assemble(dense, plan.p_ac, np.dtype(A.dtype)), stats
@@ -661,15 +676,6 @@ def _make_hash_batched_cores(donate: bool = False) -> dict:
             for alg, order in _CSR_ACCUM_ORDERS.items()}
 
 
-def _sparse_strip_csrs(ip, ix, d, strip_rows: int, n_cols: int,
-                       c_cap: int) -> list:
-    """Wrap one batch element's kernel outputs ([n_ac, ...]) as strip CSRs."""
-    return [
-        CSR(ip[i], ix[i], d[i], (strip_rows, n_cols), c_cap)
-        for i in range(ip.shape[0])
-    ]
-
-
 def _sparse_run(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int, backend: str,
                 caps=None):
     """Shared body of the unbatched sparse-output executors (ESC and hash):
@@ -694,24 +700,22 @@ def _sparse_run(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int, backend: str,
              else None)
     check_output_caps(caps.strip_nnz, caps.c_max_row_nnz, c_pad, table,
                       backend=backend, a_shape=A.shape, b_shape=B.shape)
-    strips = a_strips(A, plan.p_ac)
-    chunks = b_chunks(B, plan.p_b)
-    Ast = csr_stack([csr_stack(strips)])
-    Bst = csr_stack([csr_stack(chunks)])
-    r0s, r1s = plan.b_ranges()
-    strip_rows = strips[0].n_rows
-    C0 = _sparse_c0_stack(1, plan.n_ac, strip_rows, B.n_cols, c_pad, A.dtype)
-    if backend == "hash":
-        ip, ix, d = _HASH_CORES[plan.algorithm](
-            Ast, Bst, C0, jnp.asarray(r0s), jnp.asarray(r1s),
-            table_size=table)
-    else:
-        ip, ix, d = _SPARSE_CORES[plan.algorithm](
-            Ast, Bst, C0, jnp.asarray(r0s), jnp.asarray(r1s))
+    with span("stage") as sp:
+        strips, chunks, As, Bs, r0s, r1s = _stage(A, B, plan, sp)
+        Ast, Bst = csr_stack([As]), csr_stack([Bs])
+        strip_rows = strips[0].n_rows
+        C0 = _sparse_c0_stack(1, plan.n_ac, strip_rows, B.n_cols, c_pad,
+                              A.dtype)
+    with span("launch"):
+        if backend == "hash":
+            ip, ix, d = _HASH_CORES[plan.algorithm](
+                Ast, Bst, C0, r0s, r1s, table_size=table)
+        else:
+            ip, ix, d = _SPARSE_CORES[plan.algorithm](Ast, Bst, C0, r0s, r1s)
     stats = planned_stats_pallas(
         plan, chunks[0].nbytes(), strips[0].nbytes(),
         _c_strip_nbytes(strip_rows, c_pad, A.dtype))
-    out = _sparse_strip_csrs(ip[0], ix[0], d[0], strip_rows, B.n_cols, c_pad)
+    out = CSR(ip[0], ix[0], d[0], (strip_rows, B.n_cols), c_pad)
     return _assemble(out, plan.p_ac, B.n_cols), stats
 
 
@@ -780,18 +784,17 @@ def chunk_hash_masked(A: CSR, B: CSR, mask: CSR, plan: ChunkPlan,
     table = hash_table_slots(caps.c_max_row_nnz)
     check_output_caps(caps.strip_nnz, caps.c_max_row_nnz, c_pad, table,
                       backend="hash", a_shape=A.shape, b_shape=B.shape)
-    strips = a_strips(A, plan.p_ac)
-    chunks = b_chunks(B, plan.p_b)
-    mstrips = a_strips(mask, plan.p_ac)
-    Ast = csr_stack([csr_stack(strips)])
-    Bst = csr_stack([csr_stack(chunks)])
-    Mst = csr_stack([csr_stack(mstrips)])
-    r0s, r1s = plan.b_ranges()
-    strip_rows = strips[0].n_rows
-    C0 = _sparse_c0_stack(1, plan.n_ac, strip_rows, B.n_cols, c_pad, A.dtype)
-    ip, ix, d = _HASH_MASKED_CORES[plan.algorithm](
-        Ast, Bst, C0, Mst, jnp.asarray(r0s), jnp.asarray(r1s),
-        table_size=table)
+    with span("stage") as sp:
+        strips, chunks, As, Bs, r0s, r1s = _stage(A, B, plan, sp)
+        mstrips = a_strips(mask, plan.p_ac)
+        Ast, Bst = csr_stack([As]), csr_stack([Bs])
+        Mst = csr_stack([csr_stack(mstrips)])
+        strip_rows = strips[0].n_rows
+        C0 = _sparse_c0_stack(1, plan.n_ac, strip_rows, B.n_cols, c_pad,
+                              A.dtype)
+    with span("launch"):
+        ip, ix, d = _HASH_MASKED_CORES[plan.algorithm](
+            Ast, Bst, C0, Mst, r0s, r1s, table_size=table)
     stats = planned_stats_pallas(
         plan, chunks[0].nbytes(), strips[0].nbytes(),
         _c_strip_nbytes(strip_rows, c_pad, A.dtype))
@@ -804,7 +807,7 @@ def chunk_hash_masked(A: CSR, B: CSR, mask: CSR, plan: ChunkPlan,
     else:
         for _ in range(plan.n_ac):
             stats.add_in(m_struct)
-    out = _sparse_strip_csrs(ip[0], ix[0], d[0], strip_rows, B.n_cols, c_pad)
+    out = CSR(ip[0], ix[0], d[0], (strip_rows, B.n_cols), c_pad)
     return _assemble(out, plan.p_ac, B.n_cols), stats
 
 
@@ -1021,10 +1024,7 @@ def _scan_run_batched(As, Bs, plan: ChunkPlan, envelope: GeometryEnvelope, *,
     else:
         C0s = _empty_c_stack(plan.n_ac, strip_rows, n_cols, c_pad, dtype)
         Cb = cores["chunk2"](Ast, Bst, r0s, r1s, C0s, c_pad=c_pad)
-    return [
-        _assemble(csr_unstack(Ci), plan.p_ac, n_cols)
-        for Ci in csr_unstack(Cb)
-    ], stats
+    return [_assemble(Ci, plan.p_ac, n_cols) for Ci in csr_unstack(Cb)], stats
 
 
 def _pallas_run_batched(As, Bs, plan: ChunkPlan, envelope: GeometryEnvelope, *,
@@ -1051,9 +1051,8 @@ def _pallas_run_batched(As, Bs, plan: ChunkPlan, envelope: GeometryEnvelope, *,
         stats = planned_stats_pallas(plan, *_pallas_stage_nbytes(
             envelope.a_shape[0], envelope.a_shape[1], envelope.chunk_rows,
             n_cols))
-        return [
-            csr_from_dense(np.asarray(d).astype(np_dtype)) for d in dense
-        ], stats
+        return [_pallas_assemble(d, (0, envelope.a_shape[0]), np_dtype)
+                for d in dense], stats
     Ast, _ = _stage_strips_batched(As, plan, envelope)
     dense = cores[plan.algorithm](Ast, Bst, r0s)
     stats = planned_stats_pallas(plan, *_pallas_stage_nbytes(
@@ -1120,10 +1119,8 @@ def _csr_accum_run_batched(As, Bs, plan: ChunkPlan,
         plan, chunk_nbytes, strip_nbytes,
         _c_strip_nbytes(strip_rows, c_pad, dtype))
     return [
-        _assemble(
-            _sparse_strip_csrs(ip[b], ix[b], d[b], strip_rows, n_cols,
-                               c_pad),
-            plan.p_ac, n_cols)
+        _assemble(CSR(ip[b], ix[b], d[b], (strip_rows, n_cols), c_pad),
+                  plan.p_ac, n_cols)
         for b in range(len(As))
     ], stats
 

@@ -23,13 +23,15 @@ from __future__ import annotations
 import dataclasses
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from repro.core.kkmem import spgemm, spgemm_ranged
 from repro.core.planner import ChunkPlan
+from repro.core.spans import span
 from repro.core.symbolic import strip_output_caps
 from repro.sparse.csr import (
-    CSR, GeometryEnvelope, csr_pad_to, csr_select_rows_host,
+    CSR, GeometryEnvelope, csr_from_scipy_like, csr_rows_host, csr_stack,
 )
 
 
@@ -69,6 +71,20 @@ def _partition_caps(m: CSR, bounds: tuple) -> tuple:
     return max(cap, 1), rows
 
 
+def _row_pieces(M: CSR, bounds: tuple, caps: tuple | None) -> list:
+    """Row pieces ``M[s:e]`` of consecutive ``bounds``, each padded to
+    ``caps`` = (nnz cap, rows, max_row_nnz), or else to the largest piece,
+    on the device. ``M`` is read to the host once, sliced there, and the
+    padded pieces are put on the device in one transfer."""
+    with span("stage") as sp:
+        host = sp.get(M)
+        if caps is None:
+            caps = (*_partition_caps(host, bounds), M.max_row_nnz)
+        cap, rows, mrn = caps
+        return sp.put([csr_rows_host(host, s, e, cap, rows, mrn)
+                       for s, e in zip(bounds[:-1], bounds[1:])])
+
+
 def b_chunks(B: CSR, p_b: tuple, envelope: GeometryEnvelope | None = None):
     """Row chunks of B, uniformly padded (rows and nnz) so jit traces once.
 
@@ -76,33 +92,17 @@ def b_chunks(B: CSR, p_b: tuple, envelope: GeometryEnvelope | None = None):
     single-problem case); with one, every chunk is padded to the envelope's
     ``chunk_nnz_cap``/``chunk_rows``/``b_max_row_nnz`` so chunks from
     *different* instances stack into one batch."""
-    if envelope is None:
-        cap, rows = _partition_caps(B, p_b)
-        mrn = B.max_row_nnz
-    else:
-        cap, rows = envelope.chunk_nnz_cap, envelope.chunk_rows
-        mrn = envelope.b_max_row_nnz
-    return [
-        csr_pad_to(csr_select_rows_host(B, s, e, pad_to=cap),
-                   rows=rows, max_row_nnz=mrn)
-        for s, e in zip(p_b[:-1], p_b[1:])
-    ]
+    caps = None if envelope is None else (
+        envelope.chunk_nnz_cap, envelope.chunk_rows, envelope.b_max_row_nnz)
+    return _row_pieces(B, p_b, caps)
 
 
 def a_strips(A: CSR, p_ac: tuple, envelope: GeometryEnvelope | None = None):
     """Row strips of A, uniformly padded (rows and nnz); with an envelope the
     caps are the batch-wide ``strip_nnz_cap``/``strip_rows``/``a_max_row_nnz``."""
-    if envelope is None:
-        cap, rows = _partition_caps(A, p_ac)
-        mrn = A.max_row_nnz
-    else:
-        cap, rows = envelope.strip_nnz_cap, envelope.strip_rows
-        mrn = envelope.a_max_row_nnz
-    return [
-        csr_pad_to(csr_select_rows_host(A, s, e, pad_to=cap),
-                   rows=rows, max_row_nnz=mrn)
-        for s, e in zip(p_ac[:-1], p_ac[1:])
-    ]
+    caps = None if envelope is None else (
+        envelope.strip_nnz_cap, envelope.strip_rows, envelope.a_max_row_nnz)
+    return _row_pieces(A, p_ac, caps)
 
 
 def instance_envelope(A: CSR, B: CSR, plan: ChunkPlan,
@@ -178,22 +178,29 @@ def _empty_like_c(n_rows: int, n_cols: int, c_pad: int, dtype) -> CSR:
     )
 
 
-def _assemble(strips, p_ac: tuple, n_cols: int) -> CSR:
-    """Concatenate per-strip C results (host) into one CSR over all rows."""
-    ptrs, idxs, vals = [], [], []
-    base = 0
-    for (s, e), c in zip(zip(p_ac[:-1], p_ac[1:]), strips):
-        ptr = np.asarray(c.indptr)[: e - s + 1]
-        nnz = int(ptr[-1])
-        ptrs.append(ptr[:-1] + base)
-        idxs.append(np.asarray(c.indices)[:nnz])
-        vals.append(np.asarray(c.data)[:nnz])
-        base += nnz
-    indptr = np.concatenate(ptrs + [[base]])
-    from repro.sparse.csr import csr_from_scipy_like
-
-    return csr_from_scipy_like(indptr, np.concatenate(idxs), np.concatenate(vals),
-                               (p_ac[-1] - p_ac[0], n_cols))
+def _assemble(Cs: CSR, p_ac: tuple, n_cols: int) -> CSR:
+    """One CSR over all rows from the per-strip results ``Cs`` (fields
+    stacked over the strips): read to the host in one transfer, joined
+    there, and put back on the device."""
+    with span("wait"):
+        jax.block_until_ready(Cs)
+    with span("fetch") as sp:
+        host = sp.get(Cs)
+    with span("assemble") as sp:
+        ptrs, idxs, vals = [], [], []
+        base = 0
+        for i, (s, e) in enumerate(zip(p_ac[:-1], p_ac[1:])):
+            ptr = host.indptr[i][: e - s + 1]
+            nnz = int(ptr[-1])
+            ptrs.append(ptr[:-1] + base)
+            idxs.append(host.indices[i][:nnz])
+            vals.append(host.data[i][:nnz])
+            base += nnz
+        indptr = np.concatenate(ptrs + [[base]])
+        C = csr_from_scipy_like(indptr, np.concatenate(idxs), np.concatenate(vals),
+                                (p_ac[-1] - p_ac[0], n_cols))
+        sp.add(h2d_bytes=C.nbytes)
+        return C
 
 
 # ---------------------------------------------------------------------------
@@ -233,7 +240,7 @@ def chunk_gpu1(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int):
             stats.kernel_calls += 1
         stats.add_out(Ci.nbytes())                       # copy2Slow(FC)
         out.append(Ci)
-    return _assemble(out, plan.p_ac, B.n_cols), stats
+    return _assemble(csr_stack(out), plan.p_ac, B.n_cols), stats
 
 
 def chunk_gpu2(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int):
@@ -258,7 +265,7 @@ def chunk_gpu2(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int):
         if jb == n_b - 1:
             for ia in range(len(strips)):
                 stats.add_out(partials[ia].nbytes())           # final copy2Slow
-    return _assemble(partials, plan.p_ac, B.n_cols), stats
+    return _assemble(csr_stack(partials), plan.p_ac, B.n_cols), stats
 
 
 # ---------------------------------------------------------------------------
@@ -296,35 +303,41 @@ def chunked_spgemm(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int | None = None,
     """
     from repro.core import backend_registry
 
-    spec = None if backend == "auto" else backend_registry.get(backend)
-    # one symbolic expansion serves the default c_pad, the auto resolve, and
-    # the caps-consuming executors' overflow checks (the symbolic module's
-    # amortize-the-host-pass contract)
-    caps = None
-    if c_pad is None or backend == "auto" or (spec is not None
-                                              and spec.needs_output_caps):
-        caps = strip_output_caps(A, B, plan.p_ac)
-    if c_pad is None:
-        c_pad = caps.c_pad
-    if plan.algorithm == "whole_fast":
-        stats = ChunkStats("whole_fast", 1, 1)
-        stats.add_in(A.nbytes() + B.nbytes())
-        C = spgemm(A, B, c_pad)
-        stats.add_out(C.nbytes())
-        stats.kernel_calls = 1
-        return C, stats
-    if backend == "auto":
-        from repro.core.planner import select_accumulator_backend
+    with span("call", algorithm=plan.algorithm, n_ac=plan.n_ac,
+              n_b=plan.n_b) as call:
+        spec = None if backend == "auto" else backend_registry.get(backend)
+        # one symbolic expansion serves the default c_pad, the auto resolve, and
+        # the caps-consuming executors' overflow checks (the symbolic module's
+        # amortize-the-host-pass contract)
+        caps = None
+        if c_pad is None or backend == "auto" or (spec is not None
+                                                  and spec.needs_output_caps):
+            caps = strip_output_caps(A, B, plan.p_ac)
+        if c_pad is None:
+            c_pad = caps.c_pad
+        if plan.algorithm == "whole_fast":
+            call.note(backend=backend)
+            stats = ChunkStats("whole_fast", 1, 1)
+            stats.add_in(A.nbytes() + B.nbytes())
+            with span("launch"):
+                C = spgemm(A, B, c_pad)
+            stats.add_out(C.nbytes())
+            stats.kernel_calls = 1
+            return C, stats
+        if backend == "auto":
+            from repro.core.planner import select_accumulator_backend
 
-        env = instance_envelope(A, B, plan, c_pad=c_pad, caps=caps,
-                                block_size=block_size)
-        spec = backend_registry.get(select_accumulator_backend(plan, env))
-    fn = spec.executors.get(plan.algorithm)
-    if fn is None:
-        raise ValueError(f"unknown algorithm {plan.algorithm!r}")
-    kwargs = {}
-    if spec.needs_output_caps:
-        kwargs["caps"] = caps
-    if block_size is not None and spec.needs_block_caps:
-        kwargs["block_size"] = block_size
-    return fn(A, B, plan, c_pad, **kwargs)
+            with span("plan"):
+                env = instance_envelope(A, B, plan, c_pad=c_pad, caps=caps,
+                                        block_size=block_size)
+                spec = backend_registry.get(select_accumulator_backend(plan, env))
+        call.note(backend=spec.name)
+        fn = spec.executors.get(plan.algorithm)
+        if fn is None:
+            raise ValueError(f"unknown algorithm {plan.algorithm!r}")
+        kwargs = {}
+        if spec.needs_output_caps:
+            kwargs["caps"] = caps
+        if block_size is not None and spec.needs_block_caps:
+            kwargs["block_size"] = block_size
+        return fn(A, B, plan, c_pad, **kwargs)

@@ -32,12 +32,11 @@ from __future__ import annotations
 
 import dataclasses
 
-import numpy as np
-
 from repro.core import backend_registry
 from repro.core.chunking import ChunkStats, instance_envelope
 from repro.core.kkmem import spgemm
 from repro.core.planner import BackendFastModel, PipelinePlan, plan_pipeline
+from repro.core.spans import span
 from repro.core.symbolic import PipelineCaps, pipeline_output_caps
 from repro.sparse.csr import CSR, GeometryEnvelope
 
@@ -93,7 +92,8 @@ def _run_hop(X: CSR, Y: CSR, plan, caps, backend: str):
     if plan.algorithm == "whole_fast":
         stats = ChunkStats("whole_fast", 1, 1)
         stats.add_in(X.nbytes() + Y.nbytes())
-        C = spgemm(X, Y, caps.c_pad)
+        with span("launch"):
+            C = spgemm(X, Y, caps.c_pad)
         stats.add_out(C.nbytes())
         stats.kernel_calls = 1
         return C, stats
@@ -109,13 +109,8 @@ def _spill_to_slow(T: CSR) -> CSR:
     """Round-trip the intermediate through slow (host) memory: the spill
     path's physical analogue — hop 2 restages T from slow instead of
     consuming the fast-resident triple."""
-    return CSR(
-        indptr=np.asarray(T.indptr),
-        indices=np.asarray(T.indices),
-        data=np.asarray(T.data),
-        shape=T.shape,
-        max_row_nnz=T.max_row_nnz,
-    )
+    with span("spill") as sp:
+        return sp.get(T)
 
 
 def pipeline_spgemm(A: CSR, P: CSR, R: CSR, plan: PipelinePlan | None = None,
@@ -131,25 +126,31 @@ def pipeline_spgemm(A: CSR, P: CSR, R: CSR, plan: PipelinePlan | None = None,
     flows straight into hop 2's staging; on the spill path it round-trips
     through host memory and the stats carry the extra copy events.
     """
-    if plan is None:
-        if system is None:
-            raise ValueError(
-                "pipeline_spgemm needs either a PipelinePlan or a "
-                "MemorySystem to plan against")
-        plan = plan_pipeline(A, P, R, system,
-                             fast_limit_bytes=fast_limit_bytes)
-    if caps is None:
-        caps = pipeline_output_caps(A, P, R, plan.plan1.p_ac, plan.plan2.p_ac)
-    T, stats1 = _run_hop(A, P, plan.plan1, caps.hop1, backend)
-    spilled = not plan.t_resident
-    spill_bytes = 0.0
-    if spilled:
-        T = _spill_to_slow(T)
-        t_reads = plan.plan2.n_ac if plan.plan2.algorithm == "chunk1" else 1
-        spill_bytes = float(T.nbytes()) * (1 + t_reads)
-    C, stats2 = _run_hop(R, T, plan.plan2, caps.hop2, backend)
+    with span("pipeline", backend=backend):
+        if plan is None:
+            if system is None:
+                raise ValueError(
+                    "pipeline_spgemm needs either a PipelinePlan or a "
+                    "MemorySystem to plan against")
+            plan = plan_pipeline(A, P, R, system,
+                                 fast_limit_bytes=fast_limit_bytes)
+        if caps is None:
+            caps = pipeline_output_caps(A, P, R, plan.plan1.p_ac,
+                                        plan.plan2.p_ac)
+        resident = plan.t_resident
+        with span("hop", hop=1, algorithm=plan.plan1.algorithm,
+                  resident=resident):
+            T, stats1 = _run_hop(A, P, plan.plan1, caps.hop1, backend)
+        spill_bytes = 0.0
+        if not resident:
+            T = _spill_to_slow(T)
+            t_reads = plan.plan2.n_ac if plan.plan2.algorithm == "chunk1" else 1
+            spill_bytes = float(T.nbytes()) * (1 + t_reads)
+        with span("hop", hop=2, algorithm=plan.plan2.algorithm,
+                  resident=resident):
+            C, stats2 = _run_hop(R, T, plan.plan2, caps.hop2, backend)
     return C, PipelineStats(plan=plan, hop1=stats1, hop2=stats2,
-                            spilled=spilled, spill_bytes=spill_bytes)
+                            spilled=not resident, spill_bytes=spill_bytes)
 
 
 # ---------------------------------------------------------------------------

@@ -18,6 +18,7 @@ import dataclasses
 import numpy as np
 
 from repro.core.memory_model import MemorySystem
+from repro.core.spans import span
 from repro.sparse.csr import CSR
 
 
@@ -97,6 +98,7 @@ def staged_row_bytes(row_bytes: np.ndarray, bounds: tuple,
     return float((rows + 1) * index_bytes) + max(cap, 1.0)
 
 
+@span("plan")
 def plan_chunks(A: CSR, B: CSR, c_row_bytes: np.ndarray, system: MemorySystem,
                 fast_limit_bytes: float | None = None,
                 big_portion: float = 0.75) -> ChunkPlan:
@@ -273,6 +275,7 @@ def dense_slab_slow_bytes(plan: ChunkPlan, a_shape: tuple, b_shape: tuple) -> fl
                   + 2 * rows * n_pad)
 
 
+@span("plan")
 def plan_dense_slab(A: CSR, B: CSR, system: MemorySystem) -> ChunkPlan:
     """Plan for the dense-slab accumulator (``backend="pallas"``): uniform
     strips and chunks in the Chunk1 order, the fewest grid steps whose
@@ -620,6 +623,7 @@ class PipelinePlan:
     fast_bytes_needed: float  # peak staged footprint across both hops
 
 
+@span("plan")
 def plan_pipeline(A: CSR, P: CSR, R: CSR, system: MemorySystem,
                   fast_limit_bytes: float | None = None,
                   big_portion: float = 0.75,

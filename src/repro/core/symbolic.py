@@ -38,6 +38,7 @@ import dataclasses
 
 import numpy as np
 
+from repro.core.spans import span
 from repro.sparse.csr import CSR
 
 
@@ -108,6 +109,7 @@ def _round_up(v: int, multiple: int) -> int:
     return -(-max(int(v), 1) // multiple) * multiple
 
 
+@span("symbolic")
 def strip_output_caps(A: CSR, B: CSR, p_ac: tuple,
                       pad_multiple: int = 64) -> StripOutputCaps:
     """Output capacities for the A/C row partition ``p_ac`` of C = A x B.
@@ -155,6 +157,7 @@ class PipelineCaps:
     t_max_row_nnz: int      # densest T row = hop 2's streamed b_max_row_nnz
 
 
+@span("symbolic")
 def spgemm_pattern_host(A: CSR, B: CSR) -> CSR:
     """Exact structure of ``A x B`` as a host pattern CSR (data = 1.0).
 
@@ -171,6 +174,7 @@ def spgemm_pattern_host(A: CSR, B: CSR) -> CSR:
                         (A.n_rows, B.n_cols))
 
 
+@span("symbolic")
 def pipeline_output_caps(A: CSR, P: CSR, R: CSR, p_ac1: tuple, p_ac2: tuple,
                          pad_multiple: int = 64,
                          t_pattern: CSR | None = None) -> PipelineCaps:
@@ -202,6 +206,7 @@ def pipeline_output_caps(A: CSR, P: CSR, R: CSR, p_ac1: tuple, p_ac2: tuple,
 # ---------------------------------------------------------------------------
 
 
+@span("symbolic")
 def masked_output_caps(mask: CSR, p_ac: tuple,
                        pad_multiple: int = 64) -> StripOutputCaps:
     """Output capacities of a mask-fused product ``C = (A x B) ∘ M``.
@@ -262,6 +267,7 @@ class BsrPlanCaps:
         return (self.block_size, self.nbl_a, self.nbl_b, self.nc, self.u)
 
 
+@span("symbolic")
 def bsr_plan_caps(A: CSR, B: CSR, plan, block_size: int,
                   quantum: int = 8) -> BsrPlanCaps:
     """Exact block-structure capacities of ``plan`` on (A, B) at ``block_size``.

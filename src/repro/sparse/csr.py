@@ -136,7 +136,8 @@ def csr_from_dense(dense, pad_to: int | None = None) -> CSR:
     dense = np.asarray(dense)
     rows, cols = np.nonzero(dense)
     return csr_from_coo(rows, cols, dense[rows, cols], dense.shape, pad_to,
-                        dtype=jnp.asarray(dense).dtype, sum_duplicates=False)
+                        dtype=jax.dtypes.canonicalize_dtype(dense.dtype),
+                        sum_duplicates=False)
 
 
 def csr_to_dense(m: CSR) -> jax.Array:
@@ -157,15 +158,36 @@ def csr_row_of_entry(m: CSR) -> jax.Array:
     return jnp.clip(row, 0, m.n_rows - 1).astype(jnp.int32)
 
 
+def csr_rows_host(m: CSR, r0: int, r1: int, nnz_cap: int | None = None,
+                  rows: int | None = None, max_row_nnz: int | None = None) -> CSR:
+    """Rows ``m[r0:r1, :]`` of a CSR whose fields are host (NumPy) arrays, as
+    a new host CSR padded to ``nnz_cap`` entries (at least one) and ``rows``
+    rows, with row bound ``max_row_nnz``; each defaults to the slice's own.
+    A target smaller than the slice raises, as ``csr_pad_to`` does."""
+    ptr = np.asarray(m.indptr)
+    s, e = int(ptr[r0]), int(ptr[r1])
+    nnz, n = e - s, r1 - r0
+    longest = int(np.diff(ptr[r0 : r1 + 1]).max()) if n else 0
+    cap = nnz if nnz_cap is None else int(nnz_cap)
+    rows = n if rows is None else int(rows)
+    mrn = longest if max_row_nnz is None else int(max_row_nnz)
+    if cap < nnz or rows < n or mrn < longest:
+        raise ValueError(
+            f"rows {r0}:{r1} hold {nnz} entries in {n} rows, the longest "
+            f"{longest}; the target is nnz_cap={cap} rows={rows} max_row_nnz={mrn}")
+    indptr = np.full(rows + 1, nnz, np.int32)
+    indptr[: n + 1] = ptr[r0 : r1 + 1] - s
+    indices = np.zeros(max(cap, 1), np.int32)
+    indices[:nnz] = np.asarray(m.indices)[s:e]
+    values = np.asarray(m.data)
+    data = np.zeros(max(cap, 1), values.dtype)
+    data[:nnz] = values[s:e]
+    return CSR(indptr, indices, data, (rows, m.shape[1]), mrn)
+
+
 def csr_select_rows_host(m: CSR, r0: int, r1: int, pad_to: int | None = None) -> CSR:
-    """Host-side row slice m[r0:r1, :] as a new CSR (used by chunk planners/tests)."""
-    indptr = np.asarray(m.indptr)
-    indices = np.asarray(m.indices)
-    data = np.asarray(m.data)
-    s, e = int(indptr[r0]), int(indptr[r1])
-    new_ptr = indptr[r0 : r1 + 1] - s
-    return csr_from_scipy_like(new_ptr, indices[s:e], data[s:e], (r1 - r0, m.shape[1]),
-                               pad_to, dtype=m.dtype)
+    """Row slice m[r0:r1, :] as a new CSR on the device, sliced on the host."""
+    return jax.device_put(csr_rows_host(jax.device_get(m), r0, r1, pad_to))
 
 
 def _union_bsr_caps(a: tuple, b: tuple) -> tuple:

@@ -15,8 +15,8 @@ JSON_LANES = {**CHUNKING_LANES, **TRIANGLE_LANES}
 
 
 def test_json_lane_names_globally_unique():
-    """`--lane` names double as bench-artifact filenames and trajectory keys,
-    so two modules must never register the same lane name."""
+    """`--lane` names double as bench-artifact filenames, so two modules
+    must never register the same lane name."""
     overlap = set(CHUNKING_LANES) & set(TRIANGLE_LANES)
     assert not overlap, f"lane names registered by two modules: {overlap}"
 
@@ -63,19 +63,11 @@ def test_ci_smokes_every_json_lane():
         assert f"--lane {lane}" in ci, \
             f"JSON lane {lane!r} has no smoke step in .github/workflows/ci.yml"
     assert "upload-artifact" in ci, "bench artifacts are not uploaded by CI"
-    assert "bench_trajectory" in ci, \
-        "bench trajectory persistence step missing from CI"
 
 
 def test_triangle_speedup_is_lane_level_scalar():
-    """The chunked-vs-kkmem speedup must survive trajectory summarization
-    verbatim, which `tools/bench_trajectory.py` only guarantees for
-    lane-level non-list scalars — run the smoke lane and summarize it."""
-    import sys
-    sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]
-                           / "tools"))
-    from bench_trajectory import summarize
-
+    """The chunked-vs-kkmem speedup is one lane-level float beside the rows,
+    which the CI smoke step asserts on."""
     from benchmarks.triangle_counting import run_triangle_counting
 
     report = run_triangle_counting(smoke=True)
@@ -83,6 +75,3 @@ def test_triangle_speedup_is_lane_level_scalar():
     assert isinstance(report["chunked_vs_kkmem_speedup"], float)
     assert report["chunked_vs_kkmem_speedup"] > 0
     assert report["rows"], "smoke lane emitted no rows"
-    summary = summarize(report)
-    assert summary["chunked_vs_kkmem_speedup"] == \
-        report["chunked_vs_kkmem_speedup"]
