@@ -76,7 +76,6 @@ the request-bucketing service on top.
 
 from __future__ import annotations
 
-import collections
 from functools import partial
 
 import numpy as np
@@ -90,7 +89,7 @@ from repro.core.chunking import (
     ChunkStats, _assemble, a_strips, b_chunks, batch_envelope,
     chunk_gpu1, chunk_gpu2, chunk_knl, instance_envelope,
 )
-from repro.core.kkmem import spgemm_ranged_impl
+from repro.core.kkmem import TRACE_COUNTS, spgemm_ranged_impl
 from repro.core.planner import (
     ChunkPlan, check_output_caps, csr_field_nbytes, hash_table_slots,
     planned_stats_bsr, planned_stats_dense_slab, planned_stats_hash,
@@ -111,12 +110,6 @@ from repro.sparse.csr import (
     CSR, GeometryEnvelope, csr_from_dense, csr_pad_to, csr_stack, csr_to_dense,
     csr_unstack,
 )
-
-# Python-side trace counters: each key increments once per (re)trace of the
-# corresponding jitted wrapper / scan body. Tests assert these stay O(1) in
-# the chunk count — the whole point of the single-trace executors.
-TRACE_COUNTS: collections.Counter = collections.Counter()
-
 
 def _empty_c(n_rows: int, n_cols: int, c_pad: int, dtype) -> CSR:
     """Empty C with ``max_row_nnz=c_pad`` so the scan carry has exactly the
@@ -1517,7 +1510,7 @@ def _register_all() -> None:
         traffic_model=_traffic_csr_accum,
         make_batched_cores=_make_sparse_batched_cores,
         mosaic_refusal=(
-            "the ESC merge's argsort and scatter have no Mosaic lowering, "
+            "the ESC merge's gathers, sorts and scatter have no Mosaic lowering, "
             "and its (1, 1, cap) CSR blocks break the (8, 128) block-shape "
             "rule"),
     ))

@@ -7,13 +7,16 @@ row-wise structure but realizes the accumulator as **sort + segment-reduce** ove
 expanded product stream — the same multiset-union semantics, fully vectorized:
 
   expand:     every nonzero a_ik fans out into products with B's row k
-              (the access pattern of Fig. 1 — A streamed, B gathered)
-  accumulate: stable two-key sort brings duplicate (row, col) products together;
-              a boundary scan + scatter-add coalesces them (== hashmap insert)
+              (the access pattern of Fig. 1 — A streamed, B's rows gathered)
+  accumulate: one sort on the (row, col) key, carrying the values, brings duplicate
+              products together; a segmented sum coalesces them (== hashmap
+              insert) and a second sort compacts the run totals into C
 
 Shapes are static: the product buffer has capacity nnzA_pad x B.max_row_nnz, the
 output CSR has a caller-provided capacity from the symbolic phase. Everything here
-jits and vmaps cleanly.
+jits and vmaps cleanly. Operands are canonical CSR (no (row, col) stored twice):
+the segmented sum has a fixed depth that holds only then, and the host symbolic
+phase, which sizes every call, refuses other operands.
 
 ``spgemm_ranged`` is the paper's *modified KKMEM sub-procedure* used by the chunked
 algorithms: it multiplies only the columns of A inside a B-row-range [r0, r1)
@@ -24,14 +27,23 @@ hashmap accumulators"), i.e. C^t = A_t x B_t + C^{t-1}.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.sparse.csr import CSR, csr_row_of_entry
+
+# Python-side trace counters: each key increments once per (re)trace of the
+# corresponding jitted wrapper / scan body (repro.core.chunk_stream), and the
+# ESC body bumps the key of the sort-key layout it compiled. Tests assert
+# these stay O(1) in the chunk count — the whole point of the single-trace
+# executors.
+TRACE_COUNTS: collections.Counter = collections.Counter()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -71,62 +83,98 @@ def spgemm_symbolic_host(A: CSR, B: CSR, pad_multiple: int = 64) -> SpGEMMWorksp
 # ---------------------------------------------------------------------------
 
 
+def _ell(B: CSR, width: int):
+    """B's rows as ELL tables ``[width, n_rows]`` of column indices and values:
+    slot j of row r holds B's entry ``indptr[r] + j``, column -1 past the row's end."""
+    start = B.indptr[:-1]
+    j = jnp.arange(width, dtype=jnp.int32)[:, None]
+    ok = j < (B.indptr[1:] - start)[None, :]
+    src = jnp.clip(start[None, :] + j, 0, B.nnz_pad - 1)
+    return jnp.where(ok, B.indices[src], -1), jnp.where(ok, B.data[src], 0.0)
+
+
 def _expand_products(A: CSR, B: CSR, r0, r1):
     """Fan every (valid, in-range) A entry out into its products with B's rows.
 
     Returns (rows, cols, vals) of static length nnzA_pad * B.max_row_nnz; invalid
-    slots get row = A.n_rows (sorts to the tail) and val = 0.
+    slots get row = A.n_rows (sorts to the tail) and val = 0. B's rows come as
+    one column gather per A entry from B's ELL tables, so the stream is slot-major.
 
     ``r0, r1`` bound the *global* column range of A handled by this call; B is the
     CSR of exactly that row range (local row r_global - r0). For the unchunked case
     pass r0=0, r1=A.n_cols with B the full matrix.
     """
-    bmax = max(B.max_row_nnz, 1)
-    n_ent = A.nnz_pad
-    t = jnp.arange(n_ent, dtype=jnp.int32)
-    row_a = csr_row_of_entry(A)                      # [n_ent]
-    col_a = A.indices                                # [n_ent]
-    valid_t = t < A.indptr[-1]
-    in_range = (col_a >= r0) & (col_a < r1) & valid_t
-    b_row = jnp.clip(col_a - r0, 0, B.n_rows - 1)
-    b_start = B.indptr[b_row]                        # [n_ent]
-    b_len = B.indptr[b_row + 1] - b_start
-    j = jnp.arange(bmax, dtype=jnp.int32)            # [bmax]
-    valid = in_range[:, None] & (j[None, :] < b_len[:, None])   # [n_ent, bmax]
-    src = jnp.clip(b_start[:, None] + j[None, :], 0, B.nnz_pad - 1)
-    cols = jnp.where(valid, B.indices[src], 0)
-    vals = jnp.where(valid, A.data[:, None] * B.data[src], 0.0)
-    rows = jnp.where(valid, row_a[:, None], A.n_rows)
+    t = jnp.arange(A.nnz_pad, dtype=jnp.int32)
+    in_range = (A.indices >= r0) & (A.indices < r1) & (t < A.indptr[-1])
+    b_row = jnp.clip(A.indices - r0, 0, B.n_rows - 1)
+    ell_cols, ell_vals = _ell(B, max(B.max_row_nnz, 1))
+    cols, vals = ell_cols[:, b_row], ell_vals[:, b_row]      # [bmax, nnzA_pad]
+    valid = in_range[None, :] & (cols >= 0)
+    rows = jnp.where(valid, csr_row_of_entry(A)[None, :], A.n_rows)
+    cols = jnp.where(valid, cols, 0)
+    vals = jnp.where(valid, A.data[None, :] * vals, 0.0)
     return rows.reshape(-1), cols.reshape(-1), vals.reshape(-1)
 
 
-def _accumulate(rows, cols, vals, m: int, _n: int, c_pad: int):
+def _differs(keys, d: int):
+    """Whether element i + d's key differs from element i's, for i < len - d."""
+    out = keys[0][d:] != keys[0][:-d]
+    for k in keys[1:]:
+        out = out | (k[d:] != k[:-d])
+    return out
+
+
+def _run_sums(keys, vals, depth: int):
+    """Segmented inclusive sums over runs of equal (sorted) keys: ``depth``
+    steps of shift, mask and add, so runs of up to ``2**depth`` elements end
+    with their total on their last element."""
+    for i in range(depth):
+        d = 1 << i
+        if d >= vals.shape[0]:
+            break
+        shifted = jnp.where(_differs(keys, d), 0, vals[:-d])
+        vals = vals + jnp.pad(shifted, (d, 0))
+    return vals
+
+
+def _accumulate(rows, cols, vals, m: int, n: int, c_pad: int, max_terms: int):
     """Sort-based accumulator: coalesce duplicate (row, col) into CSR arrays.
 
-    Two stable sorts == lexsort by (row, col) without 64-bit keys. Boundary scan
-    assigns each distinct key a dense output slot; scatter-add realizes the
-    "hashmap" accumulation. Returns (indptr[m+1], indices[c_pad], data[c_pad]).
+    One sort carries the values: the stream is keyed by ``row * n + col`` in
+    one uint32 where ``(m + 1) * n`` fits (a static shape test), else by the
+    (row, col) pair in a two-key sort. A segmented sum of
+    ``ceil(log2(max_terms))`` shift-and-add steps leaves each run's total on
+    its last element (``max_terms`` bounds the products of one output entry).
+    A second sort moves the run ends, in key order, to the front; the first
+    ``c_pad`` are C, and ends past them are dropped. Padding (row ``m``)
+    keys past every entry. Nothing is gathered or scattered element by element
+    over the stream: on the chip, sorting is cheap and random access is not.
+    Every constant is a scalar, so the body also traces inside Pallas kernels
+    (the ``sparse`` merge), which reject captured array constants.
+    Returns (indptr[m+1], indices[c_pad], data[c_pad]).
     """
-    order_c = jnp.argsort(cols, stable=True)
-    rows_c, cols_c, vals_c = rows[order_c], cols[order_c], vals[order_c]
-    order_r = jnp.argsort(rows_c, stable=True)
-    rows_s, cols_s, vals_s = rows_c[order_r], cols_c[order_r], vals_c[order_r]
-    valid = rows_s < m
-    # scalar-constant pad (not jnp.array([True])) so this body also traces
-    # inside Pallas kernels, which reject captured array constants
-    new_key = jnp.pad(
-        (rows_s[1:] != rows_s[:-1]) | (cols_s[1:] != cols_s[:-1]),
-        (1, 0), constant_values=True,
-    ) & valid
-    slot = jnp.cumsum(new_key) - 1                       # dense slot per product
-    slot = jnp.where(valid, slot, c_pad)                 # invalid -> dropped bucket
-    data = jnp.zeros(c_pad + 1, vals.dtype).at[slot].add(vals_s)[:c_pad]
-    indices = jnp.zeros(c_pad + 1, jnp.int32).at[slot].max(
-        jnp.where(valid, cols_s, 0).astype(jnp.int32)
-    )[:c_pad]
-    out_rows = jnp.full(c_pad + 1, m, jnp.int32).at[slot].min(
-        jnp.where(valid, rows_s, m).astype(jnp.int32)
-    )[:c_pad]
+    packed = 0 < n and (m + 1) * n < 2**32
+    TRACE_COUNTS["esc_key_packed" if packed else "esc_key_pair"] += 1
+    if packed:
+        keys = (rows.astype(jnp.uint32) * np.uint32(n) + cols.astype(jnp.uint32),)
+        tail = (np.uint32(m * n),)
+    else:
+        keys, tail = (rows.astype(jnp.int32), cols.astype(jnp.int32)), (m, 0)
+    *keys, vals = lax.sort((*keys, vals), num_keys=len(keys), is_stable=True)
+    sums = _run_sums(keys, vals, (max_terms - 1).bit_length())
+    is_last = jnp.pad(_differs(keys, 1), (0, 1), constant_values=True)
+    ends = [jnp.where(is_last, k, t) for k, t in zip(keys, tail)]
+    *ends, sums = lax.sort((*ends, sums), num_keys=len(ends))
+    short = max(c_pad - sums.shape[0], 0)
+    ends = [jnp.pad(k[:c_pad], (0, short), constant_values=t)
+            for k, t in zip(ends, tail)]
+    sums = jnp.pad(sums[:c_pad], (0, short))
+    if packed:
+        out_rows = (ends[0] // np.uint32(n)).astype(jnp.int32)
+        indices = (ends[0] % np.uint32(n)).astype(jnp.int32)
+    else:
+        out_rows, indices = ends
+    data = jnp.where(out_rows < m, sums, 0.0)
     # rows are sorted ascending over slots -> indptr by binary search
     indptr = jnp.searchsorted(out_rows, jnp.arange(m + 1, dtype=jnp.int32)).astype(
         jnp.int32
@@ -138,7 +186,8 @@ def _accumulate(rows, cols, vals, m: int, _n: int, c_pad: int):
 def spgemm(A: CSR, B: CSR, c_pad: int, c_max_row_nnz: int = 0) -> CSR:
     """Numeric phase of C = A x B. ``c_pad`` comes from ``spgemm_symbolic_host``."""
     rows, cols, vals = _expand_products(A, B, 0, A.n_cols)
-    indptr, indices, data = _accumulate(rows, cols, vals, A.n_rows, B.n_cols, c_pad)
+    indptr, indices, data = _accumulate(rows, cols, vals, A.n_rows, B.n_cols,
+                                        c_pad, max(A.max_row_nnz, 1))
     return CSR(indptr, indices, data, (A.n_rows, B.n_cols),
                c_max_row_nnz or c_pad)
 
@@ -164,7 +213,10 @@ def spgemm_ranged_impl(A: CSR, B_chunk: CSR, r0, r1, C_prev: CSR, c_pad: int,
     rows = jnp.concatenate([rows, prev_rows])
     cols = jnp.concatenate([cols, prev_cols])
     vals = jnp.concatenate([vals, prev_vals])
-    indptr, indices, data = _accumulate(rows, cols, vals, A.n_rows, B_chunk.n_cols, c_pad)
+    # an output entry (i, j) gets at most one product per stored A[i, k],
+    # plus C_prev's entry
+    indptr, indices, data = _accumulate(rows, cols, vals, A.n_rows, B_chunk.n_cols,
+                                        c_pad, A.max_row_nnz + 1)
     return CSR(indptr, indices, data, (A.n_rows, B_chunk.n_cols),
                c_max_row_nnz or c_pad)
 

@@ -534,7 +534,7 @@ def check_output_caps(strip_nnz, c_max_row_nnz: int, c_pad: int,
     sparse-output kernel was sized with.
 
     The ESC and hash kernels silently *drop or misplace* entries past their
-    fixed capacities (the scatter's overflow bucket, a full hash table), so
+    fixed capacities (the ESC compaction's cut at ``c_pad``, a full hash table), so
     an under-capped launch must be a planner-level :class:`ValueError` naming
     the offending geometry, not wrong values. ``strip_nnz``/``c_max_row_nnz``
     are the exact realized structure (symbolic phase); ``c_pad`` is the CSR

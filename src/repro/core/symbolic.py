@@ -52,6 +52,26 @@ class SymbolicStructure:
     flops: int               # 2 * number of scalar products
 
 
+def _check_canonical(ptr: np.ndarray, idx: np.ndarray, name: str) -> None:
+    """Refuse an operand whose columns do not strictly increase within a row.
+
+    SpGEMM takes canonical CSR: no (row, col) stored twice. The numeric
+    phase's segmented sum (``repro.core.kkmem._accumulate``) has a fixed
+    depth that holds only then, so the symbolic phase, which sizes every
+    numeric call, checks it."""
+    nnz = int(ptr[-1])
+    idx = idx[:nnz]
+    starts = np.zeros(nnz, bool)
+    starts[ptr[:-1][ptr[:-1] < nnz]] = True
+    bad = np.flatnonzero(~starts[1:] & (idx[1:] <= idx[:-1]))
+    if bad.size:
+        row = int(np.searchsorted(ptr, bad[0] + 1, side="right")) - 1
+        raise ValueError(
+            f"{name} is not canonical CSR: row {row} stores column "
+            f"{int(idx[bad[0] + 1])} after column {int(idx[bad[0]])}; "
+            "coalesce duplicates and sort columns within rows")
+
+
 def _structure_expand(A: CSR, B: CSR):
     """Shared expansion core: unique C coordinate keys + scalar-product count.
 
@@ -62,6 +82,8 @@ def _structure_expand(A: CSR, B: CSR):
     a_idx = np.asarray(A.indices).astype(np.int64)
     b_ptr = np.asarray(B.indptr).astype(np.int64)
     b_idx = np.asarray(B.indices).astype(np.int64)
+    _check_canonical(a_ptr, a_idx, "A")
+    _check_canonical(b_ptr, b_idx, "B")
     nnz_a = int(a_ptr[-1])
     a_rows = np.repeat(np.arange(A.n_rows, dtype=np.int64),
                        a_ptr[1:] - a_ptr[:-1])

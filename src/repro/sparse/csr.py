@@ -6,6 +6,9 @@ Conventions (chosen so every array has a *static* shape — a JAX requirement):
   * ``indices`` : int32[nnz_pad]     -- column index per entry; padding entries are 0.
   * ``data``    : dtype[nnz_pad]     -- value per entry; padding entries are 0.0.
   * rows are contiguous (no per-row padding); all padding lives in the tail.
+  * canonical: columns strictly increase within a row, so no (row, col) is stored
+    twice. The host builders here produce it; SpGEMM requires it of its operands
+    (the symbolic phase, ``repro.core.symbolic``, checks).
   * ``shape``, ``max_row_nnz`` are static metadata (pytree aux), so jit retraces only
     when the padded geometry changes, never per-value.
 
@@ -110,7 +113,10 @@ def csr_from_scipy_like(indptr, indices, data, shape, pad_to: int | None = None,
 
 def csr_from_coo(rows, cols, vals, shape, pad_to: int | None = None, dtype=jnp.float32,
                  sum_duplicates: bool = True) -> CSR:
-    """Host-side COO -> CSR (sorts by (row, col), optionally coalescing duplicates)."""
+    """Host-side COO -> CSR (sorts by (row, col), optionally coalescing duplicates).
+
+    ``sum_duplicates=False`` is for callers whose (row, col) are already unique;
+    it keeps repeats as they are, which no SpGEMM entry point accepts."""
     rows = np.asarray(rows, dtype=np.int64)
     cols = np.asarray(cols, dtype=np.int64)
     vals = np.asarray(vals, dtype=np.float64)
@@ -152,10 +158,12 @@ def csr_to_dense(m: CSR) -> jax.Array:
 
 
 def csr_row_of_entry(m: CSR) -> jax.Array:
-    """Row id of every padded entry (padding maps to the last row; its data is 0)."""
-    entry = jnp.arange(m.nnz_pad, dtype=jnp.int32)
-    row = jnp.searchsorted(m.indptr, entry, side="right") - 1
-    return jnp.clip(row, 0, m.n_rows - 1).astype(jnp.int32)
+    """Row id of every padded entry (padding maps to the last row; its data is 0).
+
+    Counted, not searched: a one at each row end ``indptr[1:]``, summed up to
+    each entry — one update per row instead of a binary search per entry."""
+    ends = jnp.zeros(m.nnz_pad, jnp.int32).at[m.indptr[1:]].add(1, mode="drop")
+    return jnp.clip(jnp.cumsum(ends), 0, m.n_rows - 1).astype(jnp.int32)
 
 
 def csr_rows_host(m: CSR, r0: int, r1: int, nnz_cap: int | None = None,
