@@ -89,7 +89,7 @@ from repro.core.chunking import (
     ChunkStats, _assemble, a_strips, b_chunks, batch_envelope,
     chunk_gpu1, chunk_gpu2, chunk_knl, instance_envelope,
 )
-from repro.core.kkmem import TRACE_COUNTS, spgemm_ranged_impl
+from repro.core.kkmem import TRACE_COUNTS, key_layout, spgemm_ranged_impl
 from repro.core.planner import (
     ChunkPlan, check_output_caps, csr_field_nbytes, hash_table_slots,
     planned_stats_bsr, planned_stats_dense_slab, planned_stats_hash,
@@ -401,7 +401,8 @@ def chunk_knl_scan(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int):
     with span("stage") as sp:
         _, chunks, _, Bs, r0s, r1s = _stage(None, B, plan, sp)
         C0 = _empty_c(A.n_rows, B.n_cols, c_pad, A.dtype)
-    with span("launch"):
+    with span("launch") as sp:
+        sp.note(key=key_layout(A.n_rows, B.n_cols))
         C = _knl_scan(A, Bs, r0s, r1s, C0, c_pad)
     stats = planned_stats(plan, chunks[0].nbytes(), 0, 0)
     return C, stats
@@ -412,7 +413,8 @@ def chunk_gpu1_scan(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int):
         strips, chunks, As, Bs, r0s, r1s = _stage(A, B, plan, sp)
         strip_rows = strips[0].n_rows
         C0 = _empty_c(strip_rows, B.n_cols, c_pad, A.dtype)
-    with span("launch"):
+    with span("launch") as sp:
+        sp.note(key=key_layout(strip_rows, B.n_cols))
         Cs = _chunk1_scan(As, Bs, r0s, r1s, C0, c_pad)
     stats = planned_stats(plan, chunks[0].nbytes(), strips[0].nbytes(),
                           _c_strip_nbytes(strip_rows, c_pad, A.dtype))
@@ -424,7 +426,8 @@ def chunk_gpu2_scan(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int):
         strips, chunks, As, Bs, r0s, r1s = _stage(A, B, plan, sp)
         strip_rows = strips[0].n_rows
         C0s = _empty_c_stack(plan.n_ac, strip_rows, B.n_cols, c_pad, A.dtype)
-    with span("launch"):
+    with span("launch") as sp:
+        sp.note(key=key_layout(strip_rows, B.n_cols))
         Cs = _chunk2_scan(As, Bs, r0s, r1s, C0s, c_pad)
     stats = planned_stats(plan, chunks[0].nbytes(), strips[0].nbytes(),
                           _c_strip_nbytes(strip_rows, c_pad, A.dtype))

@@ -137,12 +137,19 @@ def _run_sums(keys, vals, depth: int):
     return vals
 
 
+def key_layout(m: int, n: int) -> str:
+    """The sort key the ESC body uses for an ``m x n`` output: ``"packed"``,
+    ``row * n + col`` in one uint32, where ``(m + 1) * n < 2**32`` (row ``m``
+    keys the padding), else ``"pair"``, the (row, col) pair in a two-key sort."""
+    return "packed" if 0 < n and (m + 1) * n < 2**32 else "pair"
+
+
 def _accumulate(rows, cols, vals, m: int, n: int, c_pad: int, max_terms: int):
     """Sort-based accumulator: coalesce duplicate (row, col) into CSR arrays.
 
-    One sort carries the values: the stream is keyed by ``row * n + col`` in
-    one uint32 where ``(m + 1) * n`` fits (a static shape test), else by the
-    (row, col) pair in a two-key sort. A segmented sum of
+    One sort carries the values, keyed as ``key_layout(m, n)`` says (a static
+    shape test): by ``row * n + col`` in one uint32 where it fits, else by
+    the (row, col) pair in a two-key sort. A segmented sum of
     ``ceil(log2(max_terms))`` shift-and-add steps leaves each run's total on
     its last element (``max_terms`` bounds the products of one output entry).
     A second sort moves the run ends, in key order, to the front; the first
@@ -153,8 +160,9 @@ def _accumulate(rows, cols, vals, m: int, n: int, c_pad: int, max_terms: int):
     (the ``sparse`` merge), which reject captured array constants.
     Returns (indptr[m+1], indices[c_pad], data[c_pad]).
     """
-    packed = 0 < n and (m + 1) * n < 2**32
-    TRACE_COUNTS["esc_key_packed" if packed else "esc_key_pair"] += 1
+    layout = key_layout(m, n)
+    TRACE_COUNTS["esc_key_" + layout] += 1
+    packed = layout == "packed"
     if packed:
         keys = (rows.astype(jnp.uint32) * np.uint32(n) + cols.astype(jnp.uint32),)
         tail = (np.uint32(m * n),)

@@ -34,7 +34,7 @@ import dataclasses
 
 from repro.core import backend_registry
 from repro.core.chunking import ChunkStats, instance_envelope
-from repro.core.kkmem import spgemm
+from repro.core.kkmem import key_layout, spgemm
 from repro.core.planner import BackendFastModel, PipelinePlan, plan_pipeline
 from repro.core.spans import span
 from repro.core.symbolic import PipelineCaps, pipeline_output_caps
@@ -92,7 +92,8 @@ def _run_hop(X: CSR, Y: CSR, plan, caps, backend: str):
     if plan.algorithm == "whole_fast":
         stats = ChunkStats("whole_fast", 1, 1)
         stats.add_in(X.nbytes() + Y.nbytes())
-        with span("launch"):
+        with span("launch") as sp:
+            sp.note(key=key_layout(X.n_rows, Y.n_cols))
             C = spgemm(X, Y, caps.c_pad)
         stats.add_out(C.nbytes())
         stats.kernel_calls = 1

@@ -24,7 +24,8 @@ from jaxlib.mlir import ir
 from repro.core import backend_registry
 from repro.core.chunking import instance_envelope
 from repro.core.kkmem import (
-    TRACE_COUNTS, spgemm, spgemm_ranged, spgemm_ranged_impl, spgemm_symbolic_host,
+    TRACE_COUNTS, key_layout, spgemm, spgemm_ranged, spgemm_ranged_impl,
+    spgemm_symbolic_host,
 )
 from repro.core.planner import ChunkPlan
 from repro.core.symbolic import spgemm_pattern_host, spgemm_structure_host
@@ -203,6 +204,12 @@ def test_key_layout_at_its_limit(m, n, key):
     got, ref = _run(A, B, 0, k, Cp, c_pad)
     _check(got, ref, A, B, 0, k, Cp, c_pad)
     assert _key_bumps(A, B, 0, k, Cp, c_pad) == {key: 1}
+
+
+@pytest.mark.parametrize("m,n,key", PAIR_GEOMETRIES + [(0, 2**32, "esc_key_pair"),
+                                                     (5, 0, "esc_key_pair")])
+def test_key_layout_names_the_key_the_body_sorts_on(m, n, key):
+    assert "esc_key_" + key_layout(m, n) == key
 
 
 @pytest.mark.parametrize("width", [1, 7, 8, 15, 16, 27])

@@ -17,16 +17,17 @@ Three contracts from the pipeline work:
 
 import numpy as np
 import pytest
+import scipy.sparse as sp
 
 from repro.core.chunk_stream import TRACE_COUNTS
 from repro.core.kkmem import spgemm_dense_oracle
-from repro.core.memory_model import P100
+from repro.core.memory_model import P100, TPU_V5E
 from repro.core.pipeline_spgemm import pipeline_spgemm
 from repro.core.planner import plan_knl, plan_pipeline
 from repro.core.symbolic import masked_output_caps, pipeline_output_caps
 from repro.core.triangle import count_triangles, count_triangles_dense
 from repro.sparse import graphs, multigrid
-from repro.sparse.csr import csr_to_dense
+from repro.sparse.csr import csr_from_scipy_like, csr_to_dense
 
 SIZES = {"laplace3d": 4, "bigstar2d": 8, "brick3d": 4, "elasticity": 3}
 
@@ -146,3 +147,119 @@ def test_masked_triangle_compiles_once_per_envelope():
     assert t1 == t2
     assert any(k.endswith("_hash_masked") for k in before), \
         "masked run never hit a masked hash core"
+
+
+# ---------------------------------------------------------------------------
+# the benchmark's HPCG operators on the spilled, chunk2-chunk2 path, and the
+# ESC body's two key layouts inside one pipeline
+# ---------------------------------------------------------------------------
+
+
+def _host_csr(C):
+    """The program's CSR as a SciPy CSR of its stored entries."""
+    ip, ix, d = (np.asarray(x) for x in (C.indptr, C.indices, C.data))
+    nnz = int(ip[-1])
+    return sp.csr_matrix((d[:nnz], ix[:nnz], ip), shape=C.shape)
+
+
+def _check_rap(got, A, P, R, tol):
+    """``got`` (SciPy CSR) has the structure of |R| (|A| |P|), in which no
+    entry cancels, and every value within ``tol`` of the float64 product,
+    relative to the same absolute product."""
+    A, P, R = (m.astype(np.float64) for m in (A, P, R))
+    bound = abs(R) @ (abs(A) @ abs(P))
+    bound.sort_indices()
+    np.testing.assert_array_equal(got.indptr, bound.indptr)
+    np.testing.assert_array_equal(got.indices, bound.indices)
+    ref = (R @ (A @ P)).toarray()
+    err = np.abs(got.toarray() - ref)
+    assert (err <= tol * bound.toarray()).all(), float(err.max())
+
+
+def _bench_hpcg(grid):
+    """The benchmark's HPCG operators (``box27`` and ``full_weighting``) at
+    ``grid``, as SciPy CSRs and as the program's CSRs."""
+    from bench import operators, program, registry
+
+    config = registry.read_json(registry.ROOT / "bench/configs/hpcg27_104.json")
+    ops = operators.build({**config, "grid": grid})
+    return ops, [program.to_program(m) for m in (ops.A, ops.P, ops.R)]
+
+
+def test_bench_hpcg_spills_the_intermediate_and_streams_both_hops():
+    """The benchmark's 104^3 cell at grid 12: a fast limit that spills T
+    and plans both hops as chunk2, as the v5e's memory plans grid 104. The
+    float32 error of each entry is at most about (27 + 27 + 2) roundings of
+    2**-24 relative to |R| |A| |P| (two sums of up to 27 terms, the product
+    and T's rounding); 1e-5 leaves room above that."""
+    ops, (A, P, R) = _bench_hpcg(12)
+    plan = plan_pipeline(A, P, R, TPU_V5E, fast_limit_bytes=120_000)
+    assert not plan.t_resident
+    assert (plan.plan1.algorithm, plan.plan2.algorithm) == ("chunk2", "chunk2")
+    assert plan.plan1.n_ac > 1 and plan.plan2.n_ac > 1 and plan.plan2.n_b > 1
+    C, stats = pipeline_spgemm(A, P, R, plan)
+    assert stats.spilled and stats.spill_bytes > 0
+    _check_rap(_host_csr(C), ops.A, ops.P, ops.R, 1e-5)
+
+
+# hop 1's strips of 32 rows against 2**27 columns key past 2**32; hop 2's
+# strips of 4 rows do not
+WIDE = 2**27
+
+
+def _wide_operands():
+    """A 64 x 24, P 24 x 2**27 with 12 columns in use, the last among them,
+    and R 8 x 64, which is not P^T."""
+    rng = np.random.default_rng(15)
+    A = sp.random(64, 24, density=0.3, random_state=rng, dtype=np.float32,
+                  format="csr")
+    cols = np.sort(np.concatenate([rng.choice(WIDE - 1, 11, replace=False),
+                                   [WIDE - 1]]))
+    Pc = sp.random(24, cols.size, density=0.5, random_state=rng,
+                   dtype=np.float32, format="csr")
+    P = sp.csr_matrix((Pc.data, cols[Pc.indices], Pc.indptr), shape=(24, WIDE))
+    R = sp.random(8, 64, density=0.4, random_state=rng, dtype=np.float32,
+                  format="csr")
+    for m in (A, P, R):
+        m.sort_indices()
+    return A, P, R, cols
+
+
+def test_pair_key_in_hop_1_and_packed_key_in_hop_2(tmp_path):
+    """Both key layouts in one pipeline: the launch span of each hop says
+    which sort it ran, as the ESC body's trace counters do."""
+    import jax
+
+    from bench import program_spans
+    from repro.core.kkmem import key_layout
+    from repro.core.planner import ChunkPlan, PipelinePlan
+
+    A, P, R, cols = _wide_operands()
+    assert (key_layout(32, WIDE), key_layout(4, WIDE)) == ("pair", "packed")
+    plan = PipelinePlan(ChunkPlan("chunk2", (0, 32, 64), (0, 24), 0, 0),
+                        ChunkPlan("chunk2", (0, 4, 8), (0, 32, 64), 0, 0),
+                        False, 0.0, 0.0, 0.0)
+    a, p, r = (csr_from_scipy_like(m.indptr, m.indices, m.data, m.shape)
+               for m in (A, P, R))
+    before = dict(TRACE_COUNTS)
+    pipeline_spgemm(a, p, r, plan)
+    traced = {k: v - before.get(k, 0) for k, v in TRACE_COUNTS.items()
+              if k.startswith("esc_key") and v != before.get(k, 0)}
+    # one body per hop: chunk2's inner scan traces it once
+    assert traced == {"esc_key_pair": 1, "esc_key_packed": 1}
+
+    jax.profiler.start_trace(str(tmp_path))
+    C, stats = pipeline_spgemm(a, p, r, plan)
+    jax.profiler.stop_trace()
+    assert stats.spilled
+    path, = tmp_path.rglob("*.xplane.pb")
+    spans = program_spans.load(path)
+    hops = [(s, e, st["hop"]) for n, s, e, st, _ in spans if n == "spgemm/hop"]
+    keys = {next(h for hs, he, h in hops if hs <= s and e <= he): st["key"]
+            for n, s, e, st, _ in spans if n == "spgemm/launch"}
+    assert keys == {1: "pair", 2: "packed"}
+
+    # the reference on P's columns in use: C holds no entry elsewhere
+    got = _host_csr(C)
+    assert got[:, cols].nnz == got.nnz
+    _check_rap(got[:, cols], A, P[:, cols], R, 1e-5)
