@@ -89,7 +89,10 @@ from repro.core.chunking import (
     ChunkStats, _assemble, a_strips, b_chunks, batch_envelope,
     chunk_gpu1, chunk_gpu2, chunk_knl, instance_envelope,
 )
-from repro.core.kkmem import TRACE_COUNTS, key_layout, spgemm_ranged_impl
+from repro.core.kkmem import (
+    TRACE_COUNTS, ell_tables, key_layout, spgemm_ranged_impl,
+    spgemm_ranged_tables,
+)
 from repro.core.planner import (
     ChunkPlan, check_output_caps, csr_field_nbytes, hash_table_slots,
     planned_stats_bsr, planned_stats_dense_slab, planned_stats_hash,
@@ -166,12 +169,21 @@ def _chunk1_scan_impl(As: CSR, Bs: CSR, r0s, r1s, C0: CSR, c_pad: int) -> CSR:
 
 def _chunk2_scan_impl(As: CSR, Bs: CSR, r0s, r1s, C0s: CSR, c_pad: int) -> CSR:
     def outer(Cs, x):
+        TRACE_COUNTS["chunk2_tables"] += 1
         Bc, r0, r1 = x
+        # The chunk's ELL tables, built once here for every strip's step.
+        # They are held flat through the strip loop: a two-dimensional table
+        # held there may take a padded tiled layout, and each step's reshape
+        # is a copy that dies with the step.
+        tables = ell_tables(Bc)
+        shape = tables[0].shape
+        flat = [t.reshape(-1) for t in tables]
 
         def inner(carry, y):
             TRACE_COUNTS["chunk2_body"] += 1
             Ai, Ci = y
-            return carry, spgemm_ranged_impl(Ai, Bc, r0, r1, Ci, c_pad)
+            tabs = [t.reshape(shape) for t in flat]
+            return carry, spgemm_ranged_tables(Ai, tabs, r0, r1, Ci, c_pad)
 
         _, Cs2 = lax.scan(inner, None, (As, Cs))
         return Cs2, None
@@ -401,10 +413,12 @@ def chunk_knl_scan(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int):
     with span("stage") as sp:
         _, chunks, _, Bs, r0s, r1s = _stage(None, B, plan, sp)
         C0 = _empty_c(A.n_rows, B.n_cols, c_pad, A.dtype)
-    with span("launch") as sp:
-        sp.note(key=key_layout(A.n_rows, B.n_cols))
-        C = _knl_scan(A, Bs, r0s, r1s, C0, c_pad)
     stats = planned_stats(plan, chunks[0].nbytes(), 0, 0)
+    del chunks      # the stack holds them: no second copy while the core runs
+    with span("launch") as sp:
+        sp.note(key=key_layout(A.n_rows, B.n_cols), tables=plan.n_b,
+                steps=plan.n_b)
+        C = _knl_scan(A, Bs, r0s, r1s, C0, c_pad)
     return C, stats
 
 
@@ -413,11 +427,14 @@ def chunk_gpu1_scan(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int):
         strips, chunks, As, Bs, r0s, r1s = _stage(A, B, plan, sp)
         strip_rows = strips[0].n_rows
         C0 = _empty_c(strip_rows, B.n_cols, c_pad, A.dtype)
-    with span("launch") as sp:
-        sp.note(key=key_layout(strip_rows, B.n_cols))
-        Cs = _chunk1_scan(As, Bs, r0s, r1s, C0, c_pad)
     stats = planned_stats(plan, chunks[0].nbytes(), strips[0].nbytes(),
                           _c_strip_nbytes(strip_rows, c_pad, A.dtype))
+    del strips, chunks  # the stacks hold them: no second copy while the core runs
+    with span("launch") as sp:
+        # A strip outer: each chunk's table is built anew for every strip
+        steps = plan.n_ac * plan.n_b
+        sp.note(key=key_layout(strip_rows, B.n_cols), tables=steps, steps=steps)
+        Cs = _chunk1_scan(As, Bs, r0s, r1s, C0, c_pad)
     return _assemble(Cs, plan.p_ac, B.n_cols), stats
 
 
@@ -426,11 +443,13 @@ def chunk_gpu2_scan(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int):
         strips, chunks, As, Bs, r0s, r1s = _stage(A, B, plan, sp)
         strip_rows = strips[0].n_rows
         C0s = _empty_c_stack(plan.n_ac, strip_rows, B.n_cols, c_pad, A.dtype)
-    with span("launch") as sp:
-        sp.note(key=key_layout(strip_rows, B.n_cols))
-        Cs = _chunk2_scan(As, Bs, r0s, r1s, C0s, c_pad)
     stats = planned_stats(plan, chunks[0].nbytes(), strips[0].nbytes(),
                           _c_strip_nbytes(strip_rows, c_pad, A.dtype))
+    del strips, chunks  # the stacks hold them: no second copy while the core runs
+    with span("launch") as sp:
+        sp.note(key=key_layout(strip_rows, B.n_cols), tables=plan.n_b,
+                steps=plan.n_ac * plan.n_b)
+        Cs = _chunk2_scan(As, Bs, r0s, r1s, C0s, c_pad)
     return _assemble(Cs, plan.p_ac, B.n_cols), stats
 
 

@@ -83,9 +83,13 @@ def spgemm_symbolic_host(A: CSR, B: CSR, pad_multiple: int = 64) -> SpGEMMWorksp
 # ---------------------------------------------------------------------------
 
 
-def _ell(B: CSR, width: int):
-    """B's rows as ELL tables ``[width, n_rows]`` of column indices and values:
-    slot j of row r holds B's entry ``indptr[r] + j``, column -1 past the row's end."""
+def ell_tables(B: CSR):
+    """B's rows as ELL tables ``[width, n_rows]`` of column indices and values,
+    ``width = max(B.max_row_nnz, 1)``: slot j of row r holds B's entry
+    ``indptr[r] + j``, column -1 past the row's end. One element gather over
+    every slot; a caller that multiplies by the same B more than once builds
+    the tables once and passes them to ``spgemm_ranged_tables``."""
+    width = max(B.max_row_nnz, 1)
     start = B.indptr[:-1]
     j = jnp.arange(width, dtype=jnp.int32)[:, None]
     ok = j < (B.indptr[1:] - start)[None, :]
@@ -93,22 +97,23 @@ def _ell(B: CSR, width: int):
     return jnp.where(ok, B.indices[src], -1), jnp.where(ok, B.data[src], 0.0)
 
 
-def _expand_products(A: CSR, B: CSR, r0, r1):
+def _expand_products(A: CSR, tables, r0, r1):
     """Fan every (valid, in-range) A entry out into its products with B's rows.
 
-    Returns (rows, cols, vals) of static length nnzA_pad * B.max_row_nnz; invalid
+    Returns (rows, cols, vals) of static length nnzA_pad * width; invalid
     slots get row = A.n_rows (sorts to the tail) and val = 0. B's rows come as
-    one column gather per A entry from B's ELL tables, so the stream is slot-major.
+    one column gather per A entry from B's ELL ``tables`` (``ell_tables``), so
+    the stream is slot-major.
 
-    ``r0, r1`` bound the *global* column range of A handled by this call; B is the
-    CSR of exactly that row range (local row r_global - r0). For the unchunked case
-    pass r0=0, r1=A.n_cols with B the full matrix.
+    ``r0, r1`` bound the *global* column range of A handled by this call; the
+    tables are those of exactly that row range of B (local row r_global - r0).
+    For the unchunked case pass r0=0, r1=A.n_cols with the full matrix's.
     """
+    ell_cols, ell_vals = tables                              # [width, B rows]
     t = jnp.arange(A.nnz_pad, dtype=jnp.int32)
     in_range = (A.indices >= r0) & (A.indices < r1) & (t < A.indptr[-1])
-    b_row = jnp.clip(A.indices - r0, 0, B.n_rows - 1)
-    ell_cols, ell_vals = _ell(B, max(B.max_row_nnz, 1))
-    cols, vals = ell_cols[:, b_row], ell_vals[:, b_row]      # [bmax, nnzA_pad]
+    b_row = jnp.clip(A.indices - r0, 0, ell_cols.shape[1] - 1)
+    cols, vals = ell_cols[:, b_row], ell_vals[:, b_row]      # [width, nnzA_pad]
     valid = in_range[None, :] & (cols >= 0)
     rows = jnp.where(valid, csr_row_of_entry(A)[None, :], A.n_rows)
     cols = jnp.where(valid, cols, 0)
@@ -193,26 +198,29 @@ def _accumulate(rows, cols, vals, m: int, n: int, c_pad: int, max_terms: int):
 @partial(jax.jit, static_argnames=("c_pad", "c_max_row_nnz"))
 def spgemm(A: CSR, B: CSR, c_pad: int, c_max_row_nnz: int = 0) -> CSR:
     """Numeric phase of C = A x B. ``c_pad`` comes from ``spgemm_symbolic_host``."""
-    rows, cols, vals = _expand_products(A, B, 0, A.n_cols)
+    rows, cols, vals = _expand_products(A, ell_tables(B), 0, A.n_cols)
     indptr, indices, data = _accumulate(rows, cols, vals, A.n_rows, B.n_cols,
                                         c_pad, max(A.max_row_nnz, 1))
     return CSR(indptr, indices, data, (A.n_rows, B.n_cols),
                c_max_row_nnz or c_pad)
 
 
-def spgemm_ranged_impl(A: CSR, B_chunk: CSR, r0, r1, C_prev: CSR, c_pad: int,
-                       c_max_row_nnz: int = 0) -> CSR:
-    """Fused multiply-add over a B row-range: C = A[:, r0:r1] x B_chunk + C_prev.
+def spgemm_ranged_tables(A: CSR, tables, r0, r1, C_prev: CSR, c_pad: int,
+                         c_max_row_nnz: int = 0) -> CSR:
+    """Fused multiply-add over a B row-range: C = A[:, r0:r1] x B_chunk + C_prev,
+    with ``B_chunk`` given as its ELL ``tables`` (``ell_tables``); C has
+    ``C_prev``'s shape.
 
     The previous partial result's entries join the product stream before
     accumulation — the paper's fused-add into the hashmap accumulators. A is NOT
     physically column-partitioned; out-of-range entries are masked ("skipped").
 
-    This is the traceable body; ``spgemm_ranged`` is the jitted entry point. The
-    scan executors (repro.core.chunk_stream) inline this body inside a
-    ``lax.scan`` so the whole chunk loop compiles as one program.
+    This is the traceable body. The scan executors (repro.core.chunk_stream)
+    inline it inside a ``lax.scan`` so the whole chunk loop compiles as one
+    program; where a chunk stays while A's strips pass, its tables are built
+    once for all of them.
     """
-    rows, cols, vals = _expand_products(A, B_chunk, r0, r1)
+    rows, cols, vals = _expand_products(A, tables, r0, r1)
     prev_entry = jnp.arange(C_prev.nnz_pad, dtype=jnp.int32)
     prev_valid = prev_entry < C_prev.indptr[-1]
     prev_rows = jnp.where(prev_valid, csr_row_of_entry(C_prev), A.n_rows)
@@ -223,10 +231,19 @@ def spgemm_ranged_impl(A: CSR, B_chunk: CSR, r0, r1, C_prev: CSR, c_pad: int,
     vals = jnp.concatenate([vals, prev_vals])
     # an output entry (i, j) gets at most one product per stored A[i, k],
     # plus C_prev's entry
-    indptr, indices, data = _accumulate(rows, cols, vals, A.n_rows, B_chunk.n_cols,
+    indptr, indices, data = _accumulate(rows, cols, vals, A.n_rows, C_prev.n_cols,
                                         c_pad, A.max_row_nnz + 1)
-    return CSR(indptr, indices, data, (A.n_rows, B_chunk.n_cols),
+    return CSR(indptr, indices, data, (A.n_rows, C_prev.n_cols),
                c_max_row_nnz or c_pad)
+
+
+def spgemm_ranged_impl(A: CSR, B_chunk: CSR, r0, r1, C_prev: CSR, c_pad: int,
+                       c_max_row_nnz: int = 0) -> CSR:
+    """``spgemm_ranged_tables`` on the CSR of B's row range, tabulated here:
+    the body of ``spgemm_ranged`` (the jitted entry point), of the ``knl``
+    and ``chunk1`` scan cores, and of the ``sparse``/``hash`` merges."""
+    return spgemm_ranged_tables(A, ell_tables(B_chunk), r0, r1, C_prev, c_pad,
+                                c_max_row_nnz)
 
 
 spgemm_ranged = partial(jax.jit, static_argnames=("c_pad", "c_max_row_nnz"))(

@@ -10,8 +10,15 @@ the streamed operand), so its contract is allclose to the oracle at matched
 (``planned_stats_pallas``).
 """
 
+import gc
+import weakref
+from functools import partial
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import lax
 
 from repro.core.chunk_stream import (
     TRACE_COUNTS, chunk_gpu1_pallas, chunk_gpu1_scan, chunk_gpu2_pallas,
@@ -22,10 +29,14 @@ from repro.core.chunking import (
     batch_envelope, chunk_gpu1, chunk_gpu2, chunk_knl, chunked_spgemm,
     instance_envelope,
 )
-from repro.core.kkmem import spgemm_dense_oracle, spgemm_symbolic_host
+from repro.core.kkmem import (
+    key_layout, spgemm_dense_oracle, spgemm_ranged_impl, spgemm_symbolic_host,
+)
 from repro.core.planner import ChunkPlan, plan_knl
 from repro.sparse import multigrid
-from repro.sparse.csr import csr_from_dense, csr_to_dense
+from repro.sparse.csr import (
+    CSR, csr_from_dense, csr_from_scipy_like, csr_to_dense,
+)
 from conftest import assert_close, csr_pair_cases, random_csr
 
 LOOP = {"knl": chunk_knl, "chunk1": chunk_gpu1, "chunk2": chunk_gpu2}
@@ -347,3 +358,135 @@ def test_make_batched_cores_isolated_caches(backend):
     assert TRACE_COUNTS[counter] - before == 2   # fresh set: its own cache
     run(cores_d)
     assert TRACE_COUNTS[counter] - before == 2
+
+
+# ---------------------------------------------------------------------------
+# B's ELL tables: built once per call, in place of the CSR chunks
+# ---------------------------------------------------------------------------
+
+
+def _sparse_csr(rng, m, n, nnz, pad=0):
+    """An ``m x n`` program CSR with about ``nnz`` entries at random places,
+    rows of unequal lengths and some empty."""
+    import scipy.sparse as sps
+
+    r, c = rng.integers(0, m, nnz), rng.integers(0, n, nnz)
+    M = sps.csr_matrix((rng.standard_normal(nnz).astype(np.float32), (r, c)),
+                       shape=(m, n))
+    M.sum_duplicates()
+    M.data[M.data == 0] = 1.0
+    return csr_from_scipy_like(M.indptr, M.indices, M.data, M.shape,
+                               pad_to=M.nnz + pad)
+
+
+# (A rows, inner, C cols, A/C strip bounds, B chunk bounds) per sort key:
+# the strips of the pair geometry have (65535 + 1) * 65536 = 2**32 keys.
+# The B chunks have 5, 8 and 11 rows, padded to 11, so every chunk's tables
+# carry -1 slots past its rows' ends and in its padding rows.
+KEYED = {
+    "packed": (40, 24, 31, (0, 17, 40), (0, 5, 13, 24)),
+    "pair": (2 * 65535, 24, 65536, (0, 65535, 2 * 65535), (0, 5, 13, 24)),
+}
+
+
+@pytest.mark.parametrize("key", sorted(KEYED))
+@pytest.mark.parametrize("algorithm", ["knl", "chunk1", "chunk2"])
+def test_scan_tables_match_loop_on_both_sort_keys(algorithm, key):
+    """Each order of the scan backend, which reads B from tables built once
+    per call, gives the loop oracle's CSR bit for bit, with n_ac >= 2 (but
+    knl) and n_b = 3 on each sort key; a same-structure batch does too."""
+    m, k, n, p_ac, p_b = KEYED[key]
+    rng = np.random.default_rng(len(key) + len(algorithm))
+    A = _sparse_csr(rng, m, k, 160, pad=3)
+    B = _sparse_csr(rng, k, n, 90, pad=2)
+    if algorithm == "knl":
+        p_ac = (0, m)
+    plan = ChunkPlan(algorithm, p_ac, p_b, 0.0, 0.0)
+    strip_rows = max(e - s for s, e in zip(p_ac[:-1], p_ac[1:]))
+    assert key_layout(strip_rows, n) == key
+    c_pad = spgemm_symbolic_host(A, B).c_pad
+    Cl, sl = LOOP[algorithm](A, B, plan, c_pad)
+    Cs, ss = SCAN[algorithm](A, B, plan, c_pad)
+    _assert_same_csr(Cl, Cs)
+    assert sl.per_copy_in == ss.per_copy_in
+    assert sl.per_copy_out == ss.per_copy_out
+
+    # the same structure with other values, as one batch
+    def revalue(M, seed):
+        data = np.random.default_rng(seed).standard_normal(M.nnz_pad)
+        return CSR(M.indptr, M.indices, jnp.asarray(data, jnp.float32)
+                   * (jnp.arange(M.nnz_pad) < M.indptr[-1]), M.shape,
+                   M.max_row_nnz)
+
+    As, Bs = [A, revalue(A, 1)], [B, revalue(B, 2)]
+    env = batch_envelope(As, Bs, plan, c_pad=c_pad)
+    Cb_list, _ = chunked_spgemm_batched(As, Bs, plan, envelope=env)
+    for Ai, Bi, Cb in zip(As, Bs, Cb_list):
+        _assert_same_csr(LOOP[algorithm](Ai, Bi, plan, c_pad)[0], Cb)
+
+
+def _chunk2_tabulating_every_step(As, Bs, r0s, r1s, C0s, c_pad):
+    """The chunk2 core as it was before its tables left the strip loop:
+    every strip step tabulates the chunk again (``spgemm_ranged_impl``)."""
+    def outer(Cs, x):
+        Bc, r0, r1 = x
+
+        def inner(carry, y):
+            Ai, Ci = y
+            return carry, spgemm_ranged_impl(Ai, Bc, r0, r1, Ci, c_pad)
+
+        return lax.scan(inner, None, (As, Cs))[1], None
+
+    return lax.scan(outer, C0s, (Bs, r0s, r1s))[0]
+
+
+def test_chunk2_tables_live_only_inside_the_core(monkeypatch):
+    """While the chunk2 core runs, the executor holds B's chunks and A's
+    strips only as the stacks the core takes, and no table: no staged
+    strip or chunk and no device array of a table's size is alive when the
+    core is called, nor a table after it returns. The compiled core needs
+    no more temporaries than one that tabulates in every strip step."""
+    from repro.core import chunk_stream
+
+    rng = np.random.default_rng(17)
+    A, B = random_csr(rng, 30, 26, 0.2), random_csr(rng, 26, 22, 0.25)
+    plan = ChunkPlan("chunk2", (0, 9, 20, 30), (0, 8, 26), 0.0, 0.0)
+    c_pad = spgemm_symbolic_host(A, B).c_pad
+    refs, seen = [], []
+
+    def watched(split):
+        def pieces(*args, **kwargs):
+            out = split(*args, **kwargs)
+            refs.extend(weakref.ref(x) for M in out
+                        for x in (M.indptr, M.indices, M.data))
+            return out
+        return pieces
+
+    core = chunk_stream._chunk2_scan
+
+    def watched_core(As, Bs, r0s, r1s, C0s, c_pad):
+        gc.collect()
+        seen.append(([r() is None for r in refs], Bs,
+                     {x.size for x in jax.live_arrays()}))
+        hoisted, per_step = (
+            jax.jit(partial(f, c_pad=c_pad)).lower(As, Bs, r0s, r1s, C0s)
+            .compile().memory_analysis().temp_size_in_bytes
+            for f in (chunk_stream._chunk2_scan_impl,
+                      _chunk2_tabulating_every_step))
+        assert hoisted <= per_step
+        return core(As, Bs, r0s, r1s, C0s, c_pad)
+
+    for name in ("a_strips", "b_chunks"):
+        monkeypatch.setattr(chunk_stream, name,
+                            watched(getattr(chunk_stream, name)))
+    monkeypatch.setattr(chunk_stream, "_chunk2_scan", watched_core)
+    C, _ = chunk_gpu2_scan(A, B, plan, c_pad)
+    (dropped, Bs, live), = seen
+    assert len(dropped) == 3 * (plan.n_ac + plan.n_b) and all(dropped)
+    table = Bs.max_row_nnz * Bs.n_rows
+    assert table not in {Bs.indices.shape[-1], Bs.indptr.shape[-1]}
+    assert table not in live
+    del Bs, seen
+    gc.collect()
+    assert table not in {x.size for x in jax.live_arrays()}
+    _assert_same_csr(chunk_gpu2(A, B, plan, c_pad)[0], C)

@@ -11,6 +11,8 @@ which the body's fixed-depth segmented sum needs; the symbolic phase refuses
 other operands.
 """
 
+import collections
+import pathlib
 import re
 from functools import partial
 
@@ -21,7 +23,7 @@ import pytest
 import scipy.sparse as sp
 from jaxlib.mlir import ir
 
-from repro.core import backend_registry
+from repro.core import backend_registry, chunk_stream, spans
 from repro.core.chunking import instance_envelope
 from repro.core.kkmem import (
     TRACE_COUNTS, key_layout, spgemm, spgemm_ranged, spgemm_ranged_impl,
@@ -374,19 +376,22 @@ def _walk(ops):
                 yield from _walk(block.operations)
 
 
+def _n_indices(o) -> int:
+    """Number of indices of a gather or scatter op."""
+    shape = list(ir.RankedTensorType(o.operands[1].type).shape)
+    dims = re.search(r"index_vector_dim = (\d+)", str(o.attributes))
+    vec = int(dims.group(1)) if dims else len(shape)
+    return int(np.prod([s for a, s in enumerate(shape) if a != vec]))
+
+
 def _index_counts(lowered) -> dict:
     """Number of indices of every gather and scatter in the StableHLO."""
     module = lowered.compiler_ir("stablehlo")
     counts = {"stablehlo.gather": [], "stablehlo.scatter": []}
     with module.context:
         for o in _walk(module.body.operations):
-            if o.name not in counts:
-                continue
-            shape = list(ir.RankedTensorType(o.operands[1].type).shape)
-            dims = re.search(r"index_vector_dim = (\d+)", str(o.attributes))
-            vec = int(dims.group(1)) if dims else len(shape)
-            counts[o.name].append(
-                int(np.prod([s for a, s in enumerate(shape) if a != vec])))
+            if o.name in counts:
+                counts[o.name].append(_n_indices(o))
     return counts
 
 
@@ -415,3 +420,89 @@ def test_scan_core_has_no_stream_length_gather_or_scatter():
     c0 = jax.tree.map(lambda x: x[0], target.args[4])
     ref = _index_counts(jax.jit(one_step).lower(ai, bc, c0))
     assert n in ref["stablehlo.gather"] and n in ref["stablehlo.scatter"]
+
+
+def _launch_notes(tmp_path, run):
+    """The stats of every ``spgemm/launch`` span of ``run`` under the
+    profiler."""
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        run()
+    finally:
+        jax.profiler.stop_trace()
+    path, = pathlib.Path(tmp_path).rglob("*.xplane.pb")
+    return [dict(e.stats)
+            for plane in jax.profiler.ProfileData.from_file(str(path)).planes
+            for line in plane.lines for e in line.events
+            if e.name == spans.PREFIX + "launch"]
+
+
+def _gathers_by_loop_depth(lowered) -> dict:
+    """Number of indices of every gather in the StableHLO, keyed by how many
+    ``while`` loops enclose it (through the functions it calls)."""
+    module = lowered.compiler_ir("stablehlo")
+    out = collections.defaultdict(list)
+    with module.context:
+        funcs = {ir.StringAttr(op.operation.attributes["sym_name"]).value: op
+                 for op in module.body.operations
+                 if op.operation.name == "func.func"}
+
+        def walk(ops, depth):
+            for op in ops:
+                o = op.operation
+                if o.name == "stablehlo.gather":
+                    out[depth].append(_n_indices(o))
+                if o.name == "func.call":
+                    callee = ir.FlatSymbolRefAttr(o.attributes["callee"]).value
+                    walk([funcs[callee]], depth)
+                inner = depth + (o.name == "stablehlo.while")
+                for region in o.regions:
+                    for block in region.blocks:
+                        walk(block.operations, inner)
+
+        walk([funcs["main"]], 0)
+    return out
+
+
+def test_chunk2_core_tabulates_each_chunk_once_outside_its_strip_loop(tmp_path):
+    """B's ELL tables are built in the chunk2 core's outer step, once per
+    chunk, and every strip step of the inner loop reads them: the lowered
+    core's inner loop holds no gather of ``width x chunk_rows`` indices and
+    its outer step holds the two of one table (columns, values), as the
+    CSR-taking body at the same shapes does. A chunk2 3x2 call notes 2
+    tables for 6 steps on its launch span, and the tabulation traces once
+    per geometry."""
+    A, _, P = multigrid.problem("brick3d", 6)
+    thirds = (0, A.n_rows // 3, 2 * A.n_rows // 3, A.n_rows)
+    plan = ChunkPlan("chunk2", thirds, (0, P.n_rows // 2, P.n_rows), 0.0, 0.0)
+    env = instance_envelope(A, P, plan)
+    target = backend_registry.get("scan").audit_trace(A, P, plan, env.c_pad, env)
+    As, Bs, r0s, r1s, C0 = target.args
+    table = Bs.max_row_nnz * Bs.n_rows
+    assert table not in {As.indices.shape[-1], env.c_pad, As.n_rows + 1}
+    depth = _gathers_by_loop_depth(jax.jit(target.fn).lower(*target.args))
+    assert depth[1].count(table) == 2, depth
+    assert all(table not in depth[d] for d in depth if d >= 2), depth
+    # each strip step gathers B's rows, one column per A entry, from them
+    assert As.indices.shape[-1] in depth[2], depth
+
+    def one_step(Ai, Bc, r0, r1, C):
+        return spgemm_ranged_impl(Ai, Bc, r0, r1, C, c_pad=env.c_pad)
+
+    ai, bc, c0 = (jax.tree.map(lambda x: x[0], s) for s in (As, Bs, C0))
+    body = _index_counts(jax.jit(one_step).lower(ai, bc, r0s[0], r1s[0], c0))
+    assert body["stablehlo.gather"].count(table) == 2, body
+
+    # counted on a call: its own geometry, so its tabulation traces here
+    rng = np.random.default_rng(3)
+    A2, B2 = _prog(_random(rng, 33, 29, 120)), _prog(_random(rng, 29, 27, 90))
+    plan2 = ChunkPlan("chunk2", (0, 11, 22, 33), (0, 12, 29), 0.0, 0.0)
+    c_pad = spgemm_symbolic_host(A2, B2).c_pad
+    run = lambda: chunk_stream.chunk_gpu2_scan(A2, B2, plan2, c_pad)  # noqa: E731
+    before = TRACE_COUNTS["chunk2_tables"]
+    run()
+    assert TRACE_COUNTS["chunk2_tables"] - before == 1
+    notes = _launch_notes(tmp_path, run)
+    assert TRACE_COUNTS["chunk2_tables"] - before == 1
+    assert [(n["tables"], n["steps"]) for n in notes] == [(2, 6)]
+    assert notes[0]["key"] == "packed"
