@@ -133,7 +133,8 @@ class BackendSpec:
     # the per-copy-event byte model `analysis/traffic.py` holds the traced
     # jaxpr to (exact equality). `meta` is the TraceTarget.meta of the
     # matching audit_trace — the statics (scalar-prefetch tables, chunk
-    # counts) both sides were staged from. None = flow equality not checked
+    # counts) both sides were staged from, and the ChunkStats its staging
+    # logs (`meta["stats"]`). None = flow equality not checked
     # (the scan backend is device-resident: its stats are a replay oracle by
     # design, with no per-chunk pallas copies to reconcile).
     traffic_model: Callable | None = None

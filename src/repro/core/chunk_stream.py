@@ -52,14 +52,26 @@ block analysis opt-in). ``sparse`` and ``hash`` register a
 them and asking for them on a TPU raises. The ``accumulator_shootout`` and
 ``bsr_blocking`` bench lanes measure where the models cross.
 
+Staging is written once. :func:`_stage` cuts one instance's A into strips
+and B into chunks, pads them (to the instance's largest piece, or to a
+``GeometryEnvelope``) and stacks them; each CSR-staged backend builds its
+launch from it (``_scan_launch``, ``_pallas_launch``,
+``_csr_accum_launch``), and its batched entry stages every instance the
+same way and stacks the results once. A backend's ``audit_trace`` is that
+launch staged at an explicit envelope, so the static verifier traces the
+program the executors run: at the instance envelope it is the executor's
+own jit cache entry. The BSR backend cuts (strip, chunk) pairs instead,
+through :func:`_bsr_pair`, for its executor and its audit alike.
+
 Because a traced scan (or Pallas grid) cannot mutate Python-side counters,
 ChunkStats for these backends is *computed from the plan*: the uniform padding
 makes every staged chunk/strip/partial the same size, so the per-copy event
 sequence is reproducible host-side. ``planned_stats`` replays the loop
 executors' CSR-staging events (asserted identical in tests);
-``planned_stats_pallas`` replays the Pallas pipeline's dense-slab DMA events,
-which differ structurally (dense staged sizes; Chunk2's C partials persist in
-VMEM instead of bouncing to slow memory).
+``planned_stats_pallas`` replays the Pallas pipeline's copy events
+(:func:`_pipeline_events`, which the traffic models read too), which
+differ structurally (one stationary piece per outer step; Chunk2's C
+partials persist in VMEM instead of bouncing to slow memory).
 
 Each backend's compile accounting is observable through ``TRACE_COUNTS``
 under the spec's ``trace_key``/``trace_key_batched`` templates
@@ -77,6 +89,7 @@ the request-bucketing service on top.
 from __future__ import annotations
 
 from functools import partial
+from typing import NamedTuple
 
 import numpy as np
 
@@ -114,25 +127,28 @@ from repro.sparse.csr import (
     csr_unstack,
 )
 
-def _empty_c(n_rows: int, n_cols: int, c_pad: int, dtype) -> CSR:
-    """Empty C with ``max_row_nnz=c_pad`` so the scan carry has exactly the
-    pytree structure ``spgemm_ranged_impl`` returns (aux mismatch would fail
-    the carry check)."""
-    return CSR(
-        indptr=jnp.zeros(n_rows + 1, jnp.int32),
-        indices=jnp.zeros(c_pad, jnp.int32),
-        data=jnp.zeros(c_pad, dtype),
-        shape=(n_rows, n_cols),
-        max_row_nnz=c_pad,
-    )
+TraceTarget = backend_registry.TraceTarget
+
+# The kernels' streaming order per plan algorithm: knl is the one-strip
+# special case of the chunk1 order.
+_ORDERS = {"knl": "chunk1", "chunk1": "chunk1", "chunk2": "chunk2"}
 
 
-def _empty_c_stack(n: int, n_rows: int, n_cols: int, c_pad: int, dtype) -> CSR:
-    """Stacked empty partials ([n, ...] leading axis) for the Chunk2 carry."""
+def _cores(make, key: str, **kwargs) -> dict:
+    """One jitted core per plan algorithm, ``make(key, alg, **kwargs)``,
+    with ``key`` the ``"{alg}"`` template of its ``TRACE_COUNTS`` key."""
+    return {alg: make(key.format(alg=alg), alg, **kwargs) for alg in _ORDERS}
+
+
+def _empty_c(lead: tuple, n_rows: int, n_cols: int, c_pad: int, dtype) -> CSR:
+    """An empty C at capacity ``c_pad``, or a stack of them with leading
+    axes ``lead``. ``max_row_nnz=c_pad`` gives it the pytree structure the
+    accumulating cores return (an aux mismatch would fail the scan carry
+    check)."""
     return CSR(
-        indptr=jnp.zeros((n, n_rows + 1), jnp.int32),
-        indices=jnp.zeros((n, c_pad), jnp.int32),
-        data=jnp.zeros((n, c_pad), dtype),
+        indptr=jnp.zeros((*lead, n_rows + 1), jnp.int32),
+        indices=jnp.zeros((*lead, c_pad), jnp.int32),
+        data=jnp.zeros((*lead, c_pad), dtype),
         shape=(n_rows, n_cols),
         max_row_nnz=c_pad,
     )
@@ -214,47 +230,15 @@ def _chunk2_scan(As: CSR, Bs: CSR, r0s, r1s, C0s: CSR, c_pad: int) -> CSR:
     return _chunk2_scan_impl(As, Bs, r0s, r1s, C0s, c_pad)
 
 
-# Batched (vmapped) cores: one jitted program per (envelope, plan, batch)
-# geometry. Each gets its own TRACE_COUNTS key so the serving layer can assert
-# "one compile per geometry bucket" directly.
-
-
-@partial(jax.jit, static_argnames=("c_pad",))
-def _knl_scan_batched(Ast: CSR, Bst: CSR, r0s, r1s, C0s: CSR, c_pad: int) -> CSR:
-    TRACE_COUNTS["knl_batched"] += 1
-    return jax.vmap(
-        lambda A, Bs, C0: _knl_scan_impl(A, Bs, r0s, r1s, C0, c_pad)
-    )(Ast, Bst, C0s)
-
-
-@partial(jax.jit, static_argnames=("c_pad",))
-def _chunk1_scan_batched(Ast: CSR, Bst: CSR, r0s, r1s, C0: CSR, c_pad: int) -> CSR:
-    TRACE_COUNTS["chunk1_batched"] += 1
-    return jax.vmap(
-        lambda As, Bs: _chunk1_scan_impl(As, Bs, r0s, r1s, C0, c_pad)
-    )(Ast, Bst)
-
-
-@partial(jax.jit, static_argnames=("c_pad",))
-def _chunk2_scan_batched(Ast: CSR, Bst: CSR, r0s, r1s, C0s: CSR, c_pad: int) -> CSR:
-    TRACE_COUNTS["chunk2_batched"] += 1
-    return jax.vmap(
-        lambda As, Bs: _chunk2_scan_impl(As, Bs, r0s, r1s, C0s, c_pad)
-    )(Ast, Bst)
-
-
-_SCAN_CORES_BATCHED = {"knl": _knl_scan_batched,
-                       "chunk1": _chunk1_scan_batched,
-                       "chunk2": _chunk2_scan_batched}
-
-
 def _make_scan_batched_cores(donate: bool = False) -> dict:
-    """A fresh jitted set of the three batched scan cores (same
-    ``TRACE_COUNTS`` keys as the module-level set, so compile accounting is
-    backend-uniform regardless of which set ran). Module-level cores cache
-    compilations in a module-global jit cache for the life of the process;
-    a caller that owns a set from this factory (a serving bucket) is the
-    sole owner of its executables, so dropping the set really frees them.
+    """A jitted set of the three batched (vmapped) scan cores: one program
+    per (envelope, plan, batch) geometry, each with its own
+    ``TRACE_COUNTS`` key so the serving layer can assert "one compile per
+    geometry bucket" directly. The module-level set
+    (``_SCAN_CORES_BATCHED``) caches compilations for the life of the
+    process; a caller that owns a set from this factory (a serving bucket)
+    is the sole owner of its executables, so dropping the set really frees
+    them.
 
     ``donate=True`` donates the knl C-accumulator stack — the one scan core
     whose output aliases its ``C0s`` argument shape-for-shape, letting XLA
@@ -286,6 +270,9 @@ def _make_scan_batched_cores(donate: bool = False) -> dict:
         )(Ast, Bst)
 
     return {"knl": knl, "chunk1": chunk1, "chunk2": chunk2}
+
+
+_SCAN_CORES_BATCHED = _make_scan_batched_cores()
 
 
 # ---------------------------------------------------------------------------
@@ -338,15 +325,46 @@ def _c_strip_nbytes(strip_rows: int, c_pad: int, dtype) -> int:
     return (strip_rows + 1) * 4 + c_pad * (4 + itemsize)
 
 
+def _pipeline_events(plan: ChunkPlan) -> list:
+    """The Pallas pipeline's copy events in issue order, each ``(operand,
+    piece, copies)``: the operand is ``stationary``, ``streamed`` or
+    ``c_prev`` (slow->fast) or ``c_out`` (fast->slow), the piece it moves
+    is an A strip (``"a"``), a B chunk (``"b"``) or a C strip (``"c"``),
+    and one event moves ``copies`` such pieces.
+
+    knl/chunk1 (grid ``(1, n_ac, n_b)``): per strip, the stationary strip
+    and the fused C_prev block come in, a chunk is hand-DMA'd every grid
+    step, and the strip's C goes out. chunk2 swaps the roles, and maps all
+    ``n_ac`` C partials as one ``(n_ac, strip_rows, n)`` block at a constant
+    index: one whole-block fetch with the first chunk and one writeback at
+    the end, not ``n_ac`` per-strip events."""
+    n_ac, n_b = plan.n_ac, plan.n_b
+    if plan.algorithm in ("knl", "chunk1"):
+        strip = [("stationary", "a", 1), ("c_prev", "c", 1),
+                 *[("streamed", "b", 1)] * n_b, ("c_out", "c", 1)]
+        return strip * n_ac
+    if plan.algorithm == "chunk2":
+        events = []
+        for jb in range(n_b):
+            events.append(("stationary", "b", 1))
+            if jb == 0:
+                events.append(("c_prev", "c", n_ac))
+            events += [("streamed", "a", 1)] * n_ac
+        return events + [("c_out", "c", n_ac)]
+    raise ValueError(f"unknown algorithm {plan.algorithm!r}")
+
+
 def planned_stats_pallas(plan: ChunkPlan, slab_nbytes: int, a_stage_nbytes: int,
                          c_stage_nbytes: int) -> ChunkStats:
     """Replay the Pallas pipeline's per-copy event sequence from the plan.
 
-    The event model differs from :func:`planned_stats` in three structural
-    ways, all of them properties of the kernel rather than modeling choices:
+    The event model (:func:`_pipeline_events`) differs from
+    :func:`planned_stats` in three structural ways, all of them properties
+    of the kernel rather than modeling choices:
 
-      * staged pieces are **dense** (slab = ``chunk_rows x n`` floats, strip =
-        ``strip_rows x k_pad`` floats), not padded CSR triples;
+      * the dense backend's staged pieces are **dense** (slab = ``chunk_rows
+        x n`` floats, strip = ``strip_rows x k_pad`` floats), not padded CSR
+        triples;
       * the stationary operand is staged by the Pallas pipeline once per outer
         step, and the streamed operand is hand-DMA'd once per grid step — the
         double-buffer *overlaps* those copies with compute but their byte
@@ -354,36 +372,17 @@ def planned_stats_pallas(plan: ChunkPlan, slab_nbytes: int, a_stage_nbytes: int,
       * in the Chunk2 order the per-strip C partials persist in the VMEM
         output block across outer steps, so the ``(n_b - 1)`` per-strip
         out+in partial bounces of the loop/scan model collapse into **one**
-        whole-block ``C_prev`` fetch and **one** final writeback: the kernel
-        maps all ``n_ac`` partials as a single ``(n_ac, strip_rows, n)``
-        block at a constant index, so the pipeline stages it as one copy
-        event of ``n_ac * c_stage_nbytes``, not ``n_ac`` per-strip events
-        (the traffic-equality audit holds this model to the traced jaxpr
-        event-for-event).
+        whole-block ``C_prev`` fetch and **one** final writeback of
+        ``n_ac * c_stage_nbytes`` (the traffic-equality audit holds this
+        model to the traced jaxpr event-for-event).
     """
+    size = {"a": a_stage_nbytes, "b": slab_nbytes, "c": c_stage_nbytes}
     stats = ChunkStats(plan.algorithm, plan.n_ac, plan.n_b)
-    if plan.algorithm in ("knl", "chunk1"):
-        for _ in range(plan.n_ac):           # knl is the 1-strip special case
-            stats.add_in(a_stage_nbytes)     # stationary strip -> VMEM
-            stats.add_in(c_stage_nbytes)     # fused C_prev block
-            for _ in range(plan.n_b):
-                stats.add_in(slab_nbytes)    # double-buffered slab DMA
-                stats.kernel_calls += 1
-            stats.add_out(c_stage_nbytes)    # strip result writeback
-        return stats
-    if plan.algorithm == "chunk2":
-        for jb in range(plan.n_b):
-            stats.add_in(slab_nbytes)        # stationary chunk -> VMEM
-            if jb == 0:
-                # C_prev: one whole-block fetch (all n_ac partials at once)
-                stats.add_in(plan.n_ac * c_stage_nbytes)
-            for _ in range(plan.n_ac):
-                stats.add_in(a_stage_nbytes)       # streamed strip DMA
-                stats.kernel_calls += 1
-        # single whole-block final writeback
-        stats.add_out(plan.n_ac * c_stage_nbytes)
-        return stats
-    raise ValueError(f"unknown algorithm {plan.algorithm!r}")
+    for operand, piece, copies in _pipeline_events(plan):
+        add = stats.add_out if operand == "c_out" else stats.add_in
+        add(copies * size[piece])
+    stats.kernel_calls = plan.n_ac * plan.n_b
+    return stats
 
 
 def _pallas_stage_nbytes(strip_rows: int, k: int, chunk_rows: int,
@@ -395,62 +394,96 @@ def _pallas_stage_nbytes(strip_rows: int, k: int, chunk_rows: int,
 
 
 # ---------------------------------------------------------------------------
-# executors (drop-in signatures of chunk_knl / chunk_gpu1 / chunk_gpu2)
+# staging: one instance's strips and chunks, padded and stacked
 # ---------------------------------------------------------------------------
 
 
-def _stage(A: CSR | None, B: CSR, plan: ChunkPlan, sp) -> tuple:
-    """On the device: the strips of ``A`` (none for ``None``) and the chunks
-    of ``B``, each as a list and as a stack, and the plan's B-row ranges."""
-    strips = None if A is None else a_strips(A, plan.p_ac)
-    chunks = b_chunks(B, plan.p_b)
-    r0s, r1s = sp.put(plan.b_ranges())
-    As = None if strips is None else csr_stack(strips)
-    return strips, chunks, As, csr_stack(chunks), r0s, r1s
+class _Staged(NamedTuple):
+    """One launch's staged operands: A whole or its strips stacked, B's
+    chunks stacked, and the bytes of one staged strip (or A) and chunk."""
+
+    A: CSR
+    B: CSR
+    a_nbytes: int
+    b_nbytes: int
 
 
-def chunk_knl_scan(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int):
+def _stage(A: CSR, B: CSR, plan: ChunkPlan,
+           envelope: GeometryEnvelope | None = None, *,
+           strips: bool = True) -> _Staged:
+    """On the device: the strips of ``A`` (with ``strips=False``, A whole)
+    and the chunks of ``B``, each stacked. Without an envelope every piece
+    is padded to this instance's largest; with one, to the envelope's caps,
+    and a whole A to its ``a_nnz_cap``/``a_max_row_nnz``. The lists of
+    pieces die here: only the stacks reach the launch."""
+    if strips:
+        pieces = a_strips(A, plan.p_ac, envelope=envelope)
+        A = csr_stack(pieces)
+        a_nbytes = pieces[0].nbytes()
+    else:
+        if envelope is not None:
+            A = csr_pad_to(A, nnz_cap=envelope.a_nnz_cap,
+                           max_row_nnz=envelope.a_max_row_nnz)
+        a_nbytes = A.nbytes()
+    pieces = b_chunks(B, plan.p_b, envelope=envelope)
+    return _Staged(A, csr_stack(pieces), a_nbytes, pieces[0].nbytes())
+
+
+def _stage_batch(As, Bs, plan: ChunkPlan, envelope: GeometryEnvelope | None,
+                 *, strips: bool = True) -> _Staged:
+    """Every instance staged by :func:`_stage` and the stacks stacked once
+    more ([batch, ...] leading axis)."""
+    staged = [_stage(A, B, plan, envelope, strips=strips)
+              for A, B in zip(As, Bs)]
+    return staged[0]._replace(A=csr_stack([s.A for s in staged]),
+                              B=csr_stack([s.B for s in staged]))
+
+
+# ---------------------------------------------------------------------------
+# scan backend: one lax.scan program per algorithm
+# ---------------------------------------------------------------------------
+
+
+def _scan_launch(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int,
+                 envelope: GeometryEnvelope | None = None,
+                 put=jax.device_put) -> TraceTarget:
+    """One scan launch staged: the core with ``c_pad`` bound, its operands,
+    and its copy events as ``meta["stats"]``. ``put`` puts the plan's B-row
+    ranges on the device (the stage span's ``put`` counts them). The
+    backend's ``audit_trace``."""
+    st = _stage(A, B, plan, envelope, strips=plan.algorithm != "knl")
+    rows = st.A.n_rows
+    lead = (plan.n_ac,) if plan.algorithm == "chunk2" else ()
+    C0 = _empty_c(lead, rows, B.n_cols, c_pad, A.dtype)
+    core = {"knl": _knl_scan, "chunk1": _chunk1_scan,
+            "chunk2": _chunk2_scan}[plan.algorithm]
+    stats = planned_stats(plan, st.b_nbytes, st.a_nbytes,
+                          _c_strip_nbytes(rows, c_pad, A.dtype))
+    return TraceTarget(fn=partial(core, c_pad=c_pad),
+                       args=(st.A, st.B, *put(plan.b_ranges()), C0),
+                       meta={"stats": stats})
+
+
+def _scan_run(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int):
+    """The scan executor of every algorithm: the plan's algorithm picks the
+    core. knl's C stays whole on the device; the strip orders assemble."""
     with span("stage") as sp:
-        _, chunks, _, Bs, r0s, r1s = _stage(None, B, plan, sp)
-        C0 = _empty_c(A.n_rows, B.n_cols, c_pad, A.dtype)
-    stats = planned_stats(plan, chunks[0].nbytes(), 0, 0)
-    del chunks      # the stack holds them: no second copy while the core runs
+        launch = _scan_launch(A, B, plan, c_pad, put=sp.put)
+    steps = plan.n_ac * plan.n_b
     with span("launch") as sp:
-        sp.note(key=key_layout(A.n_rows, B.n_cols), tables=plan.n_b,
-                steps=plan.n_b)
-        C = _knl_scan(A, Bs, r0s, r1s, C0, c_pad)
-    return C, stats
+        # chunk1 has the strip loop outer: each chunk's tables are built
+        # anew for every strip
+        sp.note(key=key_layout(launch.args[0].n_rows, B.n_cols),
+                tables=steps if plan.algorithm == "chunk1" else plan.n_b,
+                steps=steps)
+        C = launch.fn(*launch.args)
+    stats = launch.meta["stats"]
+    if plan.algorithm == "knl":
+        return C, stats
+    return _assemble(C, plan.p_ac, B.n_cols), stats
 
 
-def chunk_gpu1_scan(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int):
-    with span("stage") as sp:
-        strips, chunks, As, Bs, r0s, r1s = _stage(A, B, plan, sp)
-        strip_rows = strips[0].n_rows
-        C0 = _empty_c(strip_rows, B.n_cols, c_pad, A.dtype)
-    stats = planned_stats(plan, chunks[0].nbytes(), strips[0].nbytes(),
-                          _c_strip_nbytes(strip_rows, c_pad, A.dtype))
-    del strips, chunks  # the stacks hold them: no second copy while the core runs
-    with span("launch") as sp:
-        # A strip outer: each chunk's table is built anew for every strip
-        steps = plan.n_ac * plan.n_b
-        sp.note(key=key_layout(strip_rows, B.n_cols), tables=steps, steps=steps)
-        Cs = _chunk1_scan(As, Bs, r0s, r1s, C0, c_pad)
-    return _assemble(Cs, plan.p_ac, B.n_cols), stats
-
-
-def chunk_gpu2_scan(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int):
-    with span("stage") as sp:
-        strips, chunks, As, Bs, r0s, r1s = _stage(A, B, plan, sp)
-        strip_rows = strips[0].n_rows
-        C0s = _empty_c_stack(plan.n_ac, strip_rows, B.n_cols, c_pad, A.dtype)
-    stats = planned_stats(plan, chunks[0].nbytes(), strips[0].nbytes(),
-                          _c_strip_nbytes(strip_rows, c_pad, A.dtype))
-    del strips, chunks  # the stacks hold them: no second copy while the core runs
-    with span("launch") as sp:
-        sp.note(key=key_layout(strip_rows, B.n_cols), tables=plan.n_b,
-                steps=plan.n_ac * plan.n_b)
-        Cs = _chunk2_scan(As, Bs, r0s, r1s, C0s, c_pad)
-    return _assemble(Cs, plan.p_ac, B.n_cols), stats
+chunk_knl_scan = chunk_gpu1_scan = chunk_gpu2_scan = _scan_run
 
 
 # ---------------------------------------------------------------------------
@@ -472,7 +505,7 @@ def _dense_stack(stacked: CSR, levels: int = 1) -> jax.Array:
     return fn(stacked.indptr, stacked.indices, stacked.data)
 
 
-def _make_pallas_core(key: str, order: str, *, batched: bool, strips: bool):
+def _make_pallas_core(key: str, alg: str, *, batched: bool):
     """One jitted staging-and-launch core; the six variants differ only in
     the streaming order, the trace-counter key, and whether A arrives as a
     plain CSR (knl), a strip stack, or a per-instance (doubly) stacked batch.
@@ -482,6 +515,7 @@ def _make_pallas_core(key: str, order: str, *, batched: bool, strips: bool):
     with their own TRACE_COUNTS keys so the serving layer's compile
     accounting stays exact.
     """
+    strips = alg != "knl"
     a_levels = (1 if strips else 0) + (1 if batched else 0)
 
     @jax.jit
@@ -494,7 +528,7 @@ def _make_pallas_core(key: str, order: str, *, batched: bool, strips: bool):
         if not batched:              # width-1 batch axis
             a, slabs = a[None], slabs[None]
         c0 = jnp.zeros(a.shape[:3] + (Bst.n_cols,), jnp.float32)
-        out = ranged_spgemm_stream(a, slabs, c0, r0s, order=order)
+        out = ranged_spgemm_stream(a, slabs, c0, r0s, order=_ORDERS[alg])
         if not batched:
             out = out[0]
         if not strips:
@@ -504,38 +538,17 @@ def _make_pallas_core(key: str, order: str, *, batched: bool, strips: bool):
     return core
 
 
-_knl_pallas = _make_pallas_core("knl_pallas", "chunk1",
-                                batched=False, strips=False)
-_chunk1_pallas = _make_pallas_core("chunk1_pallas", "chunk1",
-                                   batched=False, strips=True)
-_chunk2_pallas = _make_pallas_core("chunk2_pallas", "chunk2",
-                                   batched=False, strips=True)
-_knl_pallas_batched = _make_pallas_core("knl_pallas_batched", "chunk1",
-                                        batched=True, strips=False)
-_chunk1_pallas_batched = _make_pallas_core("chunk1_pallas_batched", "chunk1",
-                                           batched=True, strips=True)
-_chunk2_pallas_batched = _make_pallas_core("chunk2_pallas_batched", "chunk2",
-                                           batched=True, strips=True)
-
-_PALLAS_CORES_BATCHED = {"knl": _knl_pallas_batched,
-                         "chunk1": _chunk1_pallas_batched,
-                         "chunk2": _chunk2_pallas_batched}
-
-
 def _make_pallas_batched_cores(donate: bool = False) -> dict:
-    """Fresh jitted batched Pallas cores (see ``_make_scan_batched_cores``
-    for why a caller-owned set exists). The dense accumulator is allocated
-    inside the jit and the staged CSR operands never alias the dense
-    outputs, so there is nothing donation could usefully alias here."""
+    """Jitted batched Pallas cores (see ``_make_scan_batched_cores`` for why
+    a caller-owned set exists). The dense accumulator is allocated inside
+    the jit and the staged CSR operands never alias the dense outputs, so
+    there is nothing donation could usefully alias here."""
     del donate
-    return {
-        "knl": _make_pallas_core("knl_pallas_batched", "chunk1",
-                                 batched=True, strips=False),
-        "chunk1": _make_pallas_core("chunk1_pallas_batched", "chunk1",
-                                    batched=True, strips=True),
-        "chunk2": _make_pallas_core("chunk2_pallas_batched", "chunk2",
-                                    batched=True, strips=True),
-    }
+    return _cores(_make_pallas_core, "{alg}_pallas_batched", batched=True)
+
+
+_PALLAS_CORES = _cores(_make_pallas_core, "{alg}_pallas", batched=False)
+_PALLAS_CORES_BATCHED = _make_pallas_batched_cores()
 
 
 def _pallas_assemble(dense, p_ac: tuple, dtype) -> CSR:
@@ -560,37 +573,33 @@ def _pallas_assemble(dense, p_ac: tuple, dtype) -> CSR:
         return C
 
 
-def chunk_knl_pallas(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int):
+def _pallas_launch(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int,
+                   envelope: GeometryEnvelope | None = None,
+                   put=jax.device_put) -> TraceTarget:
+    """One Pallas launch staged (see :func:`_scan_launch`); the chunk
+    starts are its scalar operand. The backend's ``audit_trace``."""
     del c_pad  # capacity is implicit in the dense accumulator
+    st = _stage(A, B, plan, envelope, strips=plan.algorithm != "knl")
+    r0s, _ = put(plan.b_ranges())
+    stats = planned_stats_pallas(plan, *_pallas_stage_nbytes(
+        st.A.n_rows, A.n_cols, st.B.n_rows, B.n_cols))
+    return TraceTarget(fn=_PALLAS_CORES[plan.algorithm],
+                       args=(st.A, st.B, r0s),
+                       meta={"stats": stats, "scalar_args": (r0s,)})
+
+
+def _pallas_run(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int):
+    """The Pallas executor of every algorithm (see :func:`_scan_run`)."""
     with span("stage") as sp:
-        _, _, _, Bs, r0s, _ = _stage(None, B, plan, sp)
+        launch = _pallas_launch(A, B, plan, c_pad, put=sp.put)
     with span("launch"):
-        dense = _knl_pallas(A, Bs, r0s)
-    stats = planned_stats_pallas(
-        plan, *_pallas_stage_nbytes(A.n_rows, A.n_cols, Bs.n_rows, B.n_cols))
-    return _pallas_assemble(dense, (0, A.n_rows), np.dtype(A.dtype)), stats
+        dense = launch.fn(*launch.args)
+    p_ac = (0, A.n_rows) if plan.algorithm == "knl" else plan.p_ac
+    return (_pallas_assemble(dense, p_ac, np.dtype(A.dtype)),
+            launch.meta["stats"])
 
 
-def chunk_gpu1_pallas(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int):
-    del c_pad
-    with span("stage") as sp:
-        _, _, As, Bs, r0s, _ = _stage(A, B, plan, sp)
-    with span("launch"):
-        dense = _chunk1_pallas(As, Bs, r0s)
-    stats = planned_stats_pallas(
-        plan, *_pallas_stage_nbytes(As.n_rows, A.n_cols, Bs.n_rows, B.n_cols))
-    return _pallas_assemble(dense, plan.p_ac, np.dtype(A.dtype)), stats
-
-
-def chunk_gpu2_pallas(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int):
-    del c_pad
-    with span("stage") as sp:
-        _, _, As, Bs, r0s, _ = _stage(A, B, plan, sp)
-    with span("launch"):
-        dense = _chunk2_pallas(As, Bs, r0s)
-    stats = planned_stats_pallas(
-        plan, *_pallas_stage_nbytes(As.n_rows, A.n_cols, Bs.n_rows, B.n_cols))
-    return _pallas_assemble(dense, plan.p_ac, np.dtype(A.dtype)), stats
+chunk_knl_pallas = chunk_gpu1_pallas = chunk_gpu2_pallas = _pallas_run
 
 
 # ---------------------------------------------------------------------------
@@ -598,20 +607,7 @@ def chunk_gpu2_pallas(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int):
 # ---------------------------------------------------------------------------
 
 
-def _sparse_c0_stack(batch: int, n_ac: int, strip_rows: int, n_cols: int,
-                     c_cap: int, dtype) -> CSR:
-    """Empty stacked C_prev strips ([batch, n_ac] leading axes) at the CSR
-    scratch capacity ``c_cap`` (the symbolic phase's strip output bound)."""
-    return CSR(
-        indptr=jnp.zeros((batch, n_ac, strip_rows + 1), jnp.int32),
-        indices=jnp.zeros((batch, n_ac, c_cap), jnp.int32),
-        data=jnp.zeros((batch, n_ac, c_cap), dtype),
-        shape=(strip_rows, n_cols),
-        max_row_nnz=c_cap,
-    )
-
-
-def _make_sparse_core(key: str, order: str, donate: bool = False):
+def _make_sparse_core(key: str, alg: str, donate: bool = False):
     """One jitted launch core for the sparse-output kernel; the six variants
     differ only in the streaming order and the trace-counter key (all staging
     is host-side, so batched cores share the same body — the batch rides the
@@ -624,26 +620,12 @@ def _make_sparse_core(key: str, order: str, donate: bool = False):
     def core(Ast: CSR, Bst: CSR, C0st: CSR, r0s, r1s):
         TRACE_COUNTS[key] += 1
         return sparse_accum_spgemm_stream(Ast, Bst, C0st, r0s, r1s,
-                                          order=order)
+                                          order=_ORDERS[alg])
 
     return core
 
 
-_knl_sparse = _make_sparse_core("knl_sparse", "chunk1")
-_chunk1_sparse = _make_sparse_core("chunk1_sparse", "chunk1")
-_chunk2_sparse = _make_sparse_core("chunk2_sparse", "chunk2")
-_knl_sparse_batched = _make_sparse_core("knl_sparse_batched", "chunk1")
-_chunk1_sparse_batched = _make_sparse_core("chunk1_sparse_batched", "chunk1")
-_chunk2_sparse_batched = _make_sparse_core("chunk2_sparse_batched", "chunk2")
-
-_SPARSE_CORES = {"knl": _knl_sparse, "chunk1": _chunk1_sparse,
-                 "chunk2": _chunk2_sparse}
-_SPARSE_CORES_BATCHED = {"knl": _knl_sparse_batched,
-                         "chunk1": _chunk1_sparse_batched,
-                         "chunk2": _chunk2_sparse_batched}
-
-
-def _make_hash_core(key: str, order: str, donate: bool = False):
+def _make_hash_core(key: str, alg: str, donate: bool = False):
     """Launch core for the hash-probe kernel; ``table_size`` (the per-row
     hash-table slot count, from the envelope's ``c_max_row_nnz``) is a static
     jit argument, so two geometries differing only in the densest-output-row
@@ -656,58 +638,88 @@ def _make_hash_core(key: str, order: str, donate: bool = False):
     def core(Ast: CSR, Bst: CSR, C0st: CSR, r0s, r1s, table_size: int):
         TRACE_COUNTS[key] += 1
         return hash_accum_spgemm_stream(Ast, Bst, C0st, r0s, r1s,
-                                        order=order, table_size=table_size)
+                                        order=_ORDERS[alg],
+                                        table_size=table_size)
 
     return core
 
 
-_knl_hash = _make_hash_core("knl_hash", "chunk1")
-_chunk1_hash = _make_hash_core("chunk1_hash", "chunk1")
-_chunk2_hash = _make_hash_core("chunk2_hash", "chunk2")
-_knl_hash_batched = _make_hash_core("knl_hash_batched", "chunk1")
-_chunk1_hash_batched = _make_hash_core("chunk1_hash_batched", "chunk1")
-_chunk2_hash_batched = _make_hash_core("chunk2_hash_batched", "chunk2")
-
-_HASH_CORES = {"knl": _knl_hash, "chunk1": _chunk1_hash,
-               "chunk2": _chunk2_hash}
-_HASH_CORES_BATCHED = {"knl": _knl_hash_batched,
-                       "chunk1": _chunk1_hash_batched,
-                       "chunk2": _chunk2_hash_batched}
-
-_CSR_ACCUM_ORDERS = {"knl": "chunk1", "chunk1": "chunk1", "chunk2": "chunk2"}
-
-
 def _make_sparse_batched_cores(donate: bool = False) -> dict:
-    """Fresh jitted batched ESC cores (caller-owned executables; see
+    """Jitted batched ESC cores (caller-owned executables; see
     ``_make_scan_batched_cores``)."""
-    return {alg: _make_sparse_core(f"{alg}_sparse_batched", order,
-                                   donate=donate)
-            for alg, order in _CSR_ACCUM_ORDERS.items()}
+    return _cores(_make_sparse_core, "{alg}_sparse_batched", donate=donate)
 
 
 def _make_hash_batched_cores(donate: bool = False) -> dict:
-    """Fresh jitted batched hash-probe cores (caller-owned executables)."""
-    return {alg: _make_hash_core(f"{alg}_hash_batched", order, donate=donate)
-            for alg, order in _CSR_ACCUM_ORDERS.items()}
+    """Jitted batched hash-probe cores (caller-owned executables)."""
+    return _cores(_make_hash_core, "{alg}_hash_batched", donate=donate)
+
+
+_SPARSE_CORES = _cores(_make_sparse_core, "{alg}_sparse")
+_SPARSE_CORES_BATCHED = _make_sparse_batched_cores()
+_HASH_CORES = _cores(_make_hash_core, "{alg}_hash")
+_HASH_CORES_BATCHED = _make_hash_batched_cores()
+
+
+def _envelope_table(envelope: GeometryEnvelope, n_cols: int) -> int:
+    """The hash tables' slot count. It is a compile key, so it derives from
+    the envelope alone, never from the per-call instances. A zero
+    ``c_max_row_nnz`` is exact (empty output, 1-slot tables) when the
+    symbolic phase ran — witnessed by ``c_nnz_cap``, whose rounding floor
+    makes it nonzero whenever computed; only a legacy both-zero envelope
+    falls back to the always-valid ``n_cols`` bound."""
+    return hash_table_slots(
+        envelope.c_max_row_nnz if envelope.c_nnz_cap else n_cols)
+
+
+def _csr_accum_launch(cores: dict, As, Bs, plan: ChunkPlan, c_pad: int,
+                      table: int | None = None,
+                      envelope: GeometryEnvelope | None = None,
+                      put=jax.device_put) -> TraceTarget:
+    """One launch of a CSR-scratch accumulator (ESC, or hash with a
+    ``table`` size) staged for the instances ``As``/``Bs``: every
+    instance's strips and chunks (knl is the 1-strip special case of the
+    chunk1 order) stacked over the batch, the empty C scratch at ``c_pad``,
+    and the B-row ranges. An unbatched executor is a width-1 batch.
+
+    The per-copy event model is structurally the Pallas pipeline's
+    (:func:`planned_stats_pallas`) — only the staged byte sizes differ:
+    padded **CSR** footprints instead of dense slabs."""
+    st = _stage_batch(As, Bs, plan, envelope)
+    rows, dtype = st.A.n_rows, As[0].dtype
+    C0 = _empty_c((len(As), plan.n_ac), rows, Bs[0].n_cols, c_pad, dtype)
+    r0s, r1s = put(plan.b_ranges())
+    stats = planned_stats_pallas(plan, st.b_nbytes, st.a_nbytes,
+                                 _c_strip_nbytes(rows, c_pad, dtype))
+    fn, meta = cores[plan.algorithm], {"stats": stats,
+                                       "scalar_args": (r0s, r1s)}
+    if table is not None:
+        fn, meta["table_size"] = partial(fn, table_size=table), table
+    return TraceTarget(fn=fn, args=(st.A, st.B, C0, r0s, r1s), meta=meta)
+
+
+def _audit_sparse(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int,
+                  envelope: GeometryEnvelope) -> TraceTarget:
+    return _csr_accum_launch(_SPARSE_CORES, [A], [B], plan, c_pad,
+                             envelope=envelope)
+
+
+def _audit_hash(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int,
+                envelope: GeometryEnvelope) -> TraceTarget:
+    return _csr_accum_launch(_HASH_CORES, [A], [B], plan, c_pad,
+                             _envelope_table(envelope, B.n_cols), envelope)
 
 
 def _sparse_run(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int, backend: str,
                 caps=None):
     """Shared body of the unbatched sparse-output executors (ESC and hash):
-    stage CSR strips and chunks (knl is the 1-strip special case of the
-    chunk1 order), validate the realized output structure against the
-    capacities, launch, and assemble the accumulated strip CSRs.
+    validate the realized output structure against the capacities, stage a
+    width-1 launch, run it, and assemble the accumulated strip CSRs.
 
     ``caps`` is the symbolic phase's :class:`StripOutputCaps` when the caller
     (the ``chunked_spgemm`` dispatch) already ran the expansion — the
     symbolic module's amortization contract; recomputed here only for direct
     executor calls.
-
-    The per-copy event model is structurally the Pallas pipeline's
-    (:func:`planned_stats_pallas`: stationary operand staged once per outer
-    step, streamed triple DMA'd per grid step, C persists in VMEM with one
-    final writeback) — only the staged byte sizes differ: padded **CSR**
-    footprints instead of dense slabs.
     """
     if caps is None:
         caps = strip_output_caps(A, B, plan.p_ac)
@@ -715,29 +727,19 @@ def _sparse_run(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int, backend: str,
              else None)
     check_output_caps(caps.strip_nnz, caps.c_max_row_nnz, c_pad, table,
                       backend=backend, a_shape=A.shape, b_shape=B.shape)
+    cores = _HASH_CORES if backend == "hash" else _SPARSE_CORES
     with span("stage") as sp:
-        strips, chunks, As, Bs, r0s, r1s = _stage(A, B, plan, sp)
-        Ast, Bst = csr_stack([As]), csr_stack([Bs])
-        strip_rows = strips[0].n_rows
-        C0 = _sparse_c0_stack(1, plan.n_ac, strip_rows, B.n_cols, c_pad,
-                              A.dtype)
+        launch = _csr_accum_launch(cores, [A], [B], plan, c_pad, table,
+                                   put=sp.put)
     with span("launch"):
-        if backend == "hash":
-            ip, ix, d = _HASH_CORES[plan.algorithm](
-                Ast, Bst, C0, r0s, r1s, table_size=table)
-        else:
-            ip, ix, d = _SPARSE_CORES[plan.algorithm](Ast, Bst, C0, r0s, r1s)
-    stats = planned_stats_pallas(
-        plan, chunks[0].nbytes(), strips[0].nbytes(),
-        _c_strip_nbytes(strip_rows, c_pad, A.dtype))
-    out = CSR(ip[0], ix[0], d[0], (strip_rows, B.n_cols), c_pad)
-    return _assemble(out, plan.p_ac, B.n_cols), stats
+        ip, ix, d = launch.fn(*launch.args)
+    out = CSR(ip[0], ix[0], d[0], (launch.args[0].n_rows, B.n_cols), c_pad)
+    return _assemble(out, plan.p_ac, B.n_cols), launch.meta["stats"]
 
 
 def chunk_sparse(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int, caps=None):
     """ESC sparse-output executor for any plan algorithm (``_sparse_run``
-    dispatches the core on ``plan.algorithm``, so unlike the scan/pallas
-    backends there is no per-algorithm staging difference to name)."""
+    dispatches the core on ``plan.algorithm``)."""
     return _sparse_run(A, B, plan, c_pad, "sparse", caps=caps)
 
 
@@ -751,7 +753,7 @@ def chunk_hash(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int, caps=None):
 # ---------------------------------------------------------------------------
 
 
-def _make_masked_hash_core(key: str, order: str):
+def _make_masked_hash_core(key: str, alg: str):
     """Launch core for the mask-fused hash kernel (``_make_hash_core`` with
     the mask's stacked structure as a fourth staged operand and the
     probe-only masked merge plugged in). Own ``TRACE_COUNTS`` keys: a masked
@@ -763,17 +765,13 @@ def _make_masked_hash_core(key: str, order: str):
              table_size: int):
         TRACE_COUNTS[key] += 1
         return hash_masked_accum_spgemm_stream(
-            Ast, Bst, C0st, Mst, r0s, r1s, order=order,
+            Ast, Bst, C0st, Mst, r0s, r1s, order=_ORDERS[alg],
             table_size=table_size)
 
     return core
 
 
-_HASH_MASKED_CORES = {
-    alg: _make_masked_hash_core(f"{alg}_hash_masked", order)
-    for alg, order in {"knl": "chunk1", "chunk1": "chunk1",
-                       "chunk2": "chunk2"}.items()
-}
+_HASH_MASKED_CORES = _cores(_make_masked_hash_core, "{alg}_hash_masked")
 
 
 def chunk_hash_masked(A: CSR, B: CSR, mask: CSR, plan: ChunkPlan,
@@ -800,23 +798,18 @@ def chunk_hash_masked(A: CSR, B: CSR, mask: CSR, plan: ChunkPlan,
     check_output_caps(caps.strip_nnz, caps.c_max_row_nnz, c_pad, table,
                       backend="hash", a_shape=A.shape, b_shape=B.shape)
     with span("stage") as sp:
-        strips, chunks, As, Bs, r0s, r1s = _stage(A, B, plan, sp)
-        mstrips = a_strips(mask, plan.p_ac)
-        Ast, Bst = csr_stack([As]), csr_stack([Bs])
-        Mst = csr_stack([csr_stack(mstrips)])
-        strip_rows = strips[0].n_rows
-        C0 = _sparse_c0_stack(1, plan.n_ac, strip_rows, B.n_cols, c_pad,
-                              A.dtype)
+        launch = _csr_accum_launch(_HASH_MASKED_CORES, [A], [B], plan, c_pad,
+                                   table, put=sp.put)
+        Mst = csr_stack([csr_stack(a_strips(mask, plan.p_ac))])
+    Ast, Bst, C0, r0s, r1s = launch.args
     with span("launch"):
-        ip, ix, d = _HASH_MASKED_CORES[plan.algorithm](
-            Ast, Bst, C0, Mst, r0s, r1s, table_size=table)
-    stats = planned_stats_pallas(
-        plan, chunks[0].nbytes(), strips[0].nbytes(),
-        _c_strip_nbytes(strip_rows, c_pad, A.dtype))
+        ip, ix, d = launch.fn(Ast, Bst, C0, Mst, r0s, r1s)
+    stats = launch.meta["stats"]
     # the mask's structure operands (indptr + indices, no data) stage with
     # the fused C_prev block's index maps: once per strip in the chunk1
     # orders, one whole block in chunk2
-    m_struct = (strip_rows + 1) * 4 + mstrips[0].indices.shape[-1] * 4
+    strip_rows = Ast.n_rows
+    m_struct = (strip_rows + 1) * 4 + Mst.indices.shape[-1] * 4
     if plan.algorithm == "chunk2":
         stats.add_in(plan.n_ac * m_struct)
     else:
@@ -833,7 +826,7 @@ def chunk_hash_masked(A: CSR, B: CSR, mask: CSR, plan: ChunkPlan,
 _BSR_DEFAULT_BLOCK = 8
 
 
-def _make_bsr_core(key: str, *, batched: bool):
+def _make_bsr_core(key: str, alg: str, *, batched: bool):
     """One jitted launch core for the blocked kernel. The whole
     :class:`GeometryEnvelope` is the *static* jit key: the kernel geometry
     (``nc_pad``, ``u_max``, ``bs``) comes from its ``bsr_caps``, and keying
@@ -844,6 +837,7 @@ def _make_bsr_core(key: str, *, batched: bool):
     Batched cores take width-stacked operands (leading axis) and unroll the
     width inside the jit, so the serving layer's width-ladder compile
     accounting sees one (re)trace per (envelope, width)."""
+    del alg  # one kernel for every order: _bsr_execute orders the pairs
 
     @partial(jax.jit, static_argnames=("envelope",))
     def core(a_blocks, b_blocks, a_slots, b_slots,
@@ -866,44 +860,65 @@ def _make_bsr_core(key: str, *, batched: bool):
     return core
 
 
-_BSR_CORES = {alg: _make_bsr_core(f"{alg}_bsr", batched=False)
-              for alg in ("knl", "chunk1", "chunk2")}
-_BSR_CORES_BATCHED = {alg: _make_bsr_core(f"{alg}_bsr_batched", batched=True)
-                      for alg in ("knl", "chunk1", "chunk2")}
-
-
 def _make_bsr_batched_cores(donate: bool = False) -> dict:
-    """Fresh jitted batched BSR cores (caller-owned executables). Staging is
-    a host loop over (strip, chunk) pairs, so there is no device scratch to
+    """Jitted batched BSR cores (caller-owned executables). Staging is a
+    host loop over (strip, chunk) pairs, so there is no device scratch to
     donate."""
     del donate
-    return {alg: _make_bsr_core(f"{alg}_bsr_batched", batched=True)
-            for alg in ("knl", "chunk1", "chunk2")}
+    return _cores(_make_bsr_core, "{alg}_bsr_batched", batched=True)
+
+
+_BSR_CORES = _cores(_make_bsr_core, "{alg}_bsr", batched=False)
+_BSR_CORES_BATCHED = _make_bsr_batched_cores()
+
+
+def _bsr_padded(envelope: GeometryEnvelope, k: int, n: int) -> tuple:
+    """(k, n, strip rows) rounded up to the envelope's block edge."""
+    bs = envelope.bsr_caps[0]
+    return tuple(-(-x // bs) * bs for x in (k, n, envelope.strip_rows))
+
+
+def _bsr_pair(Ad: np.ndarray, Bd: np.ndarray, strip: tuple, chunk: tuple,
+              envelope: GeometryEnvelope) -> tuple:
+    """One (strip, chunk) pair of dense ``Ad`` and ``Bd`` cut as BSR at the
+    envelope's block caps: the strip's rows of A with the columns outside
+    the chunk zeroed, at full contraction width, and the chunk's rows of B
+    at full output width — so summing pair products over chunks is exactly
+    the strip product. The pair's block symbolic runs at the envelope's
+    ``nc``/``u`` floors, which makes every pair one kernel geometry (and
+    fails loudly if the envelope does not dominate an instance). Returns the
+    kernel's operands (blocks with their zero sentinel, then the slot
+    tables) and the pair's symbolic result."""
+    bs, nbl_a_cap, nbl_b_cap, nc_cap, u_cap = envelope.bsr_caps
+    (s, e), (r0, r1) = strip, chunk
+    n = Bd.shape[1]
+    kpad, npad, srpad = _bsr_padded(envelope, *Bd.shape)
+    Am = np.zeros((srpad, kpad), np.float32)
+    Am[: e - s, r0:r1] = Ad[s:e, r0:r1]
+    Bm = np.zeros((kpad, npad), np.float32)
+    Bm[r0:r1, :n] = Bd[r0:r1, :]
+    Ab = bsr_from_dense(Am, bs, pad_to=nbl_a_cap)
+    Bb = bsr_from_dense(Bm, bs, pad_to=nbl_b_cap)
+    meta = bsr_spgemm_symbolic(Ab, Bb, nc_pad=nc_cap, u_max=u_cap)
+    return (bsr_blocks_with_sentinel(Ab), bsr_blocks_with_sentinel(Bb),
+            jnp.asarray(meta.a_slots), jnp.asarray(meta.b_slots)), meta
 
 
 def _bsr_execute(As, Bs, plan: ChunkPlan, envelope: GeometryEnvelope, *,
                  batched: bool, cores: dict | None = None):
-    """Shared body of the BSR executors: stage every (strip, chunk) pair as
-    BSR at the envelope's block caps, launch the blocked kernel per pair
-    (Chunk2 streams strips under a stationary chunk, the other orders stream
-    chunks under a stationary strip), and accumulate the per-pair outputs
-    into per-strip dense C.
+    """Shared body of the BSR executors: cut every (strip, chunk) pair with
+    :func:`_bsr_pair`, launch the blocked kernel per pair (Chunk2 streams
+    strips under a stationary chunk, the other orders stream chunks under a
+    stationary strip), and accumulate the per-pair outputs into per-strip
+    dense C.
 
-    Staging is host-side (like the symbolic phase): the pair's A piece is
-    the strip's rows with columns outside the chunk zeroed, at full
-    contraction width, and the B piece is the chunk's rows at full output
-    width — so summing pair products over chunks is exactly the strip
-    product. The per-pair block symbolic runs at the envelope's ``nc``/``u``
-    floors, which makes every pair one kernel geometry (and fails loudly if
-    the envelope does not dominate an instance). Accumulation and staging
+    Staging is host-side (like the symbolic phase). Accumulation and staging
     are f32, so comparisons against the loop oracle are allclose on values,
     like the Pallas dense-slab backend."""
-    bs, nbl_a_cap, nbl_b_cap, nc_cap, u_cap = envelope.bsr_caps
+    bs, nbl_a_cap, nbl_b_cap, nc_cap, _ = envelope.bsr_caps
     width = len(As)
     k, n = Bs[0].shape
-    kpad = -(-k // bs) * bs
-    npad = -(-n // bs) * bs
-    srpad = -(-envelope.strip_rows // bs) * bs
+    kpad, npad, srpad = _bsr_padded(envelope, k, n)
     mbs, nbp = srpad // bs, npad // bs
     np_dtype = np.dtype(As[0].dtype)
     Ads = [np.asarray(csr_to_dense(A), np.float32) for A in As]
@@ -920,30 +935,15 @@ def _bsr_execute(As, Bs, plan: ChunkPlan, envelope: GeometryEnvelope, *,
              [(ia, jb) for ia in range(len(strips))
               for jb in range(len(chunks))])
     for ia, jb in pairs:
-        s, e = strips[ia]
-        r0, r1 = chunks[jb]
-        a_bl, b_bl, a_sl, b_sl, metas = [], [], [], [], []
-        for w in range(width):
-            Am = np.zeros((srpad, kpad), np.float32)
-            Am[: e - s, r0:r1] = Ads[w][s:e, r0:r1]
-            Bm = np.zeros((kpad, npad), np.float32)
-            Bm[r0:r1, :n] = Bds[w][r0:r1, :]
-            Ab = bsr_from_dense(Am, bs, pad_to=nbl_a_cap)
-            Bb = bsr_from_dense(Bm, bs, pad_to=nbl_b_cap)
-            meta = bsr_spgemm_symbolic(Ab, Bb, nc_pad=nc_cap, u_max=u_cap)
-            metas.append(meta)
-            a_bl.append(bsr_blocks_with_sentinel(Ab))
-            b_bl.append(bsr_blocks_with_sentinel(Bb))
-            a_sl.append(jnp.asarray(meta.a_slots))
-            b_sl.append(jnp.asarray(meta.b_slots))
+        cut = [_bsr_pair(Ad, Bd, strips[ia], chunks[jb], envelope)
+               for Ad, Bd in zip(Ads, Bds)]
+        ops = [c[0] for c in cut]
         if batched:
-            out = core(jnp.stack(a_bl), jnp.stack(b_bl), jnp.stack(a_sl),
-                       jnp.stack(b_sl), envelope=envelope)
+            out = core(*(jnp.stack(x) for x in zip(*ops)), envelope=envelope)
         else:
-            out = core(a_bl[0], b_bl[0], a_sl[0], b_sl[0],
-                       envelope=envelope)[None]
+            out = core(*ops[0], envelope=envelope)[None]
         out_np = np.asarray(out)
-        for w, meta in enumerate(metas):
+        for w, (_, meta) in enumerate(cut):
             n_c = meta.n_c_blocks
             if not n_c:
                 continue
@@ -982,25 +982,22 @@ def chunk_bsr(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int, caps=None,
     return out[0], stats
 
 
+def _audit_bsr(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int,
+               envelope: GeometryEnvelope) -> TraceTarget:
+    """The first (strip, chunk) pair, cut as ``_bsr_execute`` cuts every
+    pair; every pair launches the same envelope-keyed kernel geometry, so
+    one pair is the whole compile surface."""
+    del c_pad
+    Ad, Bd = (np.asarray(csr_to_dense(M), np.float32) for M in (A, B))
+    args, _ = _bsr_pair(Ad, Bd, plan.p_ac[:2], plan.p_b[:2], envelope)
+    return TraceTarget(
+        fn=partial(_BSR_CORES[plan.algorithm], envelope=envelope),
+        args=args, meta={"scalar_args": args[2:]})
+
+
 # ---------------------------------------------------------------------------
 # batched entry point: many problem instances, one plan, one compilation
 # ---------------------------------------------------------------------------
-
-
-def _stage_chunks_batched(Bs, plan: ChunkPlan, envelope: GeometryEnvelope):
-    """Every instance's B chunks repadded to the envelope and doubly stacked
-    ([batch, n_b, ...]); returns the stack and one staged chunk's bytes."""
-    chunk_lists = [b_chunks(B, plan.p_b, envelope=envelope) for B in Bs]
-    return (csr_stack([csr_stack(cl) for cl in chunk_lists]),
-            chunk_lists[0][0].nbytes())
-
-
-def _stage_strips_batched(As, plan: ChunkPlan, envelope: GeometryEnvelope):
-    """Every instance's A strips repadded to the envelope and doubly stacked
-    ([batch, n_ac, ...]); returns the stack and one staged strip's bytes."""
-    strip_lists = [a_strips(A, plan.p_ac, envelope=envelope) for A in As]
-    return (csr_stack([csr_stack(sl) for sl in strip_lists]),
-            strip_lists[0][0].nbytes())
 
 
 def _scan_run_batched(As, Bs, plan: ChunkPlan, envelope: GeometryEnvelope, *,
@@ -1011,34 +1008,21 @@ def _scan_run_batched(As, Bs, plan: ChunkPlan, envelope: GeometryEnvelope, *,
     ``cores`` substitutes a caller-owned core set from
     :func:`_make_scan_batched_cores` for the module-level one."""
     del caps_list, validate_caps  # the ranged merge cannot overflow c_pad
-    if cores is None:
-        cores = _SCAN_CORES_BATCHED
-    c_pad = envelope.c_pad
-    r0s, r1s = plan.b_ranges()
-    r0s, r1s = jnp.asarray(r0s), jnp.asarray(r1s)
-    n_cols = Bs[0].n_cols
-    dtype = As[0].dtype
-    Bst, chunk_nbytes = _stage_chunks_batched(Bs, plan, envelope)
+    c_pad, n_cols, dtype = envelope.c_pad, Bs[0].n_cols, As[0].dtype
+    st = _stage_batch(As, Bs, plan, envelope,
+                      strips=plan.algorithm != "knl")
+    rows = st.A.n_rows
+    # knl's C is per instance and chunk2's per strip; chunk1's one strip
+    # template is broadcast by the vmap
+    lead = {"knl": (len(As),), "chunk1": (),
+            "chunk2": (plan.n_ac,)}[plan.algorithm]
+    C0 = _empty_c(lead, rows, n_cols, c_pad, dtype)
+    core = (cores or _SCAN_CORES_BATCHED)[plan.algorithm]
+    Cb = core(st.A, st.B, *jax.device_put(plan.b_ranges()), C0, c_pad=c_pad)
+    stats = planned_stats(plan, st.b_nbytes, st.a_nbytes,
+                          _c_strip_nbytes(rows, c_pad, dtype))
     if plan.algorithm == "knl":
-        Ast = csr_stack([
-            csr_pad_to(A, nnz_cap=envelope.a_nnz_cap,
-                       max_row_nnz=envelope.a_max_row_nnz)
-            for A in As
-        ])
-        C0s = _empty_c_stack(len(As), envelope.a_shape[0], n_cols, c_pad,
-                             dtype)
-        Cb = cores["knl"](Ast, Bst, r0s, r1s, C0s, c_pad=c_pad)
-        return csr_unstack(Cb), planned_stats(plan, chunk_nbytes, 0, 0)
-    Ast, strip_nbytes = _stage_strips_batched(As, plan, envelope)
-    strip_rows = envelope.strip_rows
-    stats = planned_stats(plan, chunk_nbytes, strip_nbytes,
-                          _c_strip_nbytes(strip_rows, c_pad, dtype))
-    if plan.algorithm == "chunk1":
-        C0 = _empty_c(strip_rows, n_cols, c_pad, dtype)
-        Cb = cores["chunk1"](Ast, Bst, r0s, r1s, C0, c_pad=c_pad)
-    else:
-        C0s = _empty_c_stack(plan.n_ac, strip_rows, n_cols, c_pad, dtype)
-        Cb = cores["chunk2"](Ast, Bst, r0s, r1s, C0s, c_pad=c_pad)
+        return csr_unstack(Cb), stats
     return [_assemble(Ci, plan.p_ac, n_cols) for Ci in csr_unstack(Cb)], stats
 
 
@@ -1050,30 +1034,16 @@ def _pallas_run_batched(As, Bs, plan: ChunkPlan, envelope: GeometryEnvelope, *,
     batch (staging and accumulation in f32 — allclose, not bitwise, against
     the loop oracle)."""
     del caps_list, validate_caps  # dense accumulators cannot overflow
-    if cores is None:
-        cores = _PALLAS_CORES_BATCHED
-    r0s = jnp.asarray(plan.b_ranges()[0])
-    n_cols = Bs[0].n_cols
-    np_dtype = np.dtype(As[0].dtype)
-    Bst, _ = _stage_chunks_batched(Bs, plan, envelope)
-    if plan.algorithm == "knl":
-        Ast = csr_stack([
-            csr_pad_to(A, nnz_cap=envelope.a_nnz_cap,
-                       max_row_nnz=envelope.a_max_row_nnz)
-            for A in As
-        ])
-        dense = cores["knl"](Ast, Bst, r0s)
-        stats = planned_stats_pallas(plan, *_pallas_stage_nbytes(
-            envelope.a_shape[0], envelope.a_shape[1], envelope.chunk_rows,
-            n_cols))
-        return [_pallas_assemble(d, (0, envelope.a_shape[0]), np_dtype)
-                for d in dense], stats
-    Ast, _ = _stage_strips_batched(As, plan, envelope)
-    dense = cores[plan.algorithm](Ast, Bst, r0s)
+    knl = plan.algorithm == "knl"
+    st = _stage_batch(As, Bs, plan, envelope, strips=not knl)
+    r0s, _ = jax.device_put(plan.b_ranges())
+    dense = (cores or _PALLAS_CORES_BATCHED)[plan.algorithm](st.A, st.B, r0s)
+    rows = st.A.n_rows
     stats = planned_stats_pallas(plan, *_pallas_stage_nbytes(
-        envelope.strip_rows, envelope.a_shape[1], envelope.chunk_rows,
-        n_cols))
-    return [_pallas_assemble(d, plan.p_ac, np_dtype) for d in dense], stats
+        rows, envelope.a_shape[1], st.B.n_rows, Bs[0].n_cols))
+    p_ac = (0, rows) if knl else plan.p_ac
+    return [_pallas_assemble(d, p_ac, np.dtype(As[0].dtype))
+            for d in dense], stats
 
 
 def _csr_accum_run_batched(As, Bs, plan: ChunkPlan,
@@ -1093,19 +1063,8 @@ def _csr_accum_run_batched(As, Bs, plan: ChunkPlan,
     union/quantization — pass ``False`` to skip the per-call host symbolic
     expansion the check costs; callers that already ran the expansions pass
     them as ``caps_list``."""
-    c_pad = envelope.c_pad
-    n_cols = Bs[0].n_cols
-    dtype = As[0].dtype
-    # the table size is a compile key, so it derives from the envelope
-    # alone, never from the per-call instances. A zero c_max_row_nnz is
-    # exact (empty output, 1-slot tables) when the symbolic phase ran —
-    # witnessed by c_nnz_cap, whose rounding floor makes it nonzero
-    # whenever computed; only a legacy both-zero envelope falls back to
-    # the always-valid n_cols bound.
-    table = None
-    if kind == "hash":
-        table = hash_table_slots(
-            envelope.c_max_row_nnz if envelope.c_nnz_cap else n_cols)
+    c_pad, n_cols = envelope.c_pad, Bs[0].n_cols
+    table = _envelope_table(envelope, n_cols) if kind == "hash" else None
     if validate_caps:
         if caps_list is None:
             caps_list = [strip_output_caps(A, B, plan.p_ac)
@@ -1114,44 +1073,15 @@ def _csr_accum_run_batched(As, Bs, plan: ChunkPlan,
             check_output_caps(caps.strip_nnz, caps.c_max_row_nnz, c_pad,
                               table, backend=kind, a_shape=A.shape,
                               b_shape=Bs[i].shape, instance=i)
-    r0s, r1s = plan.b_ranges()
-    r0s, r1s = jnp.asarray(r0s), jnp.asarray(r1s)
-    Bst, chunk_nbytes = _stage_chunks_batched(Bs, plan, envelope)
-    # uniform across all three algorithms: knl is the 1-strip special
-    # case (p_ac == (0, n_rows)), so every instance stages as strips
-    Ast, strip_nbytes = _stage_strips_batched(As, plan, envelope)
-    strip_rows = envelope.strip_rows
-    C0 = _sparse_c0_stack(len(As), plan.n_ac, strip_rows, n_cols, c_pad,
-                          dtype)
     if cores is None:
         cores = _HASH_CORES_BATCHED if kind == "hash" else _SPARSE_CORES_BATCHED
-    if kind == "hash":
-        ip, ix, d = cores[plan.algorithm](Ast, Bst, C0, r0s, r1s,
-                                          table_size=table)
-    else:
-        ip, ix, d = cores[plan.algorithm](Ast, Bst, C0, r0s, r1s)
-    stats = planned_stats_pallas(
-        plan, chunk_nbytes, strip_nbytes,
-        _c_strip_nbytes(strip_rows, c_pad, dtype))
+    launch = _csr_accum_launch(cores, As, Bs, plan, c_pad, table, envelope)
+    ip, ix, d = launch.fn(*launch.args)
+    shape = (launch.args[0].n_rows, n_cols)
     return [
-        _assemble(CSR(ip[b], ix[b], d[b], (strip_rows, n_cols), c_pad),
-                  plan.p_ac, n_cols)
+        _assemble(CSR(ip[b], ix[b], d[b], shape, c_pad), plan.p_ac, n_cols)
         for b in range(len(As))
-    ], stats
-
-
-def _sparse_run_batched(As, Bs, plan, envelope, *, caps_list=None,
-                        validate_caps=True, cores=None):
-    return _csr_accum_run_batched(As, Bs, plan, envelope, "sparse",
-                                  caps_list=caps_list,
-                                  validate_caps=validate_caps, cores=cores)
-
-
-def _hash_run_batched(As, Bs, plan, envelope, *, caps_list=None,
-                      validate_caps=True, cores=None):
-    return _csr_accum_run_batched(As, Bs, plan, envelope, "hash",
-                                  caps_list=caps_list,
-                                  validate_caps=validate_caps, cores=cores)
+    ], launch.meta["stats"]
 
 
 def _bsr_run_batched(As, Bs, plan, envelope, *, caps_list=None,
@@ -1250,125 +1180,6 @@ def chunked_spgemm_batched(As, Bs, plan: ChunkPlan, c_pad: int | None = None,
 
 
 # ---------------------------------------------------------------------------
-# registrations: the one place each backend is wired into the stack
-# ---------------------------------------------------------------------------
-
-
-# ---------------------------------------------------------------------------
-# audit staging: TraceTargets for the static verifier (repro.analysis)
-# ---------------------------------------------------------------------------
-#
-# Each helper stages one instance at an explicit GeometryEnvelope — exactly
-# the envelope-driven padding the batched executors perform — and binds the
-# statics into the backend's jitted core so `jax.make_jaxpr(fn)(*args)`
-# abstract-traces the very program the executors launch. Two same-envelope
-# instances must therefore produce byte-identical jaxprs (the retrace-leak
-# contract); the traced program is also what the VMEM and DMA audits read.
-
-
-def _audit_scan(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int,
-                envelope: GeometryEnvelope):
-    Bst = csr_stack(b_chunks(B, plan.p_b, envelope=envelope))
-    r0s, r1s = plan.b_ranges()
-    if plan.algorithm == "knl":
-        Ast = csr_pad_to(A, nnz_cap=envelope.a_nnz_cap,
-                         max_row_nnz=envelope.a_max_row_nnz)
-        C0 = _empty_c(A.n_rows, B.n_cols, c_pad, A.dtype)
-        core = _knl_scan
-    else:
-        Ast = csr_stack(a_strips(A, plan.p_ac, envelope=envelope))
-        strip_rows = envelope.strip_rows
-        if plan.algorithm == "chunk1":
-            C0 = _empty_c(strip_rows, B.n_cols, c_pad, A.dtype)
-            core = _chunk1_scan
-        else:
-            C0 = _empty_c_stack(plan.n_ac, strip_rows, B.n_cols, c_pad,
-                                A.dtype)
-            core = _chunk2_scan
-    return backend_registry.TraceTarget(
-        fn=partial(core, c_pad=c_pad),
-        args=(Ast, Bst, jnp.asarray(r0s), jnp.asarray(r1s), C0))
-
-
-def _audit_pallas(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int,
-                  envelope: GeometryEnvelope):
-    del c_pad  # capacity is implicit in the dense accumulator
-    Bst = csr_stack(b_chunks(B, plan.p_b, envelope=envelope))
-    r0s, _ = plan.b_ranges()
-    if plan.algorithm == "knl":
-        Ast = csr_pad_to(A, nnz_cap=envelope.a_nnz_cap,
-                         max_row_nnz=envelope.a_max_row_nnz)
-        core = _knl_pallas
-    else:
-        Ast = csr_stack(a_strips(A, plan.p_ac, envelope=envelope))
-        core = _chunk1_pallas if plan.algorithm == "chunk1" else _chunk2_pallas
-    return backend_registry.TraceTarget(
-        fn=core, args=(Ast, Bst, jnp.asarray(r0s)),
-        meta={"scalar_args": (jnp.asarray(r0s),)})
-
-
-def _make_audit_csr_accum(kind: str):
-    """Audit staging shared by the ESC ("sparse") and hash backends — the
-    doubly stacked width-1 staging of ``_sparse_run``, envelope-padded."""
-
-    def audit(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int,
-              envelope: GeometryEnvelope):
-        Ast = csr_stack([csr_stack(a_strips(A, plan.p_ac,
-                                            envelope=envelope))])
-        Bst = csr_stack([csr_stack(b_chunks(B, plan.p_b,
-                                            envelope=envelope))])
-        r0s, r1s = plan.b_ranges()
-        C0 = _sparse_c0_stack(1, plan.n_ac, envelope.strip_rows, B.n_cols,
-                              c_pad, A.dtype)
-        args = (Ast, Bst, C0, jnp.asarray(r0s), jnp.asarray(r1s))
-        scalar_args = (jnp.asarray(r0s), jnp.asarray(r1s))
-        if kind == "hash":
-            # compile key: the table derives from the envelope, exactly as
-            # in the batched run (see _csr_accum_run_batched)
-            table = hash_table_slots(
-                envelope.c_max_row_nnz if envelope.c_nnz_cap else B.n_cols)
-            return backend_registry.TraceTarget(
-                fn=partial(_HASH_CORES[plan.algorithm], table_size=table),
-                args=args,
-                meta={"table_size": table, "scalar_args": scalar_args})
-        return backend_registry.TraceTarget(
-            fn=_SPARSE_CORES[plan.algorithm], args=args,
-            meta={"scalar_args": scalar_args})
-
-    return audit
-
-
-def _audit_bsr(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int,
-               envelope: GeometryEnvelope):
-    """Stage the first (strip, chunk) pair exactly as ``_bsr_execute`` does;
-    every pair launches the same envelope-keyed kernel geometry, so one pair
-    is the whole compile surface."""
-    del c_pad
-    bs, nbl_a_cap, nbl_b_cap, nc_cap, u_cap = envelope.bsr_caps
-    k, n = B.shape
-    kpad = -(-k // bs) * bs
-    npad = -(-n // bs) * bs
-    srpad = -(-envelope.strip_rows // bs) * bs
-    Ad = np.asarray(csr_to_dense(A), np.float32)
-    Bd = np.asarray(csr_to_dense(B), np.float32)
-    s, e = plan.p_ac[0], plan.p_ac[1]
-    r0, r1 = plan.p_b[0], plan.p_b[1]
-    Am = np.zeros((srpad, kpad), np.float32)
-    Am[: e - s, r0:r1] = Ad[s:e, r0:r1]
-    Bm = np.zeros((kpad, npad), np.float32)
-    Bm[r0:r1, :n] = Bd[r0:r1, :]
-    Ab = bsr_from_dense(Am, bs, pad_to=nbl_a_cap)
-    Bb = bsr_from_dense(Bm, bs, pad_to=nbl_b_cap)
-    meta = bsr_spgemm_symbolic(Ab, Bb, nc_pad=nc_cap, u_max=u_cap)
-    a_slots, b_slots = jnp.asarray(meta.a_slots), jnp.asarray(meta.b_slots)
-    return backend_registry.TraceTarget(
-        fn=partial(_BSR_CORES[plan.algorithm], envelope=envelope),
-        args=(bsr_blocks_with_sentinel(Ab), bsr_blocks_with_sentinel(Bb),
-              a_slots, b_slots),
-        meta={"scalar_args": (a_slots, b_slots)})
-
-
-# ---------------------------------------------------------------------------
 # traffic models: the per-copy-event byte flows the traced jaxprs must equal
 # ---------------------------------------------------------------------------
 #
@@ -1376,77 +1187,59 @@ def _audit_bsr(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int,
 # of copy-event byte sizes the staged launch performs over its whole grid —
 # the planner-side half of the flow-equality audit (repro.analysis.traffic),
 # which reconstructs the same lists from the traced jaxpr and demands exact
-# equality, then ties the merged flows to the ChunkStats the executors log.
+# equality, then ties the merged flows to the ChunkStats the audited
+# launch's staging logs (``meta["stats"]``, the executors' own staging).
+
+
+def _pipeline_traffic(plan: ChunkPlan, fields: dict, stats: ChunkStats):
+    """The Pallas pipeline's flows (:func:`_pipeline_events`): one per
+    staged field of each operand, in the kernel's operand order. ``fields``
+    maps each piece (``"a"``, ``"b"``, ``"c"``) to its fields' bytes — one
+    for a dense block, ``(indptr, indices, data)`` for a CSR triple, whose
+    same-key flows merge into the single ChunkStats event of the triple."""
+    OpFlow = backend_registry.OpFlow
+    events = _pipeline_events(plan)
+    piece = {operand: p for operand, p, _ in events}
+
+    def flows(operands):
+        return tuple(
+            OpFlow(op, tuple(float(n * f) for o, _, n in events if o == op))
+            for op in operands for f in fields[piece[op]])
+
+    return backend_registry.ExpectedTraffic(
+        in_ops=flows(("stationary", "streamed", "c_prev")),
+        out_ops=flows(("c_out",)),
+        stats_in=tuple(stats.per_copy_in), stats_out=tuple(stats.per_copy_out))
 
 
 def _traffic_pallas(A, B, plan: ChunkPlan, c_pad: int,
                     envelope: GeometryEnvelope, meta):
-    """Dense-slab pipeline flows. knl/chunk1 grid is (1, n_ac, n_b): the
-    stationary strip and fused C_prev block refetch per strip, the slab
-    hand-DMAs every grid step; chunk2 swaps the roles and maps all C
-    partials as one constant-index block (one fetch, one writeback)."""
-    del A, B, c_pad, meta
-    OpFlow = backend_registry.OpFlow
-    k, n = envelope.a_shape[1], envelope.b_shape[1]
+    """Dense-slab pipeline flows, one dense block per operand."""
+    del A, B, c_pad
     strip_rows = (envelope.a_shape[0] if plan.algorithm == "knl"
                   else envelope.strip_rows)
-    slab, a_stage, c_stage = (
-        float(v) for v in _pallas_stage_nbytes(strip_rows, k,
-                                               envelope.chunk_rows, n))
-    n_ac, n_b = plan.n_ac, plan.n_b
-    if plan.algorithm in ("knl", "chunk1"):
-        in_ops = (OpFlow("stationary", (a_stage,) * n_ac),
-                  OpFlow("streamed", (slab,) * (n_ac * n_b)),
-                  OpFlow("c_prev", (c_stage,) * n_ac))
-        out_ops = (OpFlow("c_out", (c_stage,) * n_ac),)
-    else:
-        in_ops = (OpFlow("stationary", (slab,) * n_b),
-                  OpFlow("streamed", (a_stage,) * (n_b * n_ac)),
-                  OpFlow("c_prev", (n_ac * c_stage,)))
-        out_ops = (OpFlow("c_out", (n_ac * c_stage,)),)
-    st = planned_stats_pallas(plan, slab, a_stage, c_stage)
-    return backend_registry.ExpectedTraffic(
-        in_ops=in_ops, out_ops=out_ops,
-        stats_in=tuple(st.per_copy_in), stats_out=tuple(st.per_copy_out))
+    slab, a_stage, c_stage = _pallas_stage_nbytes(
+        strip_rows, envelope.a_shape[1], envelope.chunk_rows,
+        envelope.b_shape[1])
+    return _pipeline_traffic(
+        plan, {"a": (a_stage,), "b": (slab,), "c": (c_stage,)},
+        meta["stats"])
 
 
 def _traffic_csr_accum(A, B, plan: ChunkPlan, c_pad: int,
                        envelope: GeometryEnvelope, meta):
     """CSR-accumulator (ESC and hash) flows: every logical operand is three
-    field operands (indptr, indices, data) whose per-event bytes sum to the
-    staged triple's ``CSR.nbytes()`` — same-key fields merge event-wise into
-    the single ChunkStats event the executors log. knl stages as the
-    1-strip chunk1 special case (see ``_sparse_run``)."""
-    del A, B, meta
-    OpFlow = backend_registry.OpFlow
-    itemsize = int(np.dtype(envelope.dtype).itemsize)
-    strip_f = csr_field_nbytes(envelope.strip_rows, envelope.strip_nnz_cap,
-                               itemsize)
-    chunk_f = csr_field_nbytes(envelope.chunk_rows, envelope.chunk_nnz_cap,
-                               itemsize)
-    c_f = csr_field_nbytes(envelope.strip_rows, c_pad, itemsize)
-    n_ac, n_b = plan.n_ac, plan.n_b
-    if plan.algorithm in ("knl", "chunk1"):
-        stat_f, stream_f = strip_f, chunk_f
-        n_stat, n_stream = n_ac, n_ac * n_b
-        c_in = tuple(OpFlow("c_prev", (f,) * n_ac) for f in c_f)
-        c_out = tuple(OpFlow("c_out", (f,) * n_ac) for f in c_f)
-    else:
-        stat_f, stream_f = chunk_f, strip_f
-        n_stat, n_stream = n_b, n_b * n_ac
-        c_in = tuple(OpFlow("c_prev", (n_ac * f,)) for f in c_f)
-        c_out = tuple(OpFlow("c_out", (n_ac * f,)) for f in c_f)
-    in_ops = (
-        tuple(OpFlow("stationary", (f,) * n_stat) for f in stat_f)
-        + tuple(OpFlow("streamed", (f,) * n_stream) for f in stream_f)
-        + c_in
-    )
-    st = planned_stats_pallas(
-        plan, int(sum(chunk_f)), int(sum(strip_f)),
-        _c_strip_nbytes(envelope.strip_rows, c_pad, envelope.dtype))
-    return backend_registry.ExpectedTraffic(
-        in_ops=in_ops, out_ops=c_out,
-        stats_in=tuple(st.per_copy_in), stats_out=tuple(st.per_copy_out))
+    field operands whose per-event bytes sum to the staged triple's
+    ``CSR.nbytes()``. knl stages as the 1-strip chunk1 special case."""
+    del A, B
+    item = int(np.dtype(envelope.dtype).itemsize)
+    return _pipeline_traffic(plan, {
+        "a": csr_field_nbytes(envelope.strip_rows, envelope.strip_nnz_cap,
+                              item),
+        "b": csr_field_nbytes(envelope.chunk_rows, envelope.chunk_nnz_cap,
+                              item),
+        "c": csr_field_nbytes(envelope.strip_rows, c_pad, item),
+    }, meta["stats"])
 
 
 def _traffic_bsr(A, B, plan: ChunkPlan, c_pad: int,
@@ -1486,6 +1279,11 @@ def _traffic_bsr(A, B, plan: ChunkPlan, c_pad: int,
             "single-pair launch (documented in _bsr_execute)"))
 
 
+# ---------------------------------------------------------------------------
+# registrations: the one place each backend is wired into the stack
+# ---------------------------------------------------------------------------
+
+
 def _register_all() -> None:
     if "scan" in backend_registry._REGISTRY:   # tolerate importlib.reload
         return
@@ -1498,37 +1296,35 @@ def _register_all() -> None:
     ))
     register(Spec(
         name="scan",
-        executors={"knl": chunk_knl_scan, "chunk1": chunk_gpu1_scan,
-                   "chunk2": chunk_gpu2_scan},
+        executors=dict.fromkeys(algs, _scan_run),
         run_batched=_scan_run_batched,
         trace_key="{alg}",
         trace_key_batched="{alg}_batched",
-        audit_trace=_audit_scan,
+        audit_trace=_scan_launch,
         make_batched_cores=_make_scan_batched_cores,
     ))
     register(Spec(
         name="pallas",
-        executors={"knl": chunk_knl_pallas, "chunk1": chunk_gpu1_pallas,
-                   "chunk2": chunk_gpu2_pallas},
+        executors=dict.fromkeys(algs, _pallas_run),
         run_batched=_pallas_run_batched,
         byte_model=planned_stats_dense_slab,
         trace_key="{alg}_pallas",
         trace_key_batched="{alg}_pallas_batched",
         is_accumulator=True,
-        audit_trace=_audit_pallas,
+        audit_trace=_pallas_launch,
         traffic_model=_traffic_pallas,
         make_batched_cores=_make_pallas_batched_cores,
     ))
     register(Spec(
         name="sparse",
         executors=dict.fromkeys(algs, chunk_sparse),
-        run_batched=_sparse_run_batched,
+        run_batched=partial(_csr_accum_run_batched, kind="sparse"),
         byte_model=planned_stats_sparse,
         trace_key="{alg}_sparse",
         trace_key_batched="{alg}_sparse_batched",
         needs_output_caps=True,
         is_accumulator=True,
-        audit_trace=_make_audit_csr_accum("sparse"),
+        audit_trace=_audit_sparse,
         traffic_model=_traffic_csr_accum,
         make_batched_cores=_make_sparse_batched_cores,
         mosaic_refusal=(
@@ -1539,14 +1335,14 @@ def _register_all() -> None:
     register(Spec(
         name="hash",
         executors=dict.fromkeys(algs, chunk_hash),
-        run_batched=_hash_run_batched,
+        run_batched=partial(_csr_accum_run_batched, kind="hash"),
         byte_model=planned_stats_hash,
         trace_key="{alg}_hash",
         trace_key_batched="{alg}_hash_batched",
         needs_output_caps=True,
         is_accumulator=True,
         run_masked=chunk_hash_masked,
-        audit_trace=_make_audit_csr_accum("hash"),
+        audit_trace=_audit_hash,
         traffic_model=_traffic_csr_accum,
         make_batched_cores=_make_hash_batched_cores,
         mosaic_refusal=(

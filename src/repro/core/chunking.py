@@ -26,7 +26,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.core.kkmem import spgemm, spgemm_ranged
+from repro.core.kkmem import key_layout, spgemm, spgemm_ranged
 from repro.core.planner import ChunkPlan
 from repro.core.spans import span
 from repro.core.symbolic import strip_output_caps
@@ -203,6 +203,20 @@ def _assemble(Cs: CSR, p_ac: tuple, n_cols: int) -> CSR:
         return C
 
 
+def chunk_whole_fast(A: CSR, B: CSR, c_pad: int):
+    """The unchunked product, for a plan whose operands all fit fast memory:
+    A and B copied in once, one launch, C copied out. The launch span notes
+    the sort key, as every chunked launch does."""
+    stats = ChunkStats("whole_fast", 1, 1)
+    stats.add_in(A.nbytes() + B.nbytes())
+    with span("launch") as sp:
+        sp.note(key=key_layout(A.n_rows, B.n_cols))
+        C = spgemm(A, B, c_pad)
+    stats.add_out(C.nbytes())
+    stats.kernel_calls = 1
+    return C, stats
+
+
 # ---------------------------------------------------------------------------
 # Algorithm 1: KNL chunking — A, C in slow memory; stream B chunks through fast
 # ---------------------------------------------------------------------------
@@ -317,13 +331,7 @@ def chunked_spgemm(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int | None = None,
             c_pad = caps.c_pad
         if plan.algorithm == "whole_fast":
             call.note(backend=backend)
-            stats = ChunkStats("whole_fast", 1, 1)
-            stats.add_in(A.nbytes() + B.nbytes())
-            with span("launch"):
-                C = spgemm(A, B, c_pad)
-            stats.add_out(C.nbytes())
-            stats.kernel_calls = 1
-            return C, stats
+            return chunk_whole_fast(A, B, c_pad)
         if backend == "auto":
             from repro.core.planner import select_accumulator_backend
 

@@ -33,8 +33,9 @@ from __future__ import annotations
 import dataclasses
 
 from repro.core import backend_registry
-from repro.core.chunking import ChunkStats, instance_envelope
-from repro.core.kkmem import key_layout, spgemm
+from repro.core.chunking import (
+    ChunkStats, chunk_whole_fast, instance_envelope,
+)
 from repro.core.planner import BackendFastModel, PipelinePlan, plan_pipeline
 from repro.core.spans import span
 from repro.core.symbolic import PipelineCaps, pipeline_output_caps
@@ -90,14 +91,7 @@ def _run_hop(X: CSR, Y: CSR, plan, caps, backend: str):
     """One hop through a registered backend at pre-sized caps (no repeat
     symbolic expansion — the composed phase already ran)."""
     if plan.algorithm == "whole_fast":
-        stats = ChunkStats("whole_fast", 1, 1)
-        stats.add_in(X.nbytes() + Y.nbytes())
-        with span("launch") as sp:
-            sp.note(key=key_layout(X.n_rows, Y.n_cols))
-            C = spgemm(X, Y, caps.c_pad)
-        stats.add_out(C.nbytes())
-        stats.kernel_calls = 1
-        return C, stats
+        return chunk_whole_fast(X, Y, caps.c_pad)
     spec = backend_registry.get(backend)
     fn = spec.executors.get(plan.algorithm)
     if fn is None:

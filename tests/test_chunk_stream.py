@@ -490,3 +490,46 @@ def test_chunk2_tables_live_only_inside_the_core(monkeypatch):
     gc.collect()
     assert table not in {x.size for x in jax.live_arrays()}
     _assert_same_csr(chunk_gpu2(A, B, plan, c_pad)[0], C)
+
+
+# ---------------------------------------------------------------------------
+# the audit stages what the executor launches
+# ---------------------------------------------------------------------------
+
+
+def _audited_backends():
+    from repro.core import backend_registry
+
+    return [s.name for s in backend_registry.specs() if s.supports_audit]
+
+
+@pytest.mark.parametrize("algorithm", ["knl", "chunk1", "chunk2"])
+@pytest.mark.parametrize("backend", _audited_backends())
+def test_audit_target_is_the_executors_launch(backend, algorithm):
+    """A backend's ``audit_trace`` at the instance envelope stages exactly
+    the launch its executor runs: after one executor call, calling the
+    audit target hits the core's jit cache (its ``TRACE_COUNTS`` key does
+    not move), so the static verifier traces the program that runs. Where
+    the target carries its staging's copy events, they are the executor's."""
+    from repro.core import backend_registry
+    from repro.core.symbolic import strip_output_caps
+
+    spec = backend_registry.get(backend)
+    rng = np.random.default_rng(31)
+    A = random_csr(rng, 26, 22, 0.2, pad_extra=3)
+    B = random_csr(rng, 22, 19, 0.25)
+    p_ac = (0, A.n_rows) if algorithm == "knl" else (0, 9, 26)
+    plan = ChunkPlan(algorithm, p_ac, (0, 8, 15, 22), 0.0, 0.0)
+    c_pad = strip_output_caps(A, B, plan.p_ac).c_pad
+    key = spec.trace_key.format(alg=algorithm)
+
+    _, stats = spec.executors[algorithm](A, B, plan, c_pad)
+    before = TRACE_COUNTS[key]
+    block = spec.block_size if spec.needs_block_caps else None
+    env = instance_envelope(A, B, plan, c_pad, block_size=block)
+    target = spec.audit_trace(A, B, plan, c_pad, env)
+    jax.block_until_ready(target.fn(*target.args))
+    assert TRACE_COUNTS[key] == before
+    if "stats" in target.meta:
+        assert target.meta["stats"].per_copy_in == stats.per_copy_in
+        assert target.meta["stats"].per_copy_out == stats.per_copy_out
