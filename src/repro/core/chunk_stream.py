@@ -19,8 +19,9 @@ The registered backends, in registry (= auto tie-break) order:
   uniformly-padded B chunks and A/C strips stack host-side into batched
   CSRs (``csr_stack``) and the chunk loop is a ``lax.scan`` with the fused
   ``spgemm_ranged`` body inlined, so the multiply never leaves the device.
-  XLA is *free* to double-buffer the slow->fast transfers, but nothing
-  forces the overlap.
+  A step whose A strip has no entry in the chunk's rows skips the body
+  (:func:`_step`). XLA is *free* to double-buffer the slow->fast
+  transfers, but nothing forces the overlap.
 * ``pallas`` — forces it: ``repro.kernels.ranged_spgemm``'s pallas_call
   hand-DMAs the streamed operand through a two-slot VMEM buffer (copy
   chunk j+1 while chunk j multiplies — the paper's ``copy2Fast`` overlap
@@ -99,11 +100,11 @@ from jax import lax
 
 from repro.core import backend_registry
 from repro.core.chunking import (
-    ChunkStats, _assemble, a_strips, b_chunks, batch_envelope,
+    ChunkStats, _assemble, _fetch, _join, a_strips, b_chunks, batch_envelope,
     chunk_gpu1, chunk_gpu2, chunk_knl, instance_envelope,
 )
 from repro.core.kkmem import (
-    TRACE_COUNTS, ell_tables, key_layout, spgemm_ranged_impl,
+    TRACE_COUNTS, ell_tables, key_layout, multiplies_range, spgemm_ranged_impl,
     spgemm_ranged_tables,
 )
 from repro.core.planner import (
@@ -159,73 +160,105 @@ def _empty_c(lead: tuple, n_rows: int, n_cols: int, c_pad: int, dtype) -> CSR:
 # ---------------------------------------------------------------------------
 
 
-def _knl_scan_impl(A: CSR, Bs: CSR, r0s, r1s, C0: CSR, c_pad: int) -> CSR:
+def _step(A: CSR, r0, r1, state, run):
+    """One (strip, chunk) step of a scan core: ``run(state)``, the ESC body
+    with all of the step's work inside it, where some entry of the strip
+    ``A`` multiplies a row of B's range [r0, r1)
+    (``kkmem.multiplies_range``); else ``state`` as it came, and with it
+    whether the body ran. A dead step's body would give back the same C:
+    no product joins the stream, and sorting a canonical C returns its
+    entries, padding and ``indptr`` (only a total of -0.0 would come back
+    as +0.0). Under ``vmap`` (the batched cores) the predicate is batched
+    and the cond lowers to a select that runs both branches: the same
+    results, and no saving."""
+    live = multiplies_range(A, r0, r1)
+    return lax.cond(live, run, lambda state: state, state), live
+
+
+def _knl_scan_impl(A: CSR, Bs: CSR, r0s, r1s, C0: CSR, c_pad: int):
+    """C, and per chunk whether its step ran."""
     def body(C, x):
         TRACE_COUNTS["knl_body"] += 1
         Bc, r0, r1 = x
-        return spgemm_ranged_impl(A, Bc, r0, r1, C, c_pad), None
+        return _step(A, r0, r1, C,
+                     lambda C: spgemm_ranged_impl(A, Bc, r0, r1, C, c_pad))
 
-    C, _ = lax.scan(body, C0, (Bs, r0s, r1s))
-    return C
+    return lax.scan(body, C0, (Bs, r0s, r1s))
 
 
-def _chunk1_scan_impl(As: CSR, Bs: CSR, r0s, r1s, C0: CSR, c_pad: int) -> CSR:
+def _chunk1_scan_impl(As: CSR, Bs: CSR, r0s, r1s, C0: CSR, c_pad: int):
+    """The stacked per-strip results, and per (strip, chunk) whether its
+    step ran."""
     def outer(carry, Ai):
         def inner(C, x):
             TRACE_COUNTS["chunk1_body"] += 1
             Bc, r0, r1 = x
-            return spgemm_ranged_impl(Ai, Bc, r0, r1, C, c_pad), None
+            return _step(Ai, r0, r1, C,
+                         lambda C: spgemm_ranged_impl(Ai, Bc, r0, r1, C, c_pad))
 
-        Ci, _ = lax.scan(inner, C0, (Bs, r0s, r1s))
-        return carry, Ci
+        return carry, lax.scan(inner, C0, (Bs, r0s, r1s))
 
-    _, Cs = lax.scan(outer, None, As)
-    return Cs
+    return lax.scan(outer, None, As)[1]
 
 
-def _chunk2_scan_impl(As: CSR, Bs: CSR, r0s, r1s, C0s: CSR, c_pad: int) -> CSR:
+def _chunk2_scan_impl(As: CSR, Bs: CSR, r0s, r1s, C0s: CSR, c_pad: int):
+    """The stacked per-strip results, and per (chunk, strip) whether its
+    step ran."""
     def outer(Cs, x):
         TRACE_COUNTS["chunk2_tables"] += 1
         Bc, r0, r1 = x
         # The chunk's ELL tables, built once here for every strip's step.
         # They are held flat through the strip loop: a two-dimensional table
-        # held there may take a padded tiled layout, and each step's reshape
-        # is a copy that dies with the step.
+        # held there may take a padded tiled layout, and each live step's
+        # reshape is a copy that dies with the step.
         tables = ell_tables(Bc)
         shape = tables[0].shape
         flat = [t.reshape(-1) for t in tables]
 
-        def inner(carry, y):
+        def inner(Cs, y):
             TRACE_COUNTS["chunk2_body"] += 1
-            Ai, Ci = y
-            tabs = [t.reshape(shape) for t in flat]
-            return carry, spgemm_ranged_tables(Ai, tabs, r0, r1, Ci, c_pad)
+            i, Ai = y
 
-        _, Cs2 = lax.scan(inner, None, (As, Cs))
-        return Cs2, None
+            # The stack of partials is the loop's carry, and a live step
+            # writes its strip's in place: a partial returned from the cond
+            # would take a buffer of its own before joining the stack.
+            def run(Cs):
+                Ci = jax.tree.map(
+                    lambda s: lax.dynamic_index_in_dim(s, i, keepdims=False), Cs)
+                tabs = [t.reshape(shape) for t in flat]
+                C = spgemm_ranged_tables(Ai, tabs, r0, r1, Ci, c_pad)
+                return jax.tree.map(
+                    lambda s, c: lax.dynamic_update_index_in_dim(s, c, i, 0),
+                    Cs, C)
 
-    Cs, _ = lax.scan(outer, C0s, (Bs, r0s, r1s))
-    return Cs
+            return _step(Ai, r0, r1, Cs, run)
+
+        strips = jnp.arange(As.indptr.shape[0], dtype=jnp.int32)
+        return lax.scan(inner, Cs, (strips, As))
+
+    return lax.scan(outer, C0s, (Bs, r0s, r1s))
 
 
 @partial(jax.jit, static_argnames=("c_pad",))
-def _knl_scan(A: CSR, Bs: CSR, r0s, r1s, C0: CSR, c_pad: int) -> CSR:
+def _knl_scan(A: CSR, Bs: CSR, r0s, r1s, C0: CSR, c_pad: int):
     TRACE_COUNTS["knl"] += 1
     return _knl_scan_impl(A, Bs, r0s, r1s, C0, c_pad)
 
 
 @partial(jax.jit, static_argnames=("c_pad",))
-def _chunk1_scan(As: CSR, Bs: CSR, r0s, r1s, C0: CSR, c_pad: int) -> CSR:
+def _chunk1_scan(As: CSR, Bs: CSR, r0s, r1s, C0: CSR, c_pad: int):
     """A/C strips outer (stationary), B chunks inner (streamed). Returns the
-    stacked per-strip results ([n_ac] leading axis)."""
+    stacked per-strip results ([n_ac] leading axis) and the steps' flags
+    ([n_ac, n_b])."""
     TRACE_COUNTS["chunk1"] += 1
     return _chunk1_scan_impl(As, Bs, r0s, r1s, C0, c_pad)
 
 
 @partial(jax.jit, static_argnames=("c_pad",))
-def _chunk2_scan(As: CSR, Bs: CSR, r0s, r1s, C0s: CSR, c_pad: int) -> CSR:
+def _chunk2_scan(As: CSR, Bs: CSR, r0s, r1s, C0s: CSR, c_pad: int):
     """B chunk outer (stationary), A/C strips inner (streamed); all per-strip
-    partials ride the scan carry. Returns the stacked per-strip results."""
+    partials ride the scan carry. Returns the stacked per-strip results and
+    the steps' flags ([n_b, n_ac])."""
     TRACE_COUNTS["chunk2"] += 1
     return _chunk2_scan_impl(As, Bs, r0s, r1s, C0s, c_pad)
 
@@ -252,21 +285,21 @@ def _make_scan_batched_cores(donate: bool = False) -> dict:
     def knl(Ast: CSR, Bst: CSR, r0s, r1s, C0s: CSR, c_pad: int) -> CSR:
         TRACE_COUNTS["knl_batched"] += 1
         return jax.vmap(
-            lambda A, Bc, C0: _knl_scan_impl(A, Bc, r0s, r1s, C0, c_pad)
+            lambda A, Bc, C0: _knl_scan_impl(A, Bc, r0s, r1s, C0, c_pad)[0]
         )(Ast, Bst, C0s)
 
     @partial(jax.jit, static_argnames=("c_pad",))
     def chunk1(Ast: CSR, Bst: CSR, r0s, r1s, C0: CSR, c_pad: int) -> CSR:
         TRACE_COUNTS["chunk1_batched"] += 1
         return jax.vmap(
-            lambda As, Bs: _chunk1_scan_impl(As, Bs, r0s, r1s, C0, c_pad)
+            lambda As, Bs: _chunk1_scan_impl(As, Bs, r0s, r1s, C0, c_pad)[0]
         )(Ast, Bst)
 
     @partial(jax.jit, static_argnames=("c_pad",))
     def chunk2(Ast: CSR, Bst: CSR, r0s, r1s, C0s: CSR, c_pad: int) -> CSR:
         TRACE_COUNTS["chunk2_batched"] += 1
         return jax.vmap(
-            lambda As, Bs: _chunk2_scan_impl(As, Bs, r0s, r1s, C0s, c_pad)
+            lambda As, Bs: _chunk2_scan_impl(As, Bs, r0s, r1s, C0s, c_pad)[0]
         )(Ast, Bst)
 
     return {"knl": knl, "chunk1": chunk1, "chunk2": chunk2}
@@ -469,18 +502,20 @@ def _scan_run(A: CSR, B: CSR, plan: ChunkPlan, c_pad: int):
     core. knl's C stays whole on the device; the strip orders assemble."""
     with span("stage") as sp:
         launch = _scan_launch(A, B, plan, c_pad, put=sp.put)
-    steps = plan.n_ac * plan.n_b
+    steps, stats = plan.n_ac * plan.n_b, launch.meta["stats"]
     with span("launch") as sp:
         # chunk1 has the strip loop outer: each chunk's tables are built
-        # anew for every strip
+        # anew for every live strip step
         sp.note(key=key_layout(launch.args[0].n_rows, B.n_cols),
                 tables=steps if plan.algorithm == "chunk1" else plan.n_b,
                 steps=steps)
-        C = launch.fn(*launch.args)
-    stats = launch.meta["stats"]
-    if plan.algorithm == "knl":
-        return C, stats
-    return _assemble(C, plan.p_ac, B.n_cols), stats
+        C, live = launch.fn(*launch.args)
+        if plan.algorithm == "knl":
+            # C stays on the device; reading the flags would wait for it
+            return C, stats
+        C, live = _fetch((C, live))
+        sp.note(live_steps=int(live.sum()))
+    return _join(C, plan.p_ac, B.n_cols), stats
 
 
 chunk_knl_scan = chunk_gpu1_scan = chunk_gpu2_scan = _scan_run
@@ -557,10 +592,7 @@ def _pallas_assemble(dense, p_ac: tuple, dtype) -> CSR:
     The Pallas backend's CSR keeps exactly the nonzeros of the dense result,
     so comparisons against the loop oracle are allclose on the densified
     values rather than bitwise on padding structure."""
-    with span("wait"):
-        jax.block_until_ready(dense)
-    with span("fetch") as sp:
-        dense = sp.get(dense)
+    dense = _fetch(dense)
     with span("assemble") as sp:
         if dense.ndim == 2:
             dense = dense[None]

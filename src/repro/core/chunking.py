@@ -182,10 +182,21 @@ def _assemble(Cs: CSR, p_ac: tuple, n_cols: int) -> CSR:
     """One CSR over all rows from the per-strip results ``Cs`` (fields
     stacked over the strips): read to the host in one transfer, joined
     there, and put back on the device."""
+    return _join(_fetch(Cs), p_ac, n_cols)
+
+
+def _fetch(tree):
+    """``tree`` read to the host in one transfer, once the device has
+    written it."""
     with span("wait"):
-        jax.block_until_ready(Cs)
+        jax.block_until_ready(tree)
     with span("fetch") as sp:
-        host = sp.get(Cs)
+        return sp.get(tree)
+
+
+def _join(host: CSR, p_ac: tuple, n_cols: int) -> CSR:
+    """The per-strip results ``host``, read to the host, joined there into
+    one CSR over all rows and put back on the device."""
     with span("assemble") as sp:
         ptrs, idxs, vals = [], [], []
         base = 0

@@ -97,6 +97,21 @@ def ell_tables(B: CSR):
     return jnp.where(ok, B.indices[src], -1), jnp.where(ok, B.data[src], 0.0)
 
 
+def _in_range(A: CSR, r0, r1):
+    """Per slot of A, whether it holds a valid entry whose column lies in
+    the B row range [r0, r1): the entries that multiply a row of that
+    chunk of B."""
+    t = jnp.arange(A.nnz_pad, dtype=jnp.int32)
+    return (A.indices >= r0) & (A.indices < r1) & (t < A.indptr[-1])
+
+
+def multiplies_range(A: CSR, r0, r1):
+    """Whether some entry of A multiplies a row of B's range [r0, r1), as
+    a scalar: where none does, ``spgemm_ranged_tables`` joins no product
+    to the stream."""
+    return jnp.any(_in_range(A, r0, r1))
+
+
 def _expand_products(A: CSR, tables, r0, r1):
     """Fan every (valid, in-range) A entry out into its products with B's rows.
 
@@ -110,8 +125,7 @@ def _expand_products(A: CSR, tables, r0, r1):
     For the unchunked case pass r0=0, r1=A.n_cols with the full matrix's.
     """
     ell_cols, ell_vals = tables                              # [width, B rows]
-    t = jnp.arange(A.nnz_pad, dtype=jnp.int32)
-    in_range = (A.indices >= r0) & (A.indices < r1) & (t < A.indptr[-1])
+    in_range = _in_range(A, r0, r1)
     b_row = jnp.clip(A.indices - r0, 0, ell_cols.shape[1] - 1)
     cols, vals = ell_cols[:, b_row], ell_vals[:, b_row]      # [width, nnzA_pad]
     valid = in_range[None, :] & (cols >= 0)

@@ -69,6 +69,19 @@ def csr_pair(draw, max_dim=24):
             random_csr(rng, k, n, db, pad_extra=draw(st.integers(0, 7))))
 
 
+def live_pairs(A: CSR, p_ac, p_b) -> np.ndarray:
+    """``[n_strips, n_chunks]``: whether strip i of A (rows ``p_ac[i]`` to
+    ``p_ac[i + 1]``) holds an entry whose column is a row of B chunk j
+    (``p_b[j]`` to ``p_b[j + 1]``), counted in NumPy from A's arrays."""
+    ip, ix = np.asarray(A.indptr), np.asarray(A.indices)
+    rows = np.repeat(np.arange(A.n_rows), np.diff(ip))
+    strip = np.searchsorted(p_ac, rows, side="right") - 1
+    chunk = np.searchsorted(p_b, ix[: ip[-1]], side="right") - 1
+    live = np.zeros((len(p_ac) - 1, len(p_b) - 1), bool)
+    live[strip, chunk] = True
+    return live
+
+
 @pytest.fixture
 def rng():
     return np.random.default_rng(0)

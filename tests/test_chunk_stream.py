@@ -37,7 +37,7 @@ from repro.sparse import multigrid
 from repro.sparse.csr import (
     CSR, csr_from_dense, csr_from_scipy_like, csr_to_dense,
 )
-from conftest import assert_close, csr_pair_cases, random_csr
+from conftest import assert_close, csr_pair_cases, live_pairs, random_csr
 
 LOOP = {"knl": chunk_knl, "chunk1": chunk_gpu1, "chunk2": chunk_gpu2}
 SCAN = {"knl": chunk_knl_scan, "chunk1": chunk_gpu1_scan, "chunk2": chunk_gpu2_scan}
@@ -365,12 +365,14 @@ def test_make_batched_cores_isolated_caches(backend):
 # ---------------------------------------------------------------------------
 
 
-def _sparse_csr(rng, m, n, nnz, pad=0):
+def _sparse_csr(rng, m, n, nnz, pad=0, col_range=None):
     """An ``m x n`` program CSR with about ``nnz`` entries at random places,
-    rows of unequal lengths and some empty."""
+    rows of unequal lengths and some empty. ``col_range(rows)``, where
+    given, bounds each entry's column to ``[lo, hi)`` by its row."""
     import scipy.sparse as sps
 
-    r, c = rng.integers(0, m, nnz), rng.integers(0, n, nnz)
+    r = rng.integers(0, m, nnz)
+    c = rng.integers(*(col_range(r) if col_range else (0, n)), size=nnz)
     M = sps.csr_matrix((rng.standard_normal(nnz).astype(np.float32), (r, c)),
                        shape=(m, n))
     M.sum_duplicates()
@@ -389,19 +391,38 @@ KEYED = {
 }
 
 
-@pytest.mark.parametrize("key", sorted(KEYED))
+def _banded(p_ac, p_b):
+    """Columns by row for a banded A: the first strip's rows in B's first
+    chunk, the other rows in its last, so no entry multiplies the middle
+    chunk and each strip misses the other's chunk."""
+    def col_range(rows):
+        first = rows < p_ac[1]
+        return (np.where(first, p_b[0], p_b[-2]),
+                np.where(first, p_b[1], p_b[-1]))
+    return col_range
+
+
+@pytest.mark.parametrize("key,banded", [
+    ("packed", False), ("pair", False), ("packed", True), ("pair", True)],
+    ids=["packed", "pair", "packed-banded", "pair-banded"])
 @pytest.mark.parametrize("algorithm", ["knl", "chunk1", "chunk2"])
-def test_scan_tables_match_loop_on_both_sort_keys(algorithm, key):
+def test_scan_tables_match_loop_on_both_sort_keys(algorithm, key, banded):
     """Each order of the scan backend, which reads B from tables built once
     per call, gives the loop oracle's CSR bit for bit, with n_ac >= 2 (but
-    knl) and n_b = 3 on each sort key; a same-structure batch does too."""
+    knl) and n_b = 3 on each sort key; a same-structure batch does too.
+    A scattered A multiplies every chunk in every strip; a banded A leaves
+    (strip, chunk) steps with nothing to multiply, which the scan cores
+    skip (the batched cores select) and the loop runs."""
     m, k, n, p_ac, p_b = KEYED[key]
     rng = np.random.default_rng(len(key) + len(algorithm))
-    A = _sparse_csr(rng, m, k, 160, pad=3)
+    band = _banded(p_ac, p_b) if banded else None
+    A = _sparse_csr(rng, m, k, 160, pad=3, col_range=band)
     B = _sparse_csr(rng, k, n, 90, pad=2)
     if algorithm == "knl":
         p_ac = (0, m)
     plan = ChunkPlan(algorithm, p_ac, p_b, 0.0, 0.0)
+    live = live_pairs(A, p_ac, p_b)
+    assert live.all() if band is None else not live.all()
     strip_rows = max(e - s for s, e in zip(p_ac[:-1], p_ac[1:]))
     assert key_layout(strip_rows, n) == key
     c_pad = spgemm_symbolic_host(A, B).c_pad

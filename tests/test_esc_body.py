@@ -26,8 +26,8 @@ from jaxlib.mlir import ir
 from repro.core import backend_registry, chunk_stream, spans
 from repro.core.chunking import instance_envelope
 from repro.core.kkmem import (
-    TRACE_COUNTS, key_layout, spgemm, spgemm_ranged, spgemm_ranged_impl,
-    spgemm_symbolic_host,
+    TRACE_COUNTS, key_layout, multiplies_range, spgemm, spgemm_ranged,
+    spgemm_ranged_impl, spgemm_symbolic_host,
 )
 from repro.core.planner import ChunkPlan
 from repro.core.symbolic import spgemm_pattern_host, spgemm_structure_host
@@ -36,6 +36,7 @@ from repro.sparse.csr import (
     CSR, csr_from_coo, csr_from_scipy_like, csr_row_of_entry, csr_transpose_host,
 )
 from repro.sparse.generators import random_uniform_degree
+from conftest import live_pairs
 
 
 # ---------------------------------------------------------------------------
@@ -254,6 +255,32 @@ def test_cancelling_terms_stay_as_entries():
     assert ip.tolist() == [0, 3, 5, 5]
     assert ix[:5].tolist() == [0, 1, 2, 0, 2]
     assert d[:5].tolist() == [0.0, 0.0, 2.0, -2.0, 0.0]
+
+
+@pytest.mark.parametrize("m,n", [(37, 41), (65535, 65536)])
+def test_a_range_nothing_multiplies_gives_back_c_prev(m, n):
+    """Where no valid entry of A has its column in [r0, r1) (A's padding
+    slots point there), ``multiplies_range`` is false and the body gives
+    back C_prev's arrays bit for bit, C_prev being the body's own result,
+    on either sort key: so a scan core may skip such a step."""
+    rng = np.random.default_rng(m)
+    k, r0 = 24, 10
+    Asp = sp.hstack([_random(rng, m, r0, 120), sp.csr_matrix((m, k - r0))]).tocsr()
+    A = _prog(Asp, 4)
+    nnz = int(A.indptr[-1])
+    A = CSR(A.indptr, A.indices.at[nnz:].set(r0 + 1), A.data, A.shape,
+            A.max_row_nnz)
+    B = _random(rng, k, n, 90)
+    empty = sp.csr_matrix((m, n), dtype=np.float32)
+    c_pad = _structure(Asp, B, 0, r0, empty)[0].nnz + 5
+    C1 = spgemm_ranged(A, _chunk(B, 0, r0), 0, r0, _prog(empty, 5), c_pad)
+    C2 = spgemm_ranged(A, _chunk(B, r0, k), r0, k, C1, c_pad)
+    assert bool(multiplies_range(A, 0, r0)) and int(C1.indptr[-1]) > 0
+    assert not bool(multiplies_range(A, r0, k))
+    for x1, x2 in zip((C1.indptr, C1.indices, C1.data),
+                      (C2.indptr, C2.indices, C2.data)):
+        x1, x2 = np.asarray(x1), np.asarray(x2)
+        np.testing.assert_array_equal(x1.view(np.uint32), x2.view(np.uint32))
 
 
 @pytest.mark.parametrize("short", [1, 9, 40])
@@ -504,5 +531,42 @@ def test_chunk2_core_tabulates_each_chunk_once_outside_its_strip_loop(tmp_path):
     assert TRACE_COUNTS["chunk2_tables"] - before == 1
     notes = _launch_notes(tmp_path, run)
     assert TRACE_COUNTS["chunk2_tables"] - before == 1
-    assert [(n["tables"], n["steps"]) for n in notes] == [(2, 6)]
+    assert [(n["tables"], n["steps"], n["live_steps"]) for n in notes] == [
+        (2, 6, int(live_pairs(A2, plan2.p_ac, plan2.p_b).sum()))]
     assert notes[0]["key"] == "packed"
+
+
+@pytest.mark.parametrize("pattern", ["banded", "scattered"])
+@pytest.mark.parametrize("algorithm", ["chunk1", "chunk2"])
+def test_launch_notes_live_steps(tmp_path, algorithm, pattern):
+    """The launch span notes ``live_steps``, the steps whose ESC body ran,
+    read with C in one fetch: a NumPy count of the (strip, chunk) pairs in
+    which the strip has an entry in the chunk's rows. It is below
+    ``steps`` on a banded A, and equal to it where every pair is live. The
+    step flags add no trace: the core's keys rise by one for the
+    geometry."""
+    rng = np.random.default_rng(41)
+    m, k, n = (42, 34, 26) if pattern == "banded" else (45, 34, 26)
+    p_ac, p_b = (0, 14, 28, m), (0, 11, 23, k)
+    if pattern == "banded":
+        # row i's columns within 3 of i * k / m
+        r = rng.integers(0, m, 150)
+        c = np.clip(r * k // m + rng.integers(-3, 4, 150), 0, k - 1)
+        Asp = sp.csr_matrix((rng.standard_normal(150).astype(np.float32) + 4,
+                             (r, c)), shape=(m, k))
+    else:
+        Asp = _random(rng, m, k, 150)
+    A, B = _prog(Asp, 2), _prog(_random(rng, k, n, 90))
+    plan = ChunkPlan(algorithm, p_ac, p_b, 0.0, 0.0)
+    c_pad = spgemm_symbolic_host(A, B).c_pad
+    run = lambda: chunk_stream._scan_run(A, B, plan, c_pad)  # noqa: E731
+    keys = [algorithm] + (["chunk2_tables"] if algorithm == "chunk2" else [])
+    before = {key: TRACE_COUNTS[key] for key in keys}
+    run()
+    notes = _launch_notes(tmp_path, run)
+    assert {key: TRACE_COUNTS[key] - before[key] for key in keys} == \
+        dict.fromkeys(keys, 1)
+    live = live_pairs(A, p_ac, p_b)
+    assert live.all() if pattern == "scattered" else live.sum() < live.size
+    assert [(n["steps"], n["live_steps"]) for n in notes] == [
+        (9, int(live.sum()))]

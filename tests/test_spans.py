@@ -228,7 +228,9 @@ def test_chunked_spgemm_spans_and_bytes(tmp_path, case):
     assert phases == {"call", "stage", "launch", "wait", "fetch", "assemble"}
     strip_rows = max(e - s for s, e in zip(plan.p_ac[:-1], plan.p_ac[1:]))
     if backend == "scan":
-        fetched = plan.n_ac * ((strip_rows + 1) * I32 + c_pad * (I32 + 4))
+        # the strips of C, and one flag per step: whether its body ran
+        fetched = plan.n_ac * ((strip_rows + 1) * I32 + c_pad * (I32 + 4)
+                               + plan.n_b)
     else:
         fetched = plan.n_ac * strip_rows * B.n_cols * 4
     nnz = int(np.asarray(C.indptr)[-1])
@@ -279,6 +281,6 @@ def test_pipeline_spans_carry_hops_and_bytes(tmp_path, resident):
     assert _bytes(events, "spill", "d2h_bytes") == (0 if resident else t_bytes)
     assert ("spill" in {ev[0] for ev in events}) is not resident
     assert _bytes(events, "fetch", "d2h_bytes", hop=2) == p2.n_ac * (
-        (5 + 1) * I32 + caps.hop2.c_pad * (I32 + 4))
+        (5 + 1) * I32 + caps.hop2.c_pad * (I32 + 4) + p2.n_b)
     nnz = int(np.asarray(C.indptr)[-1])
     assert _bytes(events, "assemble", "h2d_bytes", hop=2) == _exact_nbytes(R.n_rows, nnz)
